@@ -40,7 +40,8 @@ def _eval_stream(args, seq, config, process_index):
         return token_batches(args.data, args.batch_size, seq,
                              seed=1_000_000 + process_index)
     return synthetic_tokens(args.batch_size, seq, config.vocab_size,
-                            seed=1, process_index=process_index)
+                            seed=args.seed + 1,
+                            process_index=process_index)
 
 
 def main() -> int:
@@ -50,6 +51,11 @@ def main() -> int:
                              "llama3_8b|llama3_70b, or a MoE preset "
                              "(moe_tiny|mixtral_proxy)")
     parser.add_argument("--steps", type=int, default=100)
+    parser.add_argument("--log-every", type=int, default=10,
+                        help="steps between logged losses")
+    parser.add_argument("--seed", type=int, default=0,
+                        help="seed of the initial weights and of the "
+                             "synthetic token stream")
     parser.add_argument("--batch-size", type=int, default=8)
     parser.add_argument("--seq-len", type=int, default=0,
                         help="0 = the preset's max_seq")
@@ -107,7 +113,7 @@ def main() -> int:
                                      seed=process_index)
         else:
             yield from synthetic_tokens(args.batch_size, seq,
-                                        config.vocab_size,
+                                        config.vocab_size, seed=args.seed,
                                         process_index=process_index)
 
     # pipelined loss when requested and the orchestrator rendered a pp
@@ -138,7 +144,8 @@ def main() -> int:
         init_fn=init_fn,
         data_iter=clipped_tokens(),
         config=TrainerConfig(
-            num_steps=args.steps, log_every=10,
+            num_steps=args.steps, log_every=args.log_every,
+            seed=args.seed,
             checkpoint_dir=args.checkpoint_dir,
             checkpoint_every=args.checkpoint_every,
             grad_accum=args.grad_accum,

@@ -1,7 +1,6 @@
-"""The bench measurement contract (VERDICT r3 weak #2): the driver keeps
-only a ~2 KB tail of stdout and parses the final line from it, so that
-line must be ONE compact JSON object. BENCH_r03 arrived as a 4 KB line
-(embedded stack dumps) and parsed as null."""
+"""The bench measurement contract: the driver keeps only a ~2 KB tail of
+stdout and parses the final line from it, so that line must be ONE compact
+JSON object."""
 
 import importlib.util
 import json
@@ -18,19 +17,12 @@ import pytest  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def _isolated_bench_paths(tmp_path, monkeypatch):
-    """EVERY snapshot path bench can write rides through these module
-    globals; redirecting them wholesale means no test can ever leak a
-    fabricated measurement into the real tools/ evidence directory
-    (r5: a 70.0 'partial' from this file briefly landed there)."""
+    """EVERY path bench can write rides through these module globals;
+    redirecting them wholesale means no test can ever leak a fabricated
+    measurement into the real tools/ history."""
     tools = tmp_path / "tools"
     tools.mkdir()
     monkeypatch.setattr(bench, "_TOOLS_DIR", str(tools))
-    monkeypatch.setattr(bench, "_LAST_GOOD_PATH",
-                        str(tools / "last_good_bench.json"))
-    monkeypatch.setattr(bench, "_DIAG_LOG_PATH",
-                        str(tools / "bench_diag.log"))
-    monkeypatch.setattr(bench, "_HEAD_PARTIAL_AUTO_PATH",
-                        str(tools / "bench_head_partial_auto.json"))
     monkeypatch.setattr(bench, "_HISTORY_PATH",
                         str(tools / "bench_history.jsonl"))
     monkeypatch.setattr(bench, "_commit_stamp", lambda: "testhead")
@@ -47,9 +39,7 @@ def test_emit_line_is_bounded_and_parseable(capsys):
     result = {
         "metric": bench.METRIC, "value": 0.0, "unit": "%MFU",
         "vs_baseline": 0.0,
-        "tpu_error": "e" * 2000,
-        "cpu_error": "c" * 2000,
-        "last_good_tpu_measurement": {"value": 68.08, "pad": "p" * 2000},
+        "scraped_metrics": "e" * 2000,
         "am_startup_latency": {"runs": 3, "pad": "q" * 2000},
         "error": "z" * 2000,
     }
@@ -70,123 +60,6 @@ def test_emit_small_result_untouched(capsys):
     bench._emit(result)
     line = capsys.readouterr().out.strip()
     assert json.loads(line) == result
-
-
-def test_record_last_good_partial_never_shadows_complete(tmp_path,
-                                                         monkeypatch):
-    """r5 regression: a deadline-killed (partial) or degraded-kernel
-    measurement overwrote the clean 68.08 record."""
-    path = tmp_path / "last_good.json"
-    monkeypatch.setattr(bench, "_LAST_GOOD_PATH", str(path))
-    complete = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
-                "device": "TPU v5 lite"}
-    bench._record_last_good(dict(complete))
-    assert bench._load_last_good()["value"] == 68.08
-
-    # partial must NOT overwrite a complete record — even a faster one
-    bench._record_last_good({"metric": bench.METRIC, "value": 70.0,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 164s"})
-    assert bench._load_last_good()["value"] == 68.08
-    assert "partial" not in bench._load_last_good()
-
-    # a new complete record DOES overwrite
-    bench._record_last_good({"metric": bench.METRIC, "value": 69.5,
-                             "unit": "%MFU", "device": "TPU v5 lite"})
-    assert bench._load_last_good()["value"] == 69.5
-
-    # cpu-device results are never recorded
-    bench._record_last_good({"metric": bench.METRIC, "value": 99.0,
-                             "unit": "%MFU", "device": "cpu"})
-    assert bench._load_last_good()["value"] == 69.5
-
-
-def test_record_last_good_partial_upgrades_partial(tmp_path, monkeypatch):
-    """Partials may replace partials (a better one is strictly more
-    evidence) but the 'partial' label must survive into the compact
-    embed so the driver record never presents one as complete."""
-    path = tmp_path / "last_good.json"
-    monkeypatch.setattr(bench, "_LAST_GOOD_PATH", str(path))
-    bench._record_last_good({"metric": bench.METRIC, "value": 50.0,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 100s"})
-    bench._record_last_good({"metric": bench.METRIC, "value": 58.5,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 164s"})
-    last = bench._load_last_good()
-    assert last["value"] == 58.5
-    assert bench._compact_last_good(last)["partial"] \
-        == "timed out after 164s"
-
-
-def test_head_partial_recency_gate(_isolated_bench_paths):
-    """Only snapshots written in the last 48h qualify as at-HEAD
-    evidence; the newest fresh one wins by mtime, not filename."""
-    tools = _isolated_bench_paths
-    stale = tools / "bench_head_partial_r5.json"
-    stale.write_text(json.dumps({"value": 11.1, "commit": "old"}))
-    os.utime(stale, (0, 0))   # epoch: far past the 48h window
-    assert bench._head_partial() is None
-
-    # a fresh snapshot qualifies; r10 vs r5 must sort by mtime not name
-    fresh = tools / "bench_head_partial_r10.json"
-    fresh.write_text(json.dumps({"value": 58.53, "commit": "3bc892f",
-                                 "partial": "contended", "extra": "x"}))
-    got = bench._head_partial()
-    assert got["value"] == 58.53 and got["commit"] == "3bc892f"
-    assert "extra" not in got
-
-
-def test_partial_auto_persists_to_head_partial(_isolated_bench_paths):
-    """A deadline-truncated on-chip measurement is live at-HEAD evidence:
-    _record_last_good must side-channel it to bench_head_partial_auto.json
-    (without letting it shadow the complete last-good); a lower fresh
-    partial from the SAME commit must not replace a higher one, but after
-    the code changes the fresh measurement always wins."""
-    tools = _isolated_bench_paths
-    complete = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
-                "device": "TPU v5 lite"}
-    bench._record_last_good(dict(complete))
-
-    partial = {"metric": bench.METRIC, "value": 58.53, "unit": "%MFU",
-               "device": "TPU v5 lite", "batch_tokens": 32768,
-               "partial": "timed out after 164s"}
-    bench._record_last_good(dict(partial))
-    # last-good untouched, head-partial written with stamps
-    assert bench._load_last_good()["value"] == 68.08
-    auto = json.loads((tools / "bench_head_partial_auto.json").read_text())
-    assert auto["value"] == 58.53 and auto["partial"]
-    assert auto["measured_at"] and auto["commit"] == "testhead"
-    assert bench._head_partial()["value"] == 58.53
-
-    # a LOWER fresh partial from the same commit must not replace it
-    bench._record_last_good({"metric": bench.METRIC, "value": 30.0,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 60s"})
-    assert bench._head_partial()["value"] == 58.53
-
-    # a higher partial upgrades it
-    bench._record_last_good({"metric": bench.METRIC, "value": 61.2,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 200s",
-                             "kernel_fallback": "blockwise"})
-    got = bench._head_partial()
-    # the degraded-kernel marker must survive persist AND read-back
-    assert got["value"] == 61.2 and got["kernel_fallback"] == "blockwise"
-
-    # after a code change (different commit), a lower fresh partial WINS:
-    # stale evidence must not masquerade as at-HEAD
-    bench._commit_stamp = lambda: "newhead"
-    bench._record_last_good({"metric": bench.METRIC, "value": 44.0,
-                             "unit": "%MFU", "device": "TPU v5 lite",
-                             "partial": "timed out after 90s"})
-    assert bench._head_partial()["value"] == 44.0
-
-    # cpu-device partials never persist
-    bench._record_last_good({"metric": bench.METRIC, "value": 99.0,
-                             "unit": "%MFU", "device": "cpu",
-                             "partial": "x"})
-    assert bench._head_partial()["value"] == 44.0
 
 
 def test_input_stall_field_from_prefetch_feed():
@@ -231,39 +104,16 @@ def test_input_stall_fails_loudly_when_prefetch_bypassed():
 
 def test_emit_preserves_input_stall_field(capsys):
     """input_stall_ms_per_step is a headline field: it must survive
-    _emit's truncation ladder (it is not in drop_order) and ride into the
-    head-partial snapshot keep-list."""
+    _emit's truncation ladder (it is not in drop_order)."""
     result = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
               "vs_baseline": 1.702, "input_stall_ms_per_step": 0.41,
               "prefetch_depth": 2,
-              "tpu_error": "e" * 2000, "error": "z" * 2000}
+              "scraped_metrics": "e" * 2000, "error": "z" * 2000}
     bench._emit(result)
     line = capsys.readouterr().out.strip().splitlines()[-1]
     parsed = json.loads(line)
     assert parsed["input_stall_ms_per_step"] == 0.41
     assert parsed["prefetch_depth"] == 2
-
-
-def test_head_partial_snapshot_keeps_input_stall(_isolated_bench_paths):
-    bench._record_last_good({
-        "metric": bench.METRIC, "value": 58.53, "unit": "%MFU",
-        "device": "TPU v5 lite", "input_stall_ms_per_step": 1.2,
-        "partial": "timed out after 164s"})
-    auto = json.loads(
-        (_isolated_bench_paths / "bench_head_partial_auto.json")
-        .read_text())
-    assert auto["input_stall_ms_per_step"] == 1.2
-
-
-def test_compact_last_good_keeps_headline_only():
-    last = {"metric": "m", "value": 68.08, "unit": "%MFU",
-            "commit": "abc", "measured_at": "t", "step_time_s": 1.0,
-            "tokens_per_sec_per_chip": 15897.0,
-            "llama3_8b_layer_step_ms": 63.08, "generate_batch": 8}
-    out = bench._compact_last_good(last)
-    assert out["value"] == 68.08 and out["commit"] == "abc"
-    assert "llama3_8b_layer_step_ms" not in out
-    assert len(json.dumps(out)) < 300
 
 
 def test_history_append_and_regression_verdict(_isolated_bench_paths,

@@ -205,8 +205,12 @@ class _FakeJaxConfig:
 
 
 class _FakeJax:
-    def __init__(self):
+    def __init__(self, backend="tpu"):
         self.config = _FakeJaxConfig()
+        self._backend = backend
+
+    def default_backend(self):
+        return self._backend
 
 
 def test_compile_cache_env_rendered_into_user_env():
@@ -226,33 +230,83 @@ def test_compile_cache_env_rendered_into_user_env():
     assert env[C.JAX_CACHE_DIR] == "/var/cache/tony-jax"
 
 
-def test_maybe_enable_compile_cache_honors_env(tmp_path, monkeypatch):
-    from tony_tpu.utils.compilecache import maybe_enable_compile_cache
+@pytest.mark.parametrize("jax_env,conf_key", [
+    (True, True), (False, True), (False, False),
+], ids=["jax-env-set", "conf-key", "neither"])
+def test_compile_cache_precedence(tmp_path, monkeypatch, jax_env, conf_key):
+    """$JAX_COMPILATION_CACHE_DIR set -> jax reads it itself and NO code
+    sets a directory; unset with the job's conf key -> the key's value;
+    unset without -> the one fixed path inside the checkout."""
+    from tony_tpu.utils import compilecache
 
-    cache_dir = str(tmp_path / "jax_cache")
-    monkeypatch.setenv(C.JAX_CACHE_DIR, cache_dir)
+    fixed = str(tmp_path / "checkout" / ".jax_cache")
+    monkeypatch.setattr(compilecache, "CHECKOUT_CACHE_DIR", fixed)
+    monkeypatch.delenv(compilecache.JAX_ENV, raising=False)
+    monkeypatch.delenv(C.JAX_CACHE_DIR, raising=False)
+    if jax_env:
+        monkeypatch.setenv(compilecache.JAX_ENV, str(tmp_path / "outside"))
+    if conf_key:
+        monkeypatch.setenv(C.JAX_CACHE_DIR, str(tmp_path / "from_conf"))
     jax = _FakeJax()
-    assert maybe_enable_compile_cache(jax_module=jax) == cache_dir
-    assert jax.config.calls["jax_compilation_cache_dir"] == cache_dir
-    assert os.path.isdir(cache_dir)
+    got = compilecache.enable_compile_cache(jax)
+    cpu = _FakeJax(backend="cpu")
+    if jax_env:
+        # the user's own variable is jax's business on any backend
+        assert compilecache.enable_compile_cache(cpu) == got
+    else:
+        # no default cache on the cpu backend: every hit there logs a
+        # multi-kilobyte XLA machine-feature error
+        assert compilecache.enable_compile_cache(cpu) is None
+        assert cpu.config.calls == {}
+    if jax_env:
+        assert got == str(tmp_path / "outside")
+        assert "jax_compilation_cache_dir" not in jax.config.calls
+        assert not os.path.exists(tmp_path / "from_conf")
+        assert not os.path.exists(fixed)
+    else:
+        want = str(tmp_path / "from_conf") if conf_key else fixed
+        assert got == want
+        assert jax.config.calls["jax_compilation_cache_dir"] == want
+        assert os.path.isdir(want)
+    # the thresholds are set either way: they are not a directory
+    assert jax.config.calls[
+        "jax_persistent_cache_min_compile_time_secs"] == 0.5
 
-    # unset → disabled, jax untouched
-    monkeypatch.delenv(C.JAX_CACHE_DIR)
-    jax2 = _FakeJax()
-    assert maybe_enable_compile_cache(jax_module=jax2) is None
-    assert jax2.config.calls == {}
+
+def test_checkout_cache_dir_is_fixed_under_the_checkout():
+    """Computed from the package's own location — never the working
+    directory (a container's is new on every submission), a temp name, a
+    pid or a time: the path is part of the cache key."""
+    from tony_tpu.utils.compilecache import CHECKOUT_CACHE_DIR
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
 
 
-def test_maybe_enable_compile_cache_never_raises(tmp_path, monkeypatch):
-    """The cache is an optimization, never a dependency: a jax that
-    refuses the config keys degrades to a warning, not a crash."""
-    from tony_tpu.utils.compilecache import maybe_enable_compile_cache
+def test_compile_cache_unwritable_dir_is_a_warning(tmp_path, monkeypatch):
+    """The cache is an optimization, never a dependency: a directory that
+    cannot be made (a read-only checkout) degrades to no cache."""
+    from tony_tpu.utils import compilecache
 
-    class _Refusing:
-        class config:  # noqa: N801 — mimics jax.config
-            @staticmethod
-            def update(key, value):
-                raise ValueError("unknown config")
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.delenv(compilecache.JAX_ENV, raising=False)
+    monkeypatch.setenv(C.JAX_CACHE_DIR, str(blocker / "sub"))
+    jax = _FakeJax()
+    assert compilecache.enable_compile_cache(jax) is None
+    assert jax.config.calls == {}
 
-    monkeypatch.setenv(C.JAX_CACHE_DIR, str(tmp_path / "d"))
-    assert maybe_enable_compile_cache(jax_module=_Refusing()) is None
+
+def test_warm_pool_child_keeps_the_jax_cache_env(monkeypatch):
+    """A warm executor scrubs inherited TONY_* and task-identity env
+    before a bind; $JAX_COMPILATION_CACHE_DIR must survive it, or a
+    warm-launched trainer would cache somewhere else than a cold one."""
+    from tony_tpu.cluster import warmpool
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    monkeypatch.setenv("TONY_JAX_CACHE_DIR", "/stale/app/a")
+    monkeypatch.setenv("JOB_NAME", "worker")
+    warmpool._scrub_task_env()
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
+    assert "TONY_JAX_CACHE_DIR" not in os.environ
+    assert "JOB_NAME" not in os.environ

@@ -7,6 +7,8 @@ O(S^2) oracle. The kernel itself is additionally exercised in interpret mode
 for one small case.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -349,3 +351,52 @@ def test_segmented_pallas_kernels_interpret_mode(monkeypatch):
         np.testing.assert_allclose(np.asarray(got_), np.asarray(want_),
                                    atol=5e-5, rtol=5e-4,
                                    err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("rows,d", [(3, 256), (40, 256), (2100, 128)],
+                         ids=["decode-3", "one-block", "padded-blocks"])
+def test_rms_pallas_kernel_interpret_matches_reference(rows, d):
+    """The RMSNorm kernel itself (interpreted on the CPU) at the three
+    row regimes of `_rms_pallas`: fewer rows than a tile, one block equal
+    to the array, and fixed blocks with the last one padded."""
+    from tony_tpu.ops import rmsnorm as R
+
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (d,)) + 1.0
+    block_rows = max(8, R.BLOCK_BYTES // (4 * d) // 8 * 8)
+    assert (rows > block_rows) == (rows == 2100)
+    got = R._rms_pallas(x, w, 1e-5, interpret=True)
+    np.testing.assert_allclose(got, _rms_reference(x, w, 1e-5),
+                               atol=1e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("s", [3, 37, 129])
+def test_pallas_kernels_pad_a_short_sequence_to_whole_tiles(s, monkeypatch):
+    """Prompt lengths Mosaic refused as they were (3, 37, 129 rows): the
+    REAL kernels (interpret mode), through the whole dispatch, round a
+    one-block sequence up to whole tiles, mask the padded K columns and
+    slice the padded Q rows — forward and all three gradients against the
+    O(S^2) oracle. That they then COMPILE is tests/test_tpu_compile.py's."""
+    import tony_tpu.ops.attention as att
+
+    monkeypatch.setattr(att, "_FORCE", "pallas")
+    monkeypatch.setattr(att, "_INTERPRET", True)
+    assert att._tile_pad(s, 512, 512) == (-s) % 128
+    q, k, v = _gqa_qkv(b=1, h=4, hk=2, s=s, d=32, seed=s)
+    g = jax.random.normal(jax.random.PRNGKey(s + 1), q.shape)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, True) * g)
+
+    np.testing.assert_allclose(
+        np.asarray(att.flash_attention(q, k, v, True)),
+        np.asarray(reference_attention(q, k, v, True)),
+        atol=2e-5, rtol=2e-5)
+    got = jax.grad(partial(loss, att.flash_attention),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(partial(loss, reference_attention),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=5e-4, err_msg=f"d{name}")

@@ -174,15 +174,15 @@ def test_goodput_report_table():
 # ---------------------------------------------------------------------------
 
 class _Dev:
-    def __init__(self, platform="tpu", kind="TPU v5e"):
+    def __init__(self, platform="tpu", kind="TPU v5 lite"):
         self.platform = platform
         self.device_kind = kind
 
 
 def test_peak_flops_and_mfu_shared_definition():
+    # the v5e chip names itself "TPU v5 lite": matched on purpose
     assert perf.peak_flops(_Dev()) == 197e12
     assert perf.peak_flops(_Dev(kind="TPU v5p")) == 459e12
-    assert perf.peak_flops(_Dev(platform="cpu")) == perf.CPU_PEAK
     # bench re-exports the SAME objects — one definition repo-wide
     import bench
     assert bench.peak_flops is perf.peak_flops
@@ -190,6 +190,21 @@ def test_peak_flops_and_mfu_shared_definition():
     mfu = perf.mfu_pct(1000.0, 197e6, _Dev())
     assert mfu == pytest.approx(0.1)
     assert perf.mfu_pct(1000.0, 0.0, _Dev()) == 0.0
+
+
+@pytest.mark.parametrize("dev", [
+    _Dev(kind="TPU v9 mega"),               # an accelerator nobody listed
+    _Dev(platform="cpu", kind="cpu"),       # no nominal CPU peak either
+    _Dev(platform="gpu", kind="NVIDIA H100"),
+], ids=["unknown-tpu", "cpu", "gpu"])
+def test_peak_flops_raises_for_a_device_it_does_not_know(dev):
+    """No function returns a peak rate for a device it does not know: a
+    default would feed the trainer's MFU gauge, the AM, the portal and the
+    alerts a utilization against a made-up peak."""
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        perf.peak_flops(dev)
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        perf.mfu_pct(1000.0, 197e6, dev)
 
 
 def test_mfu_reported_for_llama_and_moe():

@@ -1,0 +1,228 @@
+"""The main path's programs, compiled for a TPU v5e that is described and
+not attached (section 2 of the on-chip-measurement guide).
+
+Interpret mode and the CPU branches never see what Mosaic and the TPU
+compiler refuse: a block that is not a whole (8, 128) tile, a kernel that
+needs more scoped VMEM than it may use, a Mosaic call the partitioner is
+asked to split, a step that does not fit the chip's HBM. These cases guard
+every later PR against them at no chip time. Nothing runs here, so they
+say nothing about results or times.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif or in parametrize arguments: only one process may load
+the TPU's library, every xdist worker imports this file, and only the
+worker that is GIVEN it may load the library. All cases live in this one
+file for the same reason, and compile in the test's own process.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from tony_tpu.models.llama import get_config, llama_init, llama_loss
+from tony_tpu.ops.attention import KERNEL_NAMES, flash_attention, kernel_counts
+from tony_tpu.ops.rmsnorm import rms_norm
+
+GiB = 2 ** 30
+# a v5e chip has 16 GB of HBM; the runtime leaves ~15.75 GiB to a program
+HBM_USABLE = 15.75 * GiB
+# chip_smoke.py's train phase (MODEL, SEQ_LEN, BATCH there)
+SMOKE_BATCH, SMOKE_SEQ = 2, 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topology = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topology
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def fsdp4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("fsdp",))
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    lowered = jax.jit(fn).lower(*args)
+    return lowered, lowered.compile()
+
+
+def _qkv(b, h, hk, s, sharding, d=128):
+    q = _sds((b, h, s, d), jnp.bfloat16, sharding)
+    kv = _sds((b, hk, s, d), jnp.bfloat16, sharding)
+    return q, kv, kv
+
+
+def _flash_loss(q, k, v):
+    return flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+
+# head shapes: llama3_1b_proxy 16/8, llama3_8b 32/8, head_dim 128 both
+@pytest.mark.parametrize("heads,kv_heads", [(16, 8), (32, 8)],
+                         ids=["1b_proxy", "8b"])
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_compiles_at_model_head_shapes(one_chip, heads, kv_heads, bwd):
+    args = _qkv(SMOKE_BATCH, heads, kv_heads, 4096, one_chip)
+    fn = (jax.grad(_flash_loss, argnums=(0, 1, 2)) if bwd
+          else partial(flash_attention, causal=True))
+    _, compiled = _compile(fn, *args)
+    got = kernel_counts(compiled.as_text())
+    assert got["tony_flash_fwd"] == 1
+    assert (got["tony_flash_bwd_dq"], got["tony_flash_bwd_dkv"]) == (
+        (1, 1) if bwd else (0, 0))
+
+
+# serving admission prefills at the raw prompt length (serve/engine.py):
+# 3, 37 and 129 were refused by Mosaic before the short-sequence padding
+# ("cannot statically prove that index in dimension 1 is a multiple of 8")
+@pytest.mark.parametrize("s", [3, 37, 129, 600])
+def test_flash_fwd_compiles_at_prompt_lengths(one_chip, s):
+    _, compiled = _compile(partial(flash_attention, causal=True),
+                           *_qkv(1, 16, 8, s, one_chip))
+    assert kernel_counts(compiled.as_text())["tony_flash_fwd"] == 1
+
+
+@pytest.mark.parametrize("s", [37, 129])
+def test_flash_bwd_compiles_at_odd_lengths(one_chip, s):
+    """Training at an odd length takes the same pad/slice path."""
+    _, compiled = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                           *_qkv(2, 16, 8, s, one_chip))
+    got = kernel_counts(compiled.as_text())
+    assert all(got[k] == 1 for k in KERNEL_NAMES[:3]), got
+
+
+@pytest.mark.parametrize("rows,d,dtype", [
+    (SMOKE_BATCH * SMOKE_SEQ, 2048, jnp.bfloat16),   # 1B-proxy train
+    (4 * 4096, 4096, jnp.float32),     # 8B width, f32: VMEM-refused at 256
+    (3, 2048, jnp.bfloat16),           # decode, 3 rows
+    (8, 2048, jnp.bfloat16),           # decode, 8 slots
+    (37, 2048, jnp.bfloat16),          # prefill of a 37-token prompt
+], ids=["train-1b", "train-8b-f32", "decode-3", "decode-8", "prefill-37"])
+def test_rmsnorm_compiles_on_one_chip(one_chip, rows, d, dtype):
+    _, compiled = _compile(
+        lambda x, w: rms_norm(x, w, 1e-5),
+        _sds((rows, d), dtype, one_chip), _sds((d,), jnp.float32, one_chip))
+    assert kernel_counts(compiled.as_text())["tony_rmsnorm"] == 1
+
+
+def test_rmsnorm_and_flash_compile_on_a_4_device_fsdp_mesh(fsdp4):
+    """XLA cannot partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"): both
+    kernels must run on local shards, and the compiled program must still
+    hold them — not the jnp branches."""
+    rows = NamedSharding(fsdp4, P("fsdp"))
+    whole = NamedSharding(fsdp4, P())
+    with jax.set_mesh(fsdp4):
+        _, norm = _compile(
+            lambda x, w: rms_norm(x, w, 1e-5),
+            _sds((4, 4096, 2048), jnp.bfloat16, rows),
+            _sds((2048,), jnp.float32, whole))
+        _, flash = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                            *_qkv(4, 16, 8, 4096, rows))
+    assert kernel_counts(norm.as_text())["tony_rmsnorm"] == 1
+    got = kernel_counts(flash.as_text())
+    assert all(got[k] == 1 for k in KERNEL_NAMES[:3]), got
+
+
+def _abstract_params(config, place):
+    shapes = jax.eval_shape(partial(llama_init, config),
+                            jax.random.PRNGKey(0))
+    return jax.tree.map(lambda l: _sds(l.shape, l.dtype, place), shapes)
+
+
+def test_prefill_and_decode_step_compile_for_1b_proxy(one_chip):
+    """The two programs of the serving engine at its default shapes:
+    admission of a 512-token prompt, and one decode step of 4 slots x
+    2048 tokens (tony.serving.slots / token-budget defaults)."""
+    from tony_tpu.models.generate import decode_step, prefill
+
+    config = get_config("llama3_1b_proxy")
+    params = _abstract_params(config, one_chip)
+    slots, budget = 4, 2048
+    _, pre = _compile(
+        lambda p, t: prefill(p, t, config, budget), params,
+        _sds((1, 512), jnp.int32, one_chip))
+    got = kernel_counts(pre.as_text())
+    assert got["tony_flash_fwd"] == 1 and got["tony_rmsnorm"] == 3, got
+    cache = {name: _sds((config.n_layers, slots, config.n_kv_heads, budget,
+                         config.head_dim), jnp.bfloat16, one_chip)
+             for name in ("k", "v")}
+    _, dec = _compile(
+        lambda p, c, t, pos: decode_step(p, config, c, t, pos), params,
+        cache, _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip))
+    assert kernel_counts(dec.as_text())["tony_rmsnorm"] == 3
+    for exe in (pre, dec):
+        mem = exe.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < HBM_USABLE)
+
+
+def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
+    """The step chip_smoke.py's train phase runs — the trainer's own
+    construction (train/trainer.py: adamw under a warm-up cosine schedule,
+    bf16 state, donated) at the smoke's batch. A passing compile does NOT
+    prove the program fits (batch 8 compiles too, at 6.36 + 13.74 = 20.1
+    GiB for a 16 GB chip), so the size is read from memory_analysis().
+    Arguments + temporaries is an upper bound: batch 4 sums to 16.1 GiB
+    and still ran on the chip (CHANGES.md, PR 22)."""
+    import optax
+
+    from tony_tpu.train.step import make_train_step
+
+    config = get_config("llama3_1b_proxy")
+    params = _abstract_params(config, one_chip)
+    optimizer = optax.adamw(
+        optax.warmup_cosine_decay_schedule(0.0, 3e-4, 10, 100),
+        weight_decay=0.01)
+    opt_state = jax.tree.map(
+        lambda l: _sds(l.shape, l.dtype, one_chip),
+        jax.eval_shape(optimizer.init, params))
+    batch = {k: _sds((SMOKE_BATCH, SMOKE_SEQ), jnp.int32, one_chip)
+             for k in ("inputs", "targets")}
+    step = make_train_step(partial(llama_loss, config=config), optimizer,
+                           jit=False)
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt_state, batch)
+    compiled = lowered.compile()
+    # flash fwd once (the remat replay reuses the saved out/lse), dq and
+    # dk/dv once, RMSNorm twice a layer forward + twice in the replay +
+    # the final norm — in the lowered step and in the compiled one
+    want = {"tony_flash_fwd": 1, "tony_flash_bwd_dq": 1,
+            "tony_flash_bwd_dkv": 1, "tony_rmsnorm": 5,
+            "tpu_custom_call": 8}
+    assert kernel_counts(lowered.as_text()) == want
+    assert kernel_counts(compiled.as_text()) == want
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < HBM_USABLE, f"{total / GiB:.2f} GiB"
+    # and with room: the process also holds the prefetched batches
+    assert total < 13 * GiB, f"{total / GiB:.2f} GiB"

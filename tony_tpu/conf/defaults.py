@@ -259,7 +259,8 @@ DEFAULTS = {
     K.LOCALIZATION_CACHE_ENABLED: False,
     K.LOCALIZATION_CACHE_DIR: "",   # "" = <tmp>/tony_loc_cache
 
-    # persistent XLA compile cache dir rendered into user envs; "" = off
+    # persistent XLA compile cache dir rendered into user envs; "" = the
+    # checkout's .jax_cache/ (utils/compilecache.py)
     K.EXECUTOR_JAX_CACHE_DIR: "",
 
     # misc

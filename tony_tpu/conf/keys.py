@@ -513,8 +513,9 @@ LOCALIZATION_CACHE_DIR = "tony.localization.cache-dir"  # ""=/tmp/tony_loc_cache
 # --- executor-rendered user-env knobs ------------------------------------
 # Persistent XLA compile cache dir rendered into every trainer/serving
 # user env as $TONY_JAX_CACHE_DIR (train/trainer.py + serve honor it via
-# utils/compilecache.py); "" disables. The Nth identical trainer skips
-# its cold XLA compile.
+# utils/compilecache.py); "" = the checkout's .jax_cache/. Where
+# $JAX_COMPILATION_CACHE_DIR is set it wins and this key is not applied.
+# The Nth identical trainer skips its cold XLA compile.
 EXECUTOR_JAX_CACHE_DIR = "tony.executor.jax-cache-dir"
 
 # --- misc ----------------------------------------------------------------

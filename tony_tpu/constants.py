@@ -107,7 +107,8 @@ CHECKPOINT_KEEP = "TONY_CHECKPOINT_KEEP"
 # persistent XLA compile cache dir (tony.executor.jax-cache-dir rendered
 # into every trainer/serving user env; utils/compilecache.py applies it
 # before the first jit so the Nth identical trainer skips the cold
-# compile — empty/absent = no persistent cache)
+# compile — absent = the checkout's .jax_cache/, and
+# $JAX_COMPILATION_CACHE_DIR, where set, wins over both)
 JAX_CACHE_DIR = "TONY_JAX_CACHE_DIR"
 # warm-pool bind fence (cluster/warmpool.py): the pool stamps a
 # per-child nonce into the child env at fork and every stdin bind spec

@@ -173,7 +173,9 @@ def render_framework_env(framework: str, cluster_spec: ClusterSpec,
     # persistent XLA compile cache (tony.executor.jax-cache-dir) lands
     # in EVERY framework's user env — trainer and serving engine honor
     # it via utils/compilecache.py before their first jit, so the Nth
-    # identical process skips the cold compile
+    # identical process skips the cold compile. Only a job that set the
+    # key gets the variable, and $JAX_COMPILATION_CACHE_DIR (inherited
+    # by the container from the submitter's env) wins over it there
     jax_cache_dir = conf.get_str(K.EXECUTOR_JAX_CACHE_DIR, "")
     if jax_cache_dir:
         env.setdefault(C.JAX_CACHE_DIR, jax_cache_dir)

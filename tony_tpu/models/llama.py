@@ -205,12 +205,8 @@ def _attention_dispatch(q, k, v, config: LlamaConfig):
     running inside their own batch/heads shard_map (ops/attention.py
     _kernel_shard_axes) — a Mosaic custom call cannot be partitioned by
     XLA's Auto partitioner."""
-    from tony_tpu.ops.vma import (
-        ambient_abstract_mesh, manual_axes_of_context,
-    )
-
-    mesh = ambient_abstract_mesh()
-    sp = mesh.shape.get("sp", 1) if mesh is not None and mesh.axis_names else 1
+    mesh = jax.sharding.get_abstract_mesh()
+    sp = mesh.shape.get("sp", 1)
     if sp > 1:
         if config.sp_mode == "ulysses":
             from tony_tpu.ops.attention import _gqa_broadcast
@@ -225,7 +221,7 @@ def _attention_dispatch(q, k, v, config: LlamaConfig):
             inner = partial(ulysses_attention, axis_name="sp", causal=True)
         else:
             inner = partial(ring_attention, axis_name="sp", causal=True)
-        if "sp" in manual_axes_of_context():
+        if "sp" in mesh.manual_axes:
             # already inside a manual-sp region (the pp pipeline widens
             # its shard_map to {pp, sp}): call the collective attention
             # DIRECTLY — the kernel dispatch (ops/attention.py

@@ -19,7 +19,6 @@ from typing import Any, Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tony_tpu.ops.vma import ambient_abstract_mesh
 
 # (logical axis, mesh axis | tuple of mesh axes | None). First match wins;
 # None = replicate. Tuples shard one logical dim over several mesh axes
@@ -97,11 +96,10 @@ def constrain(x, logical_axes: Sequence[Optional[str]],
     when no mesh is active so model code is mesh-agnostic. Axes the ambient
     context holds Manually (inside shard_map) are dropped from the spec —
     with_sharding_constraint may only reference Auto axes there."""
-    mesh = ambient_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
-    from tony_tpu.ops.vma import manual_axes_of_context
-    manual = manual_axes_of_context()
+    manual = mesh.manual_axes
     spec = logical_to_mesh_axes(logical_axes, rules, mesh)
     if manual:
         cleaned = []

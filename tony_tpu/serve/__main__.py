@@ -89,11 +89,14 @@ def _load_model(args):
     import jax
     import jax.numpy as jnp
 
-    # persistent XLA compile cache ($TONY_JAX_CACHE_DIR rendered into
-    # the serving user env): applied before any device work so replica
-    # N skips replica 0's cold prefill/decode compile
-    from tony_tpu.utils.compilecache import maybe_enable_compile_cache
-    maybe_enable_compile_cache(jax_module=jax)
+    # persistent XLA compile cache (utils/compilecache.py): applied
+    # before any device work so replica N skips replica 0's cold
+    # prefill/decode compile
+    from tony_tpu.utils.compilecache import enable_compile_cache
+    enable_compile_cache(jax)
+    # the trainer's device line: what tells a TPU replica from a CPU one
+    from tony_tpu.train.metrics import log_devices
+    log_devices(LOG)
 
     from tony_tpu.models.moe import is_moe_preset
 

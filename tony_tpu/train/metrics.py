@@ -36,6 +36,30 @@ def sum_tpu_hbm(devices) -> tuple[int, int]:
     return hbm, limit
 
 
+def log_devices(log: logging.Logger) -> None:
+    """The one device line every process that holds the chip logs (the
+    trainer after distributed init, the serving replica before it loads
+    the model): how many devices, their kind, and the platform — what a
+    reader of the container log needs to tell a TPU run from a CPU one."""
+    import jax
+
+    log.info("devices: %d x %s (backend=%s)", jax.device_count(),
+             jax.devices()[0].device_kind, jax.default_backend())
+
+
+def peak_hbm_bytes() -> Optional[tuple[int, int]]:
+    """(largest `peak_bytes_in_use`, `bytes_limit`) over this process's
+    devices, None where the backend keeps no such statistic (the CPU)."""
+    import jax
+
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    stats = [s for s in stats if "peak_bytes_in_use" in s]
+    if not stats:
+        return None
+    top = max(stats, key=lambda s: s["peak_bytes_in_use"])
+    return int(top["peak_bytes_in_use"]), int(top.get("bytes_limit", 0))
+
+
 def tpu_memory_metrics() -> list[dict]:
     """Current-process TPU HBM usage as metric dicts ([] off-TPU)."""
     import jax

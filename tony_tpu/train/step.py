@@ -79,9 +79,8 @@ def make_train_step(loss_fn: Callable[..., jax.Array],
         def _batch_shards() -> int:
             """Devices the batch dim is sharded over under the ambient
             mesh (dp*fsdp), 1 when unmeshed."""
-            from tony_tpu.ops.vma import ambient_abstract_mesh
-            mesh = ambient_abstract_mesh()
-            if mesh is None or not mesh.axis_names:
+            mesh = jax.sharding.get_abstract_mesh()
+            if not mesh.axis_names:
                 return 1
             shape = dict(mesh.shape)
             return shape.get("dp", 1) * shape.get("fsdp", 1)

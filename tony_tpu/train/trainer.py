@@ -177,18 +177,24 @@ class Trainer:
 
     def _setup_inner(self) -> None:
         maybe_initialize_distributed()
-        # persistent XLA compile cache ($TONY_JAX_CACHE_DIR, rendered by
-        # the executor from tony.executor.jax-cache-dir): applied before
-        # any jit below, so the Nth identical trainer skips the cold
-        # compile — the warm-bring-up third of the cold-start work
-        from tony_tpu.utils.compilecache import maybe_enable_compile_cache
-        maybe_enable_compile_cache(jax_module=jax)
+        # persistent XLA compile cache (utils/compilecache.py): applied
+        # before any jit below, so the Nth identical trainer skips the
+        # cold compile — the warm-bring-up third of the cold-start work
+        from tony_tpu.utils.compilecache import enable_compile_cache
+        enable_compile_cache(jax)
         # device evidence AFTER distributed init — jax.devices() here
         # would otherwise initialize the local backend first and make a
         # later jax.distributed.initialize() raise on multi-worker runs
-        LOG.info("devices: %d x %s (backend=%s)", jax.device_count(),
-                 getattr(jax.devices()[0], "device_kind", "?"),
-                 jax.default_backend())
+        from tony_tpu.train.metrics import log_devices
+        log_devices(LOG)
+        # MFU against a peak the table knows, or none: the CPU reports no
+        # MFU, and an accelerator the table does not know fails HERE, at
+        # setup, not at the first log boundary
+        device = jax.local_devices()[0]
+        self._peak_flops = 0.0
+        if device.platform != "cpu" and self.config.flops_per_token > 0:
+            from tony_tpu.observability.perf import peak_flops
+            self._peak_flops = peak_flops(device)
         self._maybe_start_profiler()
         from tony_tpu.train.metrics import TpuMetricsReporter
         self._metrics_reporter = TpuMetricsReporter()
@@ -252,6 +258,15 @@ class Trainer:
             params = jax.device_put(
                 params, NamedSharding(self.mesh, PartitionSpec()))
         self.params = params
+        # where the largest parameter lives: "everything on the first
+        # chip" must be readable from the log of a multi-chip run
+        name, big = max(jax.tree_util.tree_leaves_with_path(params),
+                        key=lambda kv: kv[1].size)
+        LOG.info("param %s %s: shards %s, sharded over %d of %d mesh "
+                 "devices", jax.tree_util.keystr(name), big.shape,
+                 big.sharding.shard_shape(big.shape),
+                 len({str(s.index) for s in big.addressable_shards}),
+                 self.mesh.devices.size)
         # explicit out_shardings on the optimizer init: propagation alone
         # may leave the masters/Adam moments replicated (observed on the
         # v5p AOT compile) — at 8B that's the difference between fitting
@@ -267,8 +282,7 @@ class Trainer:
             pspecs = jax.tree.map(lambda _: PartitionSpec(), self.params)
         ospecs = opt_state_specs(
             jax.eval_shape(self.optimizer.init, self.params), pspecs)
-        from tony_tpu.ops.vma import use_mesh
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             opt_state = jax.jit(
                 self.optimizer.init,
                 out_shardings=jax.tree.map(
@@ -396,11 +410,11 @@ class Trainer:
                          / max(1, jax.device_count()))
                 out.append({"name": "TRAIN_TOKENS_PER_SEC_PER_CHIP",
                             "value": round(tok_s, 2)})
-                if self.config.flops_per_token > 0:
+                if getattr(self, "_peak_flops", 0.0) > 0:
                     out.append({"name": "TRAIN_MFU_PCT",
                                 "value": round(mfu_pct(
                                     tok_s, self.config.flops_per_token,
-                                    jax.local_devices()[0]), 3)})
+                                    peak=self._peak_flops), 3)})
         self._perf_t0, self._perf_step0 = now, self.step
         self._perf_phases0 = phases
         return out
@@ -472,12 +486,13 @@ class Trainer:
         if self.step < cfg.num_steps:
             self.ledger.transition("compile" if first_span is not None
                                    else "train_step")
-        from tony_tpu.ops.vma import use_mesh
         try:
-            with use_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 t0 = time.monotonic()
                 while self.step < cfg.num_steps:
                     batch = next(self._global_data_iter)
+                    if first_span is not None:
+                        self._log_step_kernels(batch)
                     self.params, self.opt_state, loss = self.train_step(
                         self.params, self.opt_state, batch)
                     self.step += 1
@@ -492,6 +507,12 @@ class Trainer:
                             tokens_in_batch
                         self._tokens_per_batch = tokens_in_batch(batch)
                     if first_span is not None:
+                        # a jit dispatch returns once the program is
+                        # compiled and enqueued: trace + compile seconds
+                        # (or the persistent cache's load), not the step
+                        LOG.info("first step dispatched in %.1fs "
+                                 "(trace + compile)",
+                                 time.monotonic() - t0)
                         tracer.end(first_span,
                                    attrs={"step": self.step})
                         first_span = None
@@ -525,6 +546,13 @@ class Trainer:
                     pending = None
                 if loss is not None:   # loop may no-op on exact resume
                     self.last_loss = float(loss)
+                    from tony_tpu.train.metrics import peak_hbm_bytes
+                    peak = peak_hbm_bytes()
+                    if peak is not None:
+                        LOG.info("peak HBM in use %.2f GiB of %.2f GiB "
+                                 "(memory_stats peak_bytes_in_use, "
+                                 "bytes_limit)", peak[0] / 2 ** 30,
+                                 peak[1] / 2 ** 30)
                 if cfg.checkpoint_dir and loss is not None:
                     self._checkpoint(final=True)
                 elif self._checkpointer is not None:
@@ -577,6 +605,24 @@ class Trainer:
                 LOG.debug("final goodput report failed", exc_info=True)
             self._metrics_reporter.close()
         return self.last_loss
+
+    def _log_step_kernels(self, batch) -> None:
+        """Say which attention / RMSNorm branch the step about to run was
+        lowered with: the Pallas TPU kernels by name (ops/attention.py
+        kernel_counts), all zero where the jnp paths were taken. The jit
+        keeps the trace, so the first dispatch does not pay it again."""
+        from tony_tpu.ops.attention import kernel_counts
+        step = getattr(self.train_step, "_fn", self.train_step)
+        # a CPU lowering holds no TPU kernel by construction
+        # (lax.platform_dependent): nothing to show, and every test
+        # trainer would pay a second lowering for a line of zeros
+        if jax.default_backend() == "cpu" or not hasattr(step, "lower"):
+            return
+        text = step.lower(self.params, self.opt_state, batch).as_text()
+        LOG.info("train step lowered for %s with Pallas kernels: %s",
+                 jax.default_backend(),
+                 " ".join(f"{k}={v}" for k, v in
+                          kernel_counts(text).items()))
 
     def _maybe_start_profiler(self) -> None:
         """Serve the JAX profiler on the TB port the executor reserved and

@@ -9,8 +9,10 @@ compiler degrades gracefully instead of failing the job.
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
+import platform
 import shutil
 import subprocess
 import threading
@@ -24,19 +26,45 @@ NATIVE_DIR = os.path.join(
 
 _build_lock = threading.Lock()
 _build_failed = False
+_build_checked = False
+_STAMP = os.path.join(NATIVE_DIR, "build", ".stamp")
+
+
+def _source_stamp() -> str:
+    """What a build in src/native/build/ must have been made from to be
+    used: the committed sources and Makefile, byte for byte, on this
+    machine's architecture and C library. The build directory is not
+    committed, but it travels with a copied tree — a binary built from
+    older sources, or on another machine, must not be preferred over the
+    sources that are here."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(NATIVE_DIR)):
+        if name.endswith(".cc") or name == "Makefile":
+            h.update(name.encode())
+            with open(os.path.join(NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+    h.update(repr((platform.machine(), platform.libc_ver())).encode())
+    return h.hexdigest()
 
 
 def native_binary(name: str) -> Optional[str]:
     """Absolute path of a built native helper, building all helpers on
     first use; None if the toolchain is unavailable or the build fails."""
-    global _build_failed
+    global _build_failed, _build_checked
     path = os.path.join(NATIVE_DIR, "build", name)
-    if os.path.isfile(path) and os.access(path, os.X_OK):
-        return path
     with _build_lock:
         if _build_failed:
             return None
-        if os.path.isfile(path):  # built while we waited for the lock
+        if _build_checked:
+            return path if os.path.isfile(path) else None
+        stamp = _source_stamp()
+        try:
+            with open(_STAMP, encoding="utf-8") as f:
+                fresh = f.read().strip() == stamp
+        except OSError:
+            fresh = False
+        if fresh and os.path.isfile(path) and os.access(path, os.X_OK):
+            _build_checked = True
             return path
         if shutil.which("make") is None or shutil.which("g++") is None:
             LOG.info("no native toolchain; using pure-Python fallbacks")
@@ -44,16 +72,32 @@ def native_binary(name: str) -> Optional[str]:
             return None
         try:
             # serializing the one-time native build IS this lock's
-            # purpose; no control-plane path shares it
+            # purpose; no control-plane path shares it. Built into a
+            # private directory and renamed into place, so a concurrent
+            # process never runs a half-written binary
+            tmp = f"build.{os.getpid()}"
             # tony: disable=no-blocking-under-lock -- build lock, not control plane
-            subprocess.run(["make", "-s"], cwd=NATIVE_DIR, check=True,
+            subprocess.run(["make", "-s", "-B",
+                            f"BUILD={tmp}"], cwd=NATIVE_DIR, check=True,
                            capture_output=True, timeout=120)
-        except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
-            out = getattr(e, "stderr", b"") or b""
+            with open(os.path.join(NATIVE_DIR, tmp, ".stamp"), "w",
+                      encoding="utf-8") as f:
+                f.write(stamp + "\n")
+            build = os.path.join(NATIVE_DIR, "build")
+            os.makedirs(build, exist_ok=True)
+            for built in sorted(os.listdir(os.path.join(NATIVE_DIR, tmp)),
+                                key=lambda n: n == ".stamp"):
+                os.replace(os.path.join(NATIVE_DIR, tmp, built),
+                           os.path.join(build, built))   # stamp last
+            os.rmdir(os.path.join(NATIVE_DIR, tmp))
+        except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
+                OSError) as e:
+            out = getattr(e, "stderr", b"") or str(e).encode()
             LOG.warning("native build failed, using Python fallbacks: %s",
                         out.decode(errors="replace")[-500:])
             _build_failed = True
             return None
+        _build_checked = True
     return path if os.path.isfile(path) else None
 
 
