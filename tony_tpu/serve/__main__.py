@@ -22,11 +22,14 @@ flags, no registration, metrics exposed on ``/v1/metrics`` only.
 from __future__ import annotations
 
 import argparse
+import json
 import logging
 import os
 import signal
 import sys
 import threading
+import time
+from contextlib import contextmanager
 
 LOG = logging.getLogger(__name__)
 
@@ -85,9 +88,11 @@ def build_arg_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _load_model(args):
+def _init_runtime() -> None:
+    """First contact with the backend, forced here so that it is timed on
+    its own (`runtime_init` of the SERVE_STARTUP line) and not inside
+    whichever of the model's first device calls would have paid for it."""
     import jax
-    import jax.numpy as jnp
 
     # persistent XLA compile cache (utils/compilecache.py): applied
     # before any device work so replica N skips replica 0's cold
@@ -95,8 +100,59 @@ def _load_model(args):
     from tony_tpu.utils.compilecache import enable_compile_cache
     enable_compile_cache(jax)
     # the trainer's device line: what tells a TPU replica from a CPU one
+    # (asking for the devices is what starts the runtime)
     from tony_tpu.train.metrics import log_devices
     log_devices(LOG)
+
+
+def _process_age_s():
+    """Seconds since the operating system started this process (Linux
+    /proc), None where there is no such record: what interpreter start
+    and imports cost before main() ran."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            # the fields after the parenthesised command name; the 22nd
+            # of the line is the start time in clock ticks since boot
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            uptime_s = float(f.read().split()[0])
+        return uptime_s - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class _Startup:
+    """The replica's start-up, phase by phase: a `serve_startup` lifecycle
+    span with one child a phase on the job's waterfall, and the one
+    SERVE_STARTUP line a reader of the container log finds."""
+
+    def __init__(self, recorder, t0: float):
+        self._t0 = t0                       # main()'s entry
+        self._recorder = recorder
+        self._root = recorder.start("serve_startup")
+        self._parts: dict = {}
+
+    @contextmanager
+    def phase(self, name: str):
+        t = time.monotonic()
+        with self._recorder.span(name, parent=self._root):
+            yield
+        self._parts[f"{name}_s"] = time.monotonic() - t
+
+    def finish(self) -> None:
+        line = dict(self._parts, total_s=time.monotonic() - self._t0)
+        age = _process_age_s()
+        if age is not None:
+            line["process_age_s"] = age
+        self._recorder.end(self._root, attrs={
+            k: round(v, 3) for k, v in line.items()})
+        # log-ok: raw stdout like SERVING_UP below, for the same readers
+        print("SERVE_STARTUP " + json.dumps(line), flush=True)
+
+
+def _load_model(args):
+    import jax
+    import jax.numpy as jnp
 
     from tony_tpu.models.moe import is_moe_preset
 
@@ -191,6 +247,7 @@ def _migrated_reporter(env):
 
 
 def main(argv=None) -> int:
+    t_main = time.monotonic()
     # structured JSON-lines logging (stamped with the serving task's
     # identity from the container env; TONY_LOG_PLAIN=1 opts out)
     from tony_tpu.observability.logs import configure_structured_logging
@@ -199,6 +256,17 @@ def main(argv=None) -> int:
     env = os.environ
 
     from tony_tpu import constants as C
+    # lifecycle spans of this task on the job's waterfall (only when a
+    # trace context was rendered into this container's env — standalone
+    # runs record locally and push nothing): the start-up phases. Per-
+    # request traces are reqtrace's, pulled from /v1/traces.
+    from tony_tpu.observability.trace import SpanRecorder
+    recorder = SpanRecorder.from_env(
+        env,
+        task_id=(f"{env.get(C.JOB_NAME, '')}:{env.get(C.TASK_INDEX, '0')}"
+                 if env.get(C.JOB_NAME) else ""),
+        attempt=int(env.get(C.TASK_ATTEMPT, "0") or 0))
+    startup = _Startup(recorder, t_main)
     from tony_tpu.conf import TonyConfiguration, keys as K
     conf_path = env.get(C.TONY_CONF_PATH, "")
     conf = (TonyConfiguration.read(conf_path)
@@ -220,7 +288,14 @@ def main(argv=None) -> int:
         port = conf.get_int(K.SERVING_PORT, 0) \
             or int(env.get(C.SERVING_PORT, "0") or 0)
 
-    params, config = _load_model(args)
+    with startup.phase("runtime_init"):
+        _init_runtime()
+    with startup.phase("load_model"):
+        # each phase waits for its own device work, so that the next one
+        # is not billed for it
+        import jax
+        params, config = _load_model(args)
+        jax.block_until_ready(params)
     # capped at the model's max_seq on BOTH paths (flag and conf) — the
     # documented contract; an oversized ask serves at max_seq instead of
     # crashing the container
@@ -245,53 +320,20 @@ def main(argv=None) -> int:
     migrate_to = args.migrate_to or conf.get(K.SERVING_MIGRATE_TO, "") or ""
     migrate_targets = [u.strip() for u in migrate_to.split(",")
                        if u.strip()]
-    from tony_tpu.serve.engine import ContinuousBatchingEngine
-    from tony_tpu.serve.frontend import ServeFrontend
-    engine = ContinuousBatchingEngine(
-        params, config, n_slots=slots, token_budget=token_budget,
-        queue_depth=queue_depth, temperature=args.temperature,
-        top_k=args.top_k, top_p=args.top_p,
-        eos_id=args.eos_id if args.eos_id >= 0 else None,
-        quant_cache=args.quant_cache,
-        weights_generation=weights_generation,
-        prefix_sharing=prefix_sharing, kv_page_size=kv_page_size,
-        kv_pages=kv_pages, role=role)
-    # per-request trace spans: each finished request becomes a
-    # `serve_request` span (queue_wait/prefill/decode attrs) on the same
-    # job waterfall the trainer's phases render into. Only when a trace
-    # context was rendered into this container's env — standalone runs
-    # record nothing.
-    from tony_tpu.observability.trace import SpanRecorder
-    recorder = SpanRecorder.from_env(
-        env,
-        task_id=(f"{env.get(C.JOB_NAME, '')}:{env.get(C.TASK_INDEX, '0')}"
-                 if env.get(C.JOB_NAME) else ""),
-        attempt=int(env.get(C.TASK_ATTEMPT, "0") or 0))
-    if recorder.enabled:
-        import time as _time
-
-        def _record_request_span(handle) -> None:
-            dur_s = max(0.0, (handle.finished_at or 0)
-                        - handle.submitted_at)
-            now_ms = int(_time.time() * 1000)
-            attrs = {"request_id": handle.request_id,
-                     "tokens": len(handle.tokens),
-                     "finish_reason": handle.finish_reason or ""}
-            # the lifecycle span carries the request trace id, so a
-            # job-waterfall span links to its distributed request trace
-            trace_ctx = getattr(handle, "trace_ctx", None)
-            if trace_ctx is not None:
-                attrs["request_trace_id"] = trace_ctx.trace_id
-            for key, value in (("queue_wait_ms", handle.queue_wait_s),
-                               ("prefill_ms", handle.prefill_s),
-                               ("decode_ms", handle.decode_s)):
-                if value is not None:
-                    attrs[key] = round(value * 1000.0, 3)
-            recorder.record_complete(
-                "serve_request", now_ms - int(dur_s * 1000), now_ms,
-                attrs=attrs)
-
-        engine.on_request_finished = _record_request_span
+    with startup.phase("engine_init"):
+        # the shared cache's allocation
+        from tony_tpu.serve.engine import ContinuousBatchingEngine
+        from tony_tpu.serve.frontend import ServeFrontend
+        engine = ContinuousBatchingEngine(
+            params, config, n_slots=slots, token_budget=token_budget,
+            queue_depth=queue_depth, temperature=args.temperature,
+            top_k=args.top_k, top_p=args.top_p,
+            eos_id=args.eos_id if args.eos_id >= 0 else None,
+            quant_cache=args.quant_cache,
+            weights_generation=weights_generation,
+            prefix_sharing=prefix_sharing, kv_page_size=kv_page_size,
+            kv_pages=kv_pages, role=role)
+        jax.block_until_ready(engine._cache)
 
     # request-scoped distributed tracing (observability/reqtrace.py):
     # tail-sampled per-request hop traces, pull-exported on /v1/traces
@@ -313,15 +355,17 @@ def main(argv=None) -> int:
         enabled=conf.get_bool(K.SERVING_TRACE_ENABLED, True))
     install_engine_tracing(engine, collector)
 
-    engine.start()
-    frontend = ServeFrontend(engine, port=port, host=args.host,
-                             migrate_targets=migrate_targets,
-                             on_migrated=_migrated_reporter(env),
-                             collector=collector)
-    frontend.start()
+    with startup.phase("frontend_start"):
+        engine.start()
+        frontend = ServeFrontend(engine, port=port, host=args.host,
+                                 migrate_targets=migrate_targets,
+                                 on_migrated=_migrated_reporter(env),
+                                 collector=collector)
+        frontend.start()
 
     from tony_tpu.utils.common import current_host
     url = f"http://{current_host()}:{frontend.port}"
+    startup.finish()
     # log-ok: greppable bring-up marker on RAW stdout (e2e tests + bench
     # drivers grep for it; it must not be wrapped in a JSON log line)
     print(f"SERVING_UP {url}", flush=True)

@@ -75,6 +75,7 @@ from tony_tpu.models.generate import (
     _sample, _warn_moe_below_capacity, decode_step, prefill,
 )
 from tony_tpu.models.llama import LlamaConfig, Params
+from tony_tpu.observability.spans import Phases, span
 from tony_tpu.serve import kvcache as kvc
 
 LOG = logging.getLogger(__name__)
@@ -239,6 +240,20 @@ class EngineStats:
     # (prefill role) / adopted from a prefill replica (decode role)
     migrated_out: int = 0
     migrated_in: int = 0
+    # the loop's own counters (cumulative): decode steps dispatched, the
+    # active slots summed over them (their ratio is the mean batch a step
+    # carried), and requests admitted into a slot (prefilled or migrated
+    # in)
+    decode_steps_total: int = 0
+    decode_slot_steps_total: int = 0
+    admissions_total: int = 0
+    # per decode step, the host's share of the gap between two steps:
+    # from the previous step's tokens landing on the host to this step's
+    # dispatch returning, less the admissions in between (`prefill_s`
+    # holds those, device and host together). No sample for the first
+    # step after the engine sat idle.
+    step_host_s: collections.deque = field(
+        default_factory=lambda: collections.deque(maxlen=2048))
 
 
 def _percentile(samples, q: float) -> Optional[float]:
@@ -421,9 +436,14 @@ class ContinuousBatchingEngine:
         except ValueError:
             self._test_decode_delay_s = 0.0
         self.stats = EngineStats()
+        # step() calls so far (the `step` attribute of the loop's spans),
+        # and when the last decode step's tokens landed on the host (the
+        # anchor of stats.step_host_s; None while nothing decodes)
+        self._steps = 0
+        self._tokens_landed_at: Optional[float] = None
         # observability hook: called (outside the engine lock) with each
-        # RequestHandle as it finishes — serve/__main__ turns these into
-        # per-request trace spans on the job waterfall
+        # RequestHandle as it finishes — serve/frontend turns these into
+        # request-trace hops
         self.on_request_finished: Optional[callable] = None
 
     def _empty_cache(self) -> dict[str, jax.Array]:
@@ -638,69 +658,109 @@ class ContinuousBatchingEngine:
         """One engine iteration: reap cancelled slots, admit as many queued
         requests as there are free slots, then decode every active slot one
         token. Returns True when any work happened (the loop's idle
-        signal)."""
-        reaped = False
-        for slot in self._slots:
-            if slot.active and slot.handle.cancelled.is_set():
-                self._finish_slot(slot, "cancelled", time.monotonic())
-                reaped = True
-        admitted = self._admit_pending() or reaped
-        active = [s for s in self._slots if s.active]
-        if not active:
-            return admitted
-        self._key, step_key = jax.random.split(self._key)
-        nxt, self._cache = _decode_sample_step(
-            self.params, self.config, self._cache,
-            jnp.asarray(self._tokens_np), jnp.asarray(self._pos_np),
-            step_key, self.temperature, self.top_k, self.top_p)
-        nxt_np = np.asarray(jax.device_get(nxt))
-        if self._test_decode_delay_s > 0:
-            # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
-            # decode by a fixed per-step delay — the slow-hop-attribution
-            # e2e's guilty replica
-            time.sleep(self._test_decode_delay_s)
-        now = time.monotonic()
-        for slot in active:
-            token = int(nxt_np[slot.index])
-            slot.pos += 1
-            self._pos_np[slot.index] = slot.pos
-            self._tokens_np[slot.index] = token
-            slot.emitted += 1
-            slot.handle._push(token, now)
+        signal).
+
+        The iteration is tiled by `tony.engine.*` spans on the profiler's
+        clock (observability/spans.py; docs/OBSERVABILITY.md lists them):
+        with a profile open, every device-idle instant falls in a named
+        phase of the host's work."""
+        self._steps += 1
+        with Phases("tony.engine.step", step=self._steps) as ph:
+            ph.enter("tony.engine.reap")
+            reaped = False
+            for slot in self._slots:
+                if slot.active and slot.handle.cancelled.is_set():
+                    self._finish_slot(slot, "cancelled", time.monotonic())
+                    reaped = True
+            ph.leave()
+            before = self.stats.admissions_total
+            t_admit = time.monotonic()
+            admitted = self._admit_pending() or reaped
+            admit_s = time.monotonic() - t_admit
+            active = [s for s in self._slots if s.active]
+            ph.note(active=len(active),
+                    admitted=self.stats.admissions_total - before)
+            if not active:
+                self._tokens_landed_at = None
+                return admitted
+            ph.enter("tony.engine.decode.prepare")
+            self._key, step_key = jax.random.split(self._key)
+            tokens = jnp.asarray(self._tokens_np)
+            pos = jnp.asarray(self._pos_np)
+            ph.enter("tony.engine.decode.dispatch")
+            nxt, self._cache = _decode_sample_step(
+                self.params, self.config, self._cache, tokens, pos,
+                step_key, self.temperature, self.top_k, self.top_p)
+            dispatched_at = time.monotonic()
             with self._lock:
-                self.stats.tokens_emitted += 1
-                self.stats.itl_s.append(now - slot.last_emit_at)
-            slot.last_emit_at = now
-            self._maybe_finish(slot, token, now)
-        return True
+                self.stats.decode_steps_total += 1
+                self.stats.decode_slot_steps_total += len(active)
+                if self._tokens_landed_at is not None:
+                    self.stats.step_host_s.append(
+                        dispatched_at - self._tokens_landed_at - admit_s)
+            ph.enter("tony.engine.decode.wait")
+            nxt_np = np.asarray(jax.device_get(nxt))
+            if self._test_decode_delay_s > 0:
+                # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
+                # decode by a fixed per-step delay — the slow-hop-attribution
+                # e2e's guilty replica
+                time.sleep(self._test_decode_delay_s)
+            now = self._tokens_landed_at = time.monotonic()
+            ph.enter("tony.engine.emit")
+            for slot in active:
+                token = int(nxt_np[slot.index])
+                slot.pos += 1
+                self._pos_np[slot.index] = slot.pos
+                self._tokens_np[slot.index] = token
+                slot.emitted += 1
+                slot.handle._push(token, now)
+                with self._lock:
+                    self.stats.tokens_emitted += 1
+                    self.stats.itl_s.append(now - slot.last_emit_at)
+                slot.last_emit_at = now
+                self._maybe_finish(slot, token, now)
+            ph.enter("tony.engine.release")
+            # the step's device arrays die here, inside a leaf, and not a
+            # moment later at the return: freeing a device buffer releases
+            # the GIL, and the handler threads the pushes just woke then
+            # take it in turn before the loop gets it back (on the chip
+            # that wait was the longest piece of a step's host time, and
+            # lay between two steps, under no span)
+            del nxt, tokens, pos, step_key
+            return True
 
     def _admit_pending(self) -> bool:
         admitted = False
-        while True:
+        while self._pending:      # a peek: the dequeue below holds the lock
             free = next((s for s in self._slots if not s.active), None)
             if free is None:
-                return admitted
-            with self._lock:
-                if not self._pending:
-                    return admitted
-                handle = self._pending.popleft()
-                self._pending_tokens -= (len(handle.prompt)
-                                         + handle.max_new_tokens)
-            if handle.cancelled.is_set():
-                # dropped while still queued: no prefill is ever paid
-                handle._finish("cancelled", time.monotonic())
-                admitted = True
-                continue
-            if handle.install is not None:
-                self._admit_migrated(free, handle)
-            else:
-                self._admit(free, handle)
+                break
+            with Phases("tony.engine.admit") as ph:
+                ph.enter("tony.engine.admit.prepare")
+                with self._lock:
+                    if not self._pending:
+                        break
+                    handle = self._pending.popleft()
+                    self._pending_tokens -= (len(handle.prompt)
+                                             + handle.max_new_tokens)
+                ph.set(request_id=handle.request_id)
+                ph.note(prompt_tokens=len(handle.prompt), slot=free.index)
+                if handle.cancelled.is_set():
+                    # dropped while still queued: no prefill is ever paid
+                    handle._finish("cancelled", time.monotonic())
+                elif handle.install is not None:
+                    self._admit_migrated(free, handle, ph)
+                else:
+                    self._admit(free, handle, ph)
             admitted = True
+        return admitted
 
-    def _admit(self, slot: _Slot, handle: RequestHandle) -> None:
+    def _admit(self, slot: _Slot, handle: RequestHandle,
+               ph: Phases) -> None:
         # phase stamps: the queue-wait phase ends the moment a free slot
         # dequeued this request; everything until the first sampled token
-        # lands on the host is the prefill phase
+        # lands on the host is the prefill phase. `ph` is the admission's
+        # span, its `prepare` leaf open since before the dequeue.
         t_dequeue = time.monotonic()
         handle.queue_wait_s = t_dequeue - handle.submitted_at
         self._key, req_key = jax.random.split(self._key)
@@ -728,20 +788,16 @@ class ContinuousBatchingEngine:
                 start = depth * pool.page_size
                 handle.kv_matched_tokens = start
                 handle.kv_match_s = time.monotonic() - t_dequeue
-            suffix = jnp.asarray(handle.prompt[start:], jnp.int32)
-            tok0_dev, self._cache = _admit_step(
-                self.params, self.config, self._cache, suffix,
-                jnp.int32(slot.index), req_key, self.temperature,
-                self.top_k, self.top_p, self.quant_cache,
-                jnp.int32(start), True)
-        else:
-            prompt = jnp.asarray(handle.prompt, jnp.int32)
-            tok0_dev, self._cache = _admit_step(
-                self.params, self.config, self._cache, prompt,
-                jnp.int32(slot.index), req_key, self.temperature,
-                self.top_k, self.top_p, self.quant_cache, jnp.int32(0),
-                False)
+        prompt = jnp.asarray(handle.prompt[start:], jnp.int32)
+        slot_dev, start_dev = jnp.int32(slot.index), jnp.int32(start)
+        ph.enter("tony.engine.admit.dispatch")
+        tok0_dev, self._cache = _admit_step(
+            self.params, self.config, self._cache, prompt, slot_dev,
+            req_key, self.temperature, self.top_k, self.top_p,
+            self.quant_cache, start_dev, pool is not None)
+        ph.enter("tony.engine.admit.wait")
         tok0 = int(jax.device_get(tok0_dev))
+        ph.enter("tony.engine.admit.book")
         if pool is not None:
             # the slot now holds the full prompt K/V: seal the complete
             # blocks the index lacks so the NEXT sharer hits, then
@@ -766,6 +822,7 @@ class ContinuousBatchingEngine:
         self._tokens_np[slot.index] = tok0
         handle._push(tok0, now)
         with self._lock:
+            self.stats.admissions_total += 1
             self.stats.tokens_emitted += 1
             self.stats.ttft_s.append(now - handle.submitted_at)
             self.stats.queue_wait_s.append(handle.queue_wait_s)
@@ -842,7 +899,8 @@ class ContinuousBatchingEngine:
                 "pos": int(slot.pos), "tok0": int(tok0), "emitted": 1}
         return {"meta": meta, "leaves": leaves}
 
-    def _admit_migrated(self, slot: _Slot, handle: RequestHandle) -> None:
+    def _admit_migrated(self, slot: _Slot, handle: RequestHandle,
+                        ph: Phases) -> None:
         """Install a migrated-in request: pad the payload rows to the
         full budget, one fixed-shape install_rows, resume decode at pos.
         tok0 was already streamed to the client by the prefill replica —
@@ -859,8 +917,10 @@ class ContinuousBatchingEngine:
             full = np.zeros((l, 1, h, s, d), leaf.dtype)
             full[:, 0, :, :pos, :] = leaf
             rows[name] = jnp.asarray(full)
-        self._cache = kvc.install_rows(self._cache, rows,
-                                       jnp.int32(slot.index))
+        slot_dev = jnp.int32(slot.index)
+        ph.enter("tony.engine.admit.dispatch")
+        self._cache = kvc.install_rows(self._cache, rows, slot_dev)
+        ph.enter("tony.engine.admit.book")
         now = time.monotonic()
         handle.prefill_s = now - t_dequeue
         handle.admitted_at = now
@@ -874,6 +934,7 @@ class ContinuousBatchingEngine:
             self.stats.queue_wait_s.append(handle.queue_wait_s)
             self.stats.prefill_s.append(handle.prefill_s)
             self.stats.migrated_in += 1
+            self.stats.admissions_total += 1
         LOG.debug("installed migrated request %d into slot %d (pos %d)",
                   handle.request_id, slot.index, pos)
         if slot.emitted >= handle.max_new_tokens:
@@ -929,8 +990,9 @@ class ContinuousBatchingEngine:
                 LOG.exception("engine step failed")    # wedge the server
                 busy = False
             if not busy:
-                self._work.wait(timeout=0.02)
-                self._work.clear()
+                with span("tony.engine.idle_wait"):
+                    self._work.wait(timeout=0.02)
+                    self._work.clear()
         beacon.idle()
 
     def stop(self) -> None:
@@ -982,6 +1044,10 @@ class ContinuousBatchingEngine:
                 "role": self.role,
                 "migrated_out_total": self.stats.migrated_out,
                 "migrated_in_total": self.stats.migrated_in,
+                "decode_steps_total": self.stats.decode_steps_total,
+                "decode_slot_steps_total":
+                    self.stats.decode_slot_steps_total,
+                "admissions_total": self.stats.admissions_total,
             }
             if self.kv_pool is not None:
                 snap.update(self.kv_pool.stats_fields())
@@ -997,6 +1063,10 @@ class ContinuousBatchingEngine:
             _phase_percentiles(snap, "prefill_s", self.stats.prefill_s)
             _phase_percentiles(snap, "decode_ms_per_token",
                                self.stats.itl_s, scale=1000.0)
+            # the host's share of the gap between two decode steps
+            # (/v1/metrics only: the counterpart of the loop's spans)
+            _phase_percentiles(snap, "step_host_ms",
+                               self.stats.step_host_s, scale=1000.0)
             return snap
 
     def metrics(self) -> list[dict]:

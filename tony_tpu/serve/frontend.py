@@ -49,11 +49,13 @@ import threading
 import time
 import urllib.error
 import urllib.request
+from functools import partial
 from http.server import BaseHTTPRequestHandler
 from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from tony_tpu.observability import reqtrace
+from tony_tpu.observability.spans import span
 from tony_tpu.serve import kvcache as kvc
 from tony_tpu.serve.engine import (
     BudgetExceededError, ContinuousBatchingEngine, DrainingError,
@@ -352,6 +354,15 @@ class _Handler(BaseHTTPRequestHandler):
             raise ValueError("request body must be a JSON object")
         return body
 
+    def _write_chunk(self, request_id: int, obj) -> None:
+        """One JSON line of a chunked body. `tony.frontend.write` is the
+        handler threads' side of any GIL hand-off with the engine's loop:
+        on the profiler's clock, beside the loop's tony.engine.* spans."""
+        with span("tony.frontend.write", request_id=request_id):
+            data = (json.dumps(obj) + "\n").encode("utf-8")
+            self.wfile.write(f"{len(data):x}\r\n".encode("ascii")
+                             + data + b"\r\n")
+
     def _stream(self, handle) -> None:
         """Chunked token stream: one JSON line per token, then the done
         record. A broken client connection just stops the writes — the
@@ -361,10 +372,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Transfer-Encoding", "chunked")
         self.end_headers()
 
-        def chunk(obj) -> None:
-            data = (json.dumps(obj) + "\n").encode("utf-8")
-            self.wfile.write(f"{len(data):x}\r\n".encode("ascii")
-                             + data + b"\r\n")
+        chunk = partial(self._write_chunk, handle.request_id)
 
         try:
             for token in handle.iter_tokens(
@@ -579,10 +587,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Transfer-Encoding", "chunked")
         self.end_headers()
 
-        def chunk(obj) -> None:
-            data = (json.dumps(obj) + "\n").encode("utf-8")
-            self.wfile.write(f"{len(data):x}\r\n".encode("ascii")
-                             + data + b"\r\n")
+        chunk = partial(self._write_chunk, handle.request_id)
 
         try:
             chunk({"token": tok0})
@@ -613,7 +618,7 @@ def install_engine_tracing(engine: ContinuousBatchingEngine,
     and the TTFT-attribution rollup. A migrated-OUT handle is NOT
     finished here — the frontend finishes it after the decode relay so
     its duration is the client-observed total. Chains any hook already
-    installed (serve/__main__'s lifecycle span recorder)."""
+    installed."""
     prev = engine.on_request_finished
 
     def _on_finished(handle) -> None:

@@ -17,6 +17,8 @@ imported, so the lint can run against a broken tree):
   RESERVED_SEGMENTS;
 - reserved segments are respected: `tony.<reserved>.<x>` literals must
   be exact registered keys, never dynamic matches;
+- profiler span names (`tony.engine.*`, `tony.frontend.*`) are not keys
+  and are passed over;
 - every registered key is documented in docs/configuration.md;
 - every registered key constant is referenced somewhere outside keys.py
   (a key nothing reads is dead weight or a rename's orphan).
@@ -33,6 +35,10 @@ from tools.tonylint.engine import Finding, Project, PyFile, Rule
 KEYS_FILE = "tony_tpu/conf/keys.py"
 DOCS_FILE = "docs/configuration.md"
 KEY_LITERAL_RE = re.compile(r"^tony\.[a-z][a-z0-9_.\-]*$")
+# profiler span names (observability/spans.py: `tony.engine.*` on the
+# serving loop's thread, `tony.frontend.*` on its handler threads) share
+# the prefix and are not configuration keys
+SPAN_PREFIXES = ("tony.engine.", "tony.frontend.")
 
 
 class KeyRegistry:
@@ -140,7 +146,8 @@ class ConfigKeyRegistryRule(Rule):
             if pf.relpath == KEYS_FILE:
                 continue
             for line, value in _string_literals(pf):
-                if not KEY_LITERAL_RE.match(value):
+                if not KEY_LITERAL_RE.match(value) \
+                        or value.startswith(SPAN_PREFIXES):
                     continue
                 problem = registry.classify(value)
                 if problem:
