@@ -186,6 +186,68 @@ def test_prefill_and_decode_step_compile_for_1b_proxy(one_chip):
                 < HBM_USABLE)
 
 
+def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
+    """Instructions of the scheduled program, outside any fused
+    computation, whose result has a cache slab's dimensions (in any type,
+    with any leading 1s): each one is a pass over a whole layer of the
+    cache in HBM. Parameters and views (get-tuple-element, bitcast) move
+    nothing and do not count; nor does an int8 cache's slice of a layer's
+    row scales (last dimension 1: 1/32 of the slab's bytes)."""
+    import re
+    dims = ",".join(str(d) for d in slab)
+    shaped = re.compile(r" = \w+\[(1,)*%s\]" % dims)
+    view = re.compile(r" = \S+ (parameter|get-tuple-element|bitcast)\(")
+    found, fused = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            fused = "fused_computation" in head.group(2)
+        elif not fused and shaped.search(line) and not view.search(line):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["per-row", "scalar"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
+        one_chip, quant, per_row):
+    """The serving engine's decode program at the chat-steady cell's
+    shapes (benchmark/configs/mistral-7b-serve.json: Mistral-7B widths, 16
+    layers, 32 slots x 2048 tokens, cache donated). Before PR 27 it held a
+    second whole cache in temporaries (4.83 GB) and made three passes over
+    the cache per token: `dynamic-slice_bitcast_fusion`, `copy` and
+    `dynamic-update-slice`, each with a slab-shaped result, per layer
+    (41 of 58.8 ms a step on the chip). Now the layer loop only reads the
+    cache and the rows are written in place after it."""
+    from tony_tpu.models.llama import LlamaConfig
+    from tony_tpu.serve.engine import _decode_sample_step
+
+    config = LlamaConfig(vocab_size=32000, dim=4096, n_layers=16, n_heads=32,
+                         n_kv_heads=8, ffn_dim=14336, max_seq=4096,
+                         rope_theta=10000.0)
+    slots, budget = 32, 2048
+    slab = (slots, config.n_kv_heads, budget, config.head_dim)
+    kv = jnp.int8 if quant else jnp.bfloat16
+    cache = {name: _sds((config.n_layers,) + slab, kv, one_chip)
+             for name in ("k", "v")}
+    if quant:
+        for name in ("k_scale", "v_scale"):
+            cache[name] = _sds((config.n_layers,) + slab[:-1] + (1,),
+                               jnp.float32, one_chip)
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache.values())
+    compiled = _decode_sample_step.lower(
+        _abstract_params(config, one_chip), config, cache,
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,) if per_row else (), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip), 0.0, 0, 1.0).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256e6, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    assert _slab_results_outside_fusions(compiled.as_text(), slab) == []
+
+
 def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
     """The step chip_smoke.py's train phase runs — the trainer's own
     construction (train/trainer.py: adamw under a warm-up cosine schedule,

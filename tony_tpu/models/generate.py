@@ -9,10 +9,14 @@ with no model code, SURVEY.md §2.3):
 - **Prefill via the training forward pieces**: full causal flash attention
   over the prompt (narrow GQA K/V), capturing each layer's K/V as scan
   outputs.
-- **Decode step**: one token per step; per layer, the new K/V row is
-  `dynamic_update_slice`d into the cache and attention is a masked
-  single-query einsum against the cache, grouped by GQA head group (no
-  K/V repeat materialization — (B, G, rep, d) x (B, G, S, d)).
+- **Decode step**: one token per step (`decode_step`, the W=1 case of
+  `window_logits`). The layer loop only READS the cache: attention is a
+  masked einsum against the rows already there plus the new row straight
+  from registers, grouped by GQA head group (no K/V repeat
+  materialization — (B, G, rep, W, d) x (B, G, S, d)). The new rows of
+  all layers are written once, after the loop, in place
+  (`write_cache_rows`) — no layer's slab is ever sliced out, copied or
+  stacked back.
 - **Sampling**: greedy (temperature 0) or temperature + optional top-k
   via `jax.random.categorical`; an emitted `eos_id` latches and pads the
   remainder with `eos_id`.
@@ -34,7 +38,7 @@ from tony_tpu.models.llama import (
     LlamaConfig, Params, embed_lookup, qkv_proj, rope_tables, swiglu_mlp,
 )
 from tony_tpu.models.quant import (
-    dequantize_layer, maybe_dequantize, quantize_rows,
+    dequantize_layer, dequantize_rows, maybe_dequantize, quantize_rows,
 )
 from tony_tpu.ops.attention import NEG_INF, flash_attention
 from tony_tpu.ops.rmsnorm import rms_norm
@@ -87,58 +91,75 @@ def _warn_moe_below_capacity(config: LlamaConfig, who: str = "decode"
             f"for train/serve parity", stacklevel=3)
 
 
-def _row_update(cache_row, new_row, off):
-    """(Hkv, S, hd), (Hkv, W, hd), scalar — one batch row's cache write."""
-    return lax.dynamic_update_slice_in_dim(cache_row, new_row, off, axis=1)
+def new_cache_rows(k, v, dtype, quant: bool):
+    """What the cache stores for new K/V rows (B, Hkv, W, hd), quantized
+    iff `quant` (an int8 cache) and cast to the cache's `dtype` otherwise.
+    Returns (rows, k_eff, v_eff): `rows` holds one entry per key of the
+    cache dict, k_eff/v_eff are the attention-ready views of those rows —
+    exactly what a read back from the cache would give.
 
-
-def write_cache_rows(kc, vc, scales, k, v, offsets):
-    """Write new K/V rows (B, Hkv, W, hd) into the caches at PER-ROW
-    offsets (B,), quantizing iff `scales` is present ((ksc, vsc) for an
-    int8 cache, None for bf16). Returns (kc, vc, scales', k_eff, v_eff)
-    where k_eff/v_eff are the attention-ready (dequantized) views.
-
-    ONE place for the int8/bf16 cache write+view, shared by decode_step
-    and speculative.window_logits — a scheme change applied to one and
+    ONE place for the int8/bf16 row format, shared by every decode-side
+    caller through `window_logits` — a scheme change applied to one and
     not the other would silently break the greedy-lossless identity."""
-    if scales is None:
-        kc = jax.vmap(_row_update)(kc, k.astype(kc.dtype), offsets)
-        vc = jax.vmap(_row_update)(vc, v.astype(vc.dtype), offsets)
-        return kc, vc, None, kc, vc
-    from tony_tpu.models.quant import dequantize_rows
-    ksc, vsc = scales
+    if not quant:
+        k, v = k.astype(dtype), v.astype(dtype)
+        return {"k": k, "v": v}, k, v
     qk, k_s = quantize_rows(k)
     qv, v_s = quantize_rows(v)
-    kc = jax.vmap(_row_update)(kc, qk, offsets)
-    vc = jax.vmap(_row_update)(vc, qv, offsets)
-    ksc = jax.vmap(_row_update)(ksc, k_s, offsets)
-    vsc = jax.vmap(_row_update)(vsc, v_s, offsets)
-    return (kc, vc, (ksc, vsc),
-            dequantize_rows(kc, ksc), dequantize_rows(vc, vsc))
+    return ({"k": qk, "v": qv, "k_scale": k_s, "v_scale": v_s},
+            dequantize_rows(qk, k_s), dequantize_rows(qv, v_s))
 
 
-def _cache_attention(q, k_cache, v_cache, cur_len: jax.Array,
-                     config: LlamaConfig) -> jax.Array:
-    """Single-position attention against the cache.
+def write_cache_rows(cache, rows, offsets):
+    """Write every layer's new rows {name: (L, B, Hkv, W, d)} into the
+    cache {name: (L, B, Hkv, S, d)} at PER-ROW offsets (B,): batch row b's
+    W rows land at positions offsets[b]..offsets[b]+W-1 of all L layers.
 
-    q: (B, H, 1, hd); caches: (B, Hkv, S_max, hd); positions >= cur_len
-    are masked. cur_len is a scalar (whole-batch decode) or (B,) per-row
-    lengths (continuous batching: every slot at its own position).
-    GQA grouped einsum — K/V never repeated."""
-    b, nh, _, hd = q.shape
-    nkv = k_cache.shape[1]
+    One `dynamic_update_slice` per batch row into the whole buffer, in a
+    loop that carries it — with the cache donated (or carried by an outer
+    loop) each is an in-place write of L x Hkv x W rows, and nothing
+    cache-sized is sliced, copied or stacked. A scatter would do it in one
+    op, but forces a cache layout that brings slab copies back."""
+    def write_row(b, cache):
+        return {name: lax.dynamic_update_slice(
+                    arr, lax.dynamic_slice_in_dim(rows[name], b, 1, axis=1),
+                    (0, b, 0, offsets[b], 0))
+                for name, arr in cache.items()}
+
+    return lax.fori_loop(0, offsets.shape[0], write_row, cache)
+
+
+def _cache_attention(q, k_cache, v_cache, k_new, v_new, lens) -> jax.Array:
+    """Attention of W new positions against the cache AND themselves.
+
+    q: (B, H, W, hd) for window rows at batch row b's positions lens[b]..
+    lens[b]+W-1; caches (B, Hkv, S, hd) are only READ, and only columns
+    < lens[b] count (whatever lies beyond is stale and masked); k_new/
+    v_new (B, Hkv, W, hd) are the window's own rows, attended from
+    registers with the within-window causal mask — they are not in the
+    cache yet (`write_cache_rows` stores them after the layer loop).
+    One softmax over both sets of scores: every position attends to
+    exactly its rows 0..position. GQA grouped einsum — K/V never
+    repeated."""
+    b, nh, w, hd = q.shape
+    nkv, s = k_cache.shape[1], k_cache.shape[2]
     rep = nh // nkv
-    if getattr(cur_len, "ndim", 0) == 1:
-        cur_len = cur_len[:, None, None, None]            # (B,1,1,1)
-    qg = q.reshape(b, nkv, rep, hd).astype(jnp.float32) * hd ** -0.5
-    scores = jnp.einsum("bgrd,bgsd->bgrs", qg,
-                        k_cache.astype(jnp.float32))      # (B,G,rep,S)
-    mask = lax.broadcasted_iota(jnp.int32, scores.shape, 3) < cur_len
-    scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bgrs,bgsd->bgrd", probs,
-                     v_cache.astype(jnp.float32))         # (B,G,rep,hd)
-    return out.reshape(b, nh, 1, hd).astype(q.dtype)
+    qg = q.reshape(b, nkv, rep, w, hd).astype(jnp.float32) * hd ** -0.5
+    old = jnp.einsum("bgrwd,bgsd->bgrws", qg,
+                     k_cache.astype(jnp.float32))      # (B,G,rep,W,S)
+    col = lax.broadcasted_iota(jnp.int32, old.shape, 4)
+    old = jnp.where(col < lens[:, None, None, None, None], old, NEG_INF)
+    new = jnp.einsum("bgrwd,bgud->bgrwu", qg,
+                     k_new.astype(jnp.float32))        # (B,G,rep,W,W)
+    causal = (lax.broadcasted_iota(jnp.int32, new.shape, 4)
+              <= lax.broadcasted_iota(jnp.int32, new.shape, 3))
+    new = jnp.where(causal, new, NEG_INF)
+    probs = jax.nn.softmax(jnp.concatenate([old, new], axis=-1), axis=-1)
+    out = (jnp.einsum("bgrws,bgsd->bgrwd", probs[..., :s],
+                      v_cache.astype(jnp.float32))
+           + jnp.einsum("bgrwu,bgud->bgrwd", probs[..., s:],
+                        v_new.astype(jnp.float32)))    # (B,G,rep,W,hd)
+    return out.reshape(b, nh, w, hd).astype(q.dtype)
 
 
 def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
@@ -191,6 +212,64 @@ def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
     return logits, cache
 
 
+def window_logits(params: Params, config: LlamaConfig,
+                  cache: dict[str, jax.Array], tokens: jax.Array,
+                  lens: jax.Array
+                  ) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Forward a (B, W) token window against per-row cache lengths — THE
+    decode-side forward: `decode_step` is its W=1 case, the speculative
+    verify its W=gamma+1 case, so the two cannot drift apart.
+
+    Row b's window occupies positions lens[b]..lens[b]+W-1. Returns
+    (logits (B, W, V), new cache) with the window's K/V written there.
+    The caller owns lens bookkeeping: only advance past positions whose
+    tokens were actually accepted — anything beyond stays invisible to
+    the mask and is overwritten by later windows. An int8 cache
+    (prefill's quant_cache=True) is detected by tree structure — a static
+    property under jit, so both layouts share this function.
+
+    The layer loop only READS the cache (its scan `xs`): each layer
+    attends to the rows below `lens` plus the window's own rows from
+    registers, and hands the new rows out as `ys`. They are written once,
+    after the loop, in place (`write_cache_rows`). Passing updated slabs
+    back through the scan instead costs a slice, a copy and a write-back
+    of every layer's whole slab per token."""
+    quant = "k_scale" in cache
+    b, w = tokens.shape
+    cache_len = cache["k"].shape[3]
+    cos, sin = rope_tables(config, cache_len)
+    positions = lens[:, None] + jnp.arange(w, dtype=lens.dtype)[None, :]
+    x = embed_lookup(params["embed"], tokens, config)   # (B, W, D)
+
+    def body(x, layer_and_cache):
+        layer, c = layer_and_cache
+        # int8-quantized layers dequantize HERE, inside the scan body
+        layer = dequantize_layer(layer)
+        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
+        q, k, v = qkv_proj(h, layer, config)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        rows, k_new, v_new = new_cache_rows(k, v, c["k"].dtype, quant)
+        # dequantized views feed straight into the attention einsums:
+        # XLA fuses the int8 read + row scale into the operand load
+        kc = dequantize_rows(c["k"], c["k_scale"]) if quant else c["k"]
+        vc = dequantize_rows(c["v"], c["v_scale"]) if quant else c["v"]
+        attn = _cache_attention(q, kc, vc, k_new, v_new, lens)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, w, -1)
+        x = x + jnp.einsum("bsh,hd->bsd", attn, layer["wo"])
+        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
+        x = x + _mlp(h, layer, config)
+        return x, rows
+
+    x, rows = lax.scan(body, x, (params["layers"], cache))
+    cache = write_cache_rows(cache, rows, lens)
+    x = rms_norm(x, params["final_norm"], config.norm_eps)
+    logits = jnp.einsum("bwd,dv->bwv", x,
+                        maybe_dequantize(params["output"]),
+                        preferred_element_type=jnp.float32)
+    return logits, cache
+
+
 def decode_step(params: Params, config: LlamaConfig,
                 cache: dict[str, jax.Array], token: jax.Array,
                 pos: jax.Array) -> tuple[jax.Array, dict[str, jax.Array]]:
@@ -198,67 +277,10 @@ def decode_step(params: Params, config: LlamaConfig,
     the token occupies) or (B,) int32 per-row positions — the latter is
     the continuous-batching shape (serve/engine.py), where every batch
     row is an independent request slot at its own sequence position.
-    Returns (logits (B, V), updated cache). An int8 cache (prefill's
-    quant_cache=True) is detected by tree structure — a static property
-    under jit, so both layouts share this function."""
-    quant = "k_scale" in cache
-    cache_len = cache["k"].shape[3]
-    cos, sin = rope_tables(config, cache_len)
-    # per-row positions take the gather form of RoPE (ops/rope.py
-    # `positions`); the scalar path keeps the original dynamic-slice —
-    # both read the identical table rows, so the math is bit-identical
-    per_row = getattr(pos, "ndim", 0) == 1
-    if not per_row:
-        cos_p = lax.dynamic_slice_in_dim(cos, pos, 1, axis=0)
-        sin_p = lax.dynamic_slice_in_dim(sin, pos, 1, axis=0)
-    x = embed_lookup(params["embed"], token[:, None], config)  # (B, 1, D)
-    b = x.shape[0]
-
-    offsets = pos if per_row else jnp.broadcast_to(pos, (b,))
-    cur_len = pos + 1                     # (B,) or scalar — both broadcast
-
-    def body(x, layer_and_cache):
-        if quant:
-            layer, kc, vc, ksc, vsc = layer_and_cache
-        else:
-            layer, kc, vc = layer_and_cache
-            ksc = vsc = None
-        layer = dequantize_layer(layer)
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q, k, v = qkv_proj(h, layer, config)
-        if per_row:
-            q = apply_rope(q, cos, sin, positions=pos[:, None])
-            k = apply_rope(k, cos, sin, positions=pos[:, None])
-        else:
-            q = apply_rope(q, cos_p, sin_p)
-            k = apply_rope(k, cos_p, sin_p)
-        # dequantized views feed straight into the attention einsums:
-        # XLA fuses the int8 read + row scale into the operand load
-        kc, vc, scales, k_eff, v_eff = write_cache_rows(
-            kc, vc, (ksc, vsc) if quant else None, k, v, offsets)
-        if quant:
-            ksc, vsc = scales
-        attn = _cache_attention(q, k_eff, v_eff, cur_len, config)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, 1, -1)
-        x = x + jnp.einsum("bsh,hd->bsd", attn, layer["wo"])
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        x = x + _mlp(h, layer, config)
-        return x, ((kc, vc, ksc, vsc) if quant else (kc, vc))
-
-    if quant:
-        xs = (params["layers"], cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"])
-        x, (ks, vs, kscs, vscs) = lax.scan(body, x, xs)
-        new_cache = {"k": ks, "v": vs, "k_scale": kscs, "v_scale": vscs}
-    else:
-        x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
-        new_cache = {"k": ks, "v": vs}
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bd,dv->bv", x[:, 0],
-                        maybe_dequantize(params["output"]),
-                        preferred_element_type=jnp.float32)
-    return logits, new_cache
+    Returns (logits (B, V), updated cache)."""
+    logits, cache = window_logits(params, config, cache, token[:, None],
+                                  jnp.broadcast_to(pos, token.shape))
+    return logits[:, 0], cache
 
 
 def _sample(logits: jax.Array, temperature: float, top_k: int,

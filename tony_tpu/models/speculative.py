@@ -19,10 +19,13 @@ no inference code, SURVEY §2.3):
   per-row acceptance divergence is handled with per-row cache lengths,
   not dynamic shapes.
 - caches may hold garbage BEYOND each row's length: the attention mask
-  (`col < len + row + 1`) makes stale rows invisible and later rounds
-  simply overwrite them — no rollback pass.
-- per-row cache writes are `vmap`ed `dynamic_update_slice`s (batched
-  start indices), and RoPE uses `apply_rope`'s per-batch positions.
+  (`col < len`, the window's own rows come from registers) makes stale
+  rows invisible and later rounds simply overwrite them — no rollback
+  pass.
+- the windowed forward is `models/generate.window_logits`, the SAME
+  function vanilla decode runs at W=1 (`decode_step`): one attention,
+  one row format, one in-place per-row cache write — the two paths
+  cannot drift apart. RoPE uses `apply_rope`'s per-batch positions.
 - the draft chain deliberately consumes ALL gamma drafted tokens (one
   step more than strictly needed to produce them): that keeps the draft
   cache exactly ONE token behind the target stream in every case, so
@@ -37,91 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tony_tpu.models.generate import _mlp, prefill, write_cache_rows
-from tony_tpu.models.llama import (
-    LlamaConfig, Params, embed_lookup, qkv_proj, rope_tables,
-)
-from tony_tpu.models.quant import dequantize_layer, maybe_dequantize
-from tony_tpu.ops.attention import NEG_INF
-from tony_tpu.ops.rmsnorm import rms_norm
-from tony_tpu.ops.rope import apply_rope
-
-
-def _window_attention(q, k_cache, v_cache, lens, config: LlamaConfig):
-    """q: (B, H, W, hd) for window rows written at per-row offsets
-    `lens`; caches (B, Hkv, S, hd). Window row i of batch b attends to
-    cache cols < lens[b] + i + 1 (prefix + within-window causal)."""
-    b, nh, w, hd = q.shape
-    nkv = k_cache.shape[1]
-    rep = nh // nkv
-    qg = q.reshape(b, nkv, rep, w, hd).astype(jnp.float32) * hd ** -0.5
-    scores = jnp.einsum("bgrwd,bgsd->bgrws", qg,
-                        k_cache.astype(jnp.float32))   # (B,G,rep,W,S)
-    col = lax.broadcasted_iota(jnp.int32, scores.shape, 4)
-    row = lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-    limit = lens[:, None, None, None, None] + row + 1
-    scores = jnp.where(col < limit, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bgrws,bgsd->bgrwd", probs,
-                     v_cache.astype(jnp.float32))
-    return out.reshape(b, nh, w, hd).astype(q.dtype)
-
-
-def window_logits(params: Params, config: LlamaConfig,
-                  cache: dict[str, jax.Array], tokens: jax.Array,
-                  lens: jax.Array
-                  ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """Forward a (B, W) token window against per-row cache lengths.
-
-    Writes the window's K/V at row b's positions lens[b]..lens[b]+W-1
-    and returns (logits (B, W, V), new cache). The caller owns lens
-    bookkeeping: only advance past positions whose tokens were actually
-    accepted — anything beyond stays invisible to the mask and is
-    overwritten by later windows. An int8 cache (prefill's
-    quant_cache=True) is detected by tree structure, like decode_step."""
-    quant = "k_scale" in cache
-    b, w = tokens.shape
-    cache_len = cache["k"].shape[3]
-    cos, sin = rope_tables(config, cache_len)
-    positions = lens[:, None] + jnp.arange(w, dtype=lens.dtype)[None, :]
-    x = embed_lookup(params["embed"], tokens, config)   # (B, W, D)
-
-    def body(x, layer_and_cache):
-        if quant:
-            layer, kc, vc, ksc, vsc = layer_and_cache
-        else:
-            layer, kc, vc = layer_and_cache
-            ksc = vsc = None
-        layer = dequantize_layer(layer)
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q, k, v = qkv_proj(h, layer, config)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        kc, vc, scales, k_eff, v_eff = write_cache_rows(
-            kc, vc, (ksc, vsc) if quant else None, k, v, lens)
-        if quant:
-            ksc, vsc = scales
-        attn = _window_attention(q, k_eff, v_eff, lens, config)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, w, -1)
-        x = x + jnp.einsum("bsh,hd->bsd", attn, layer["wo"])
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        x = x + _mlp(h, layer, config)
-        return x, ((kc, vc, ksc, vsc) if quant else (kc, vc))
-
-    if quant:
-        xs = (params["layers"], cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"])
-        x, (ks, vs, kscs, vscs) = lax.scan(body, x, xs)
-        new_cache = {"k": ks, "v": vs, "k_scale": kscs, "v_scale": vscs}
-    else:
-        x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
-        new_cache = {"k": ks, "v": vs}
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bwd,dv->bwv", x,
-                        maybe_dequantize(params["output"]),
-                        preferred_element_type=jnp.float32)
-    return logits, new_cache
+from tony_tpu.models.generate import prefill, window_logits
+from tony_tpu.models.llama import LlamaConfig, Params
 
 
 @partial(jax.jit, static_argnames=("config", "draft_config",

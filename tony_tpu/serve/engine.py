@@ -23,7 +23,7 @@ without ever changing a shape:
   or decode write, so recycling needs no cache scrubbing.
 
 Composes with the offline path's levers: int8 KV cache (`quant_cache`,
-shared `write_cache_rows`), int8 weights (quantized params pass straight
+shared `new_cache_rows`), int8 weights (quantized params pass straight
 through), and the MoE/dense MLP dispatch in `models/generate._mlp` (MoE at
 no-drop capacity routes each token independently, preserving row
 independence).
@@ -277,10 +277,15 @@ def _phase_percentiles(snap: dict, key: str, samples, scale: float = 1.0
 # ---------------------------------------------------------------------------
 
 # the shared cache is DONATED through both jitted kernels: the caller
-# rebinds self._cache to the output every call, and without donation XLA
-# would allocate + copy the full multi-GB static cache per decoded token
-# (on backends without buffer donation — CPU tests — jax warns and copies,
-# which is the pre-donation behavior)
+# rebinds self._cache to the output every call, so output and input are
+# one buffer (on backends without buffer donation — CPU tests — jax warns
+# and copies). Donation only aliases the two ends; whether the program
+# between them copies the cache is the program's own doing: `decode_step`
+# only reads it in its layer loop and writes the new rows in place after
+# it (models/generate.py `write_cache_rows`), and tests/test_tpu_compile.py
+# pins that in the compiled step (no slab-sized op, no cache-sized
+# temporary — passing the slabs through the layer scan cost 41 of 58.8 ms
+# a step under this same donation, PERF.md PR 27)
 @partial(jax.jit, static_argnames=("config", "temperature", "top_k",
                                    "top_p"), donate_argnames=("cache",))
 def _decode_sample_step(params: Params, config: LlamaConfig, cache,
