@@ -1,4 +1,6 @@
-"""Operations and bytes the algorithm needs, computed from shapes alone.
+"""Operations and bytes the `llama` family's algorithm needs (one kind of
+layer: GQA attention with a full cache and a dense SwiGLU MLP), computed
+from shapes alone.
 
 These are the benchmark's own counts (the program's
 `LlamaConfig.flops_per_token` counts the embedding lookup as a matmul and

@@ -1,5 +1,6 @@
-"""The plain reference: Mistral-7B's forward pass, loss, gradients and the
-AdamW update in straightforward `jax.numpy`, float32 at
+"""The plain reference of the `llama` family (Llama's block, which is
+Mistral-7B's): forward pass, loss, gradients and the AdamW update in
+straightforward `jax.numpy`, float32 at
 `default_matmul_precision("highest")`, with no kernels, no cache and no
 batching. It imports nothing of the program and takes nothing the program
 made: weights come from the seed by the recipe the configuration file
@@ -135,15 +136,14 @@ def _rope(x, cos, sin):
     return jnp.concatenate((x1 * cos - x2 * sin, x1 * sin + x2 * cos), -1)
 
 
-def block(x, w, cos, sin, cfg: dict, precision="highest"):
-    """One pre-norm block on one sequence. x (S, d) float32; w one layer's
-    weights. Causal softmax attention, one group of query heads (those
-    that share a K/V head) at a time so that the S x S scores stay small."""
+def attention(x, w, cos, sin, cfg: dict, precision="highest"):
+    """The attention half of a pre-norm block on one sequence, residual
+    included. x (S, d) float32; w one layer's weights in float32. Causal
+    softmax attention, one group of query heads (those that share a K/V
+    head) at a time so that the S x S scores stay small."""
     s = shapes(cfg)
     nh, nkv, hd, S = s["nh"], s["nkv"], s["hd"], x.shape[0]
-    w = jax.tree.map(lambda a: a.astype(F32), w)
-    eps = float(cfg["rms_norm_eps"])
-    h = _rms(x, w["attn_norm"], eps)
+    h = _rms(x, w["attn_norm"], float(cfg["rms_norm_eps"]))
     q = _mm(h, w["wq"], precision).reshape(S, nh, hd).transpose(1, 0, 2)
     k = _mm(h, w["wk"], precision).reshape(S, nkv, hd).transpose(1, 0, 2)
     v = _mm(h, w["wv"], precision).reshape(S, nkv, hd).transpose(1, 0, 2)
@@ -159,8 +159,15 @@ def block(x, w, cos, sin, cfg: dict, precision="highest"):
 
     o = lax.map(group, (q.reshape(nkv, nh // nkv, S, hd), k, v))
     o = o.reshape(nh, S, hd).transpose(1, 0, 2).reshape(S, nh * hd)
-    x = x + _mm(o, w["wo"], precision)
-    h = _rms(x, w["mlp_norm"], eps)
+    return x + _mm(o, w["wo"], precision)
+
+
+def block(x, w, cos, sin, cfg: dict, precision="highest"):
+    """One pre-norm block on one sequence: `attention`, then the dense
+    SwiGLU MLP. x (S, d) float32; w one layer's weights."""
+    w = jax.tree.map(lambda a: a.astype(F32), w)
+    x = attention(x, w, cos, sin, cfg, precision)
+    h = _rms(x, w["mlp_norm"], float(cfg["rms_norm_eps"]))
     ff = jax.nn.silu(_mm(h, w["w_gate"], precision)) * _mm(h, w["w_up"],
                                                           precision)
     return x + _mm(ff, w["w_down"], precision)

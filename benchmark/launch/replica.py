@@ -1,8 +1,9 @@
 """The serving replica a serving cell launches: `python -m tony_tpu.serve`
 (the program's own entry, `tony_tpu.serve.__main__.main`) with the cell's
 configuration registered as a preset and its weights made on the device
-from --seed in one jitted call. Everything else — engine, front end,
-registration with the AM, shutdown — is the program's.
+from --seed, both by the `program.py` of the configuration's family
+(benchmark/families/). Everything else — engine, front end, registration
+with the AM, shutdown — is the program's.
 
   --control int8-cache   the lower-precision control: the program's own
                          int8 K/V cache (`--quant-cache`). It moves the
@@ -40,22 +41,18 @@ def main() -> int:
     with open(args.config, encoding="utf-8") as f:
         cfg = json.load(f)
 
-    from lib import inproc
+    from lib import inproc, spec
     compile_log = inproc.install_compile_log()
-    from tony_tpu.models import llama
     from tony_tpu.serve import __main__ as serve_main
-    config = inproc.program_config(cfg)
-    llama.PRESETS["benchmark"] = config
-    program_init = llama.llama_init
-    llama.llama_init = lambda c, _key: inproc.seeded_init(
-        program_init, c, args.seed)
+    family = spec.load_family(args.config, cfg)
+    preset = family.program.serving(cfg, args.seed)
     if args.sabotage == "flip":
         from tony_tpu.serve import engine
-        sample = engine._sample
-        engine._sample = lambda *a, **kw: (sample(*a, **kw) + 1) % config.vocab_size
+        sample, vocab = engine._sample, cfg["vocab_size"]
+        engine._sample = lambda *a, **kw: (sample(*a, **kw) + 1) % vocab
     inproc.watch_requests(args.ctl_dir, compile_log)
     run = cfg["run"]
-    argv = ["--config", "benchmark", "--slots", str(run["slots"]),
+    argv = ["--config", preset, "--slots", str(run["slots"]),
             "--token-budget", str(run["token_budget"]),
             "--queue-depth", str(run["queue_depth"])]
     if args.control == "int8-cache":

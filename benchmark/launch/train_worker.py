@@ -2,11 +2,13 @@
 executor: the program's `Trainer` (its mesh, sharding, optimizer, jitted
 donated step and `PrefetchIterator` feed) at the cell's configuration, fed
 by the benchmark's seeded token rows, and driven by its own `Trainer.run()`
-throughout. `run()` trains to `config.num_steps` and may be called again
-with a larger one, so one trainer runs, in turn: the first step (the
-optimizer's state then gives the first gradient), the rest of the steps
-the reference follows (they also tell the step's time), and the window,
-whose number of steps is the seconds asked for over that time.
+throughout. The model's loss, initialiser and sharding come from the
+`program.py` of the configuration's family (benchmark/families/). `run()`
+trains to `config.num_steps` and may be called again with a larger one, so
+one trainer runs, in turn: the first step (the optimizer's state then
+gives the first gradient), the rest of the steps the reference follows
+(they also tell the step's time), and the window, whose number of steps is
+the seconds asked for over that time.
 
 The trainer logs every step (`log_every` 1): at each step its loop waits
 for the step before, so its step log (`metrics_history`: step, loss,
@@ -50,23 +52,22 @@ def _leaf_norms(tree) -> dict:
             zip(_leaf_names(tree), jax.tree.leaves(tree))}
 
 
-def _delta_norms(params, config, seed: int) -> dict:
+def _delta_norms(params, init, config, seed: int) -> dict:
     """Norm of (params - initial params), leaf by leaf. The initial leaf
-    is made again from the seed by the program that made it the first
-    time, so no second copy of the model is held. Two programs, not one:
-    fused into the difference, the compiler may skip the initial leaf's
-    rounding to bfloat16 (xla_allow_excess_precision), and the "change"
-    would then be that rounding."""
+    is made again from the seed by `init`, the program that made it the
+    first time, so no second copy of the model is held. Two programs, not
+    one: fused into the difference, the compiler may skip the initial
+    leaf's rounding to bfloat16 (xla_allow_excess_precision), and the
+    "change" would then be that rounding."""
     import jax
     import jax.numpy as jnp
     from lib import inproc
-    from tony_tpu.models import llama
     diff = jax.jit(lambda x, y: jnp.sqrt(jnp.sum(jnp.square(
         x.astype(jnp.float32) - y.astype(jnp.float32)))))
     out = {}
     for name, leaf, (_, init) in zip(
             _leaf_names(params), jax.tree.leaves(params),
-            inproc.seeded_leaves(llama.llama_init, config, seed)):
+            inproc.seeded_leaves(init, config, seed)):
         out[name] = float(diff(leaf, init))
         init.delete()
     return out
@@ -96,32 +97,28 @@ def main() -> int:
     os.makedirs(args.out, exist_ok=True)
 
     import jax
-    from lib import inproc, traffic
+    from lib import inproc, spec, traffic
     compile_log = inproc.install_compile_log()
-    from tony_tpu.models.llama import (
-        llama_init, llama_loss, llama_param_axes,
-    )
     from tony_tpu.train.trainer import Trainer, TrainerConfig
 
-    config = inproc.program_config(cfg)
+    family = spec.load_family(args.config, cfg)
+    model = family.program.training(cfg, args.seed)
     opt = cfg["run"]["optimizer"]
     trainer = Trainer(
-        loss_fn=partial(llama_loss, config=config),
-        init_fn=lambda _key: inproc.seeded_init(llama_init, config,
-                                                args.seed),
-        data_iter=traffic.train_batches(mix, config.vocab_size, args.seed),
+        loss_fn=model["loss_fn"], init_fn=model["init_fn"],
+        data_iter=traffic.train_batches(mix, cfg["vocab_size"], args.seed),
         config=TrainerConfig(
             num_steps=opt["schedule_steps"], log_every=1, seed=0,
             learning_rate=opt["learning_rate"],
             warmup_steps=opt["warmup_steps"],
             weight_decay=opt["weight_decay"]),
-        param_axes=llama_param_axes(config))
+        param_axes=model["param_axes"])
     mark("imports_done")
     trainer.setup()
     mark("trainer_setup_done")
     if args.sabotage == "noop":
         # tests only: a step that returns its state unchanged
-        loss_only = jax.jit(partial(llama_loss, config=config))
+        loss_only = jax.jit(model["loss_fn"])
         trainer.train_step = lambda p, o, b: (p, o, loss_only(p, b))
 
     def run_to(step: int) -> tuple:
@@ -157,8 +154,8 @@ def main() -> int:
     step_s = step_seconds(log)[-1]     # sizes the window, no more
     mark("check_steps_done")
     with jax.set_mesh(trainer.mesh):
-        record["delta_norms"] = _delta_norms(trainer.params, config,
-                                             args.seed)
+        record["delta_norms"] = _delta_norms(
+            trainer.params, model["init"], model["config"], args.seed)
     mark("delta_norms_done")
     steps = max(1, int(-(-args.seconds // step_s)))
     mark(f"window_sized step_s={step_s:.4f} window_steps={steps}")

@@ -18,7 +18,8 @@ the job has ended and released the chip (the reference needs the chip, and
 Every number compared is printed beside its limit on a `compare` line; the
 last line is `CHECK <json>` with the verdict. Limits live in the
 configuration file (`limits`), PERF.md gives the readings they were set
-from.
+from. The reference and the byte counts are those of the configuration's
+family (benchmark/families/<family>/reference.py and counts.py).
 """
 
 from __future__ import annotations
@@ -82,6 +83,15 @@ def norm_gap(prog: dict, ref: dict) -> tuple:
     return worst, leaf
 
 
+def _load(args) -> tuple:
+    """(the configuration, its family's reference, its family's counts)."""
+    from lib import spec
+    with open(args.config, encoding="utf-8") as f:
+        cfg = json.load(f)
+    family = spec.load_family(args.config, cfg)
+    return cfg, family.reference, family.counts
+
+
 def _batches(traffic_mod, mix, vocab, seed, n):
     return [b["tokens"] for b in itertools.islice(
         traffic_mod.train_batches(mix, vocab, seed), n)]
@@ -109,8 +119,8 @@ def compare_training(cmp: Compare, prog: dict, ref: dict, limits: dict):
 
 def cmd_train(args) -> int:
     _setup_jax(args.rehearse)
-    from lib import reference, traffic
-    cfg = json.load(open(args.config, encoding="utf-8"))
+    from lib import traffic
+    cfg, reference, _ = _load(args)
     mix = json.load(open(args.traffic, encoding="utf-8"))
     prog = json.load(open(args.record, encoding="utf-8"))
     t = time.monotonic()
@@ -130,8 +140,8 @@ def cmd_train(args) -> int:
 def cmd_control_train(args) -> int:
     """The reference in float8 in the program's place, on each seed."""
     _setup_jax(args.rehearse)
-    from lib import reference, traffic
-    cfg = json.load(open(args.config, encoding="utf-8"))
+    from lib import traffic
+    cfg, reference, _ = _load(args)
     mix = json.load(open(args.traffic, encoding="utf-8"))
     n = int(mix["check_steps"])
     verdicts = []
@@ -153,8 +163,7 @@ def cmd_control_train(args) -> int:
 def cmd_serve(args) -> int:
     jax = _setup_jax(args.rehearse)
     import jax.numpy as jnp
-    from lib import counts, reference
-    cfg = json.load(open(args.config, encoding="utf-8"))
+    cfg, reference, counts = _load(args)
     sample = json.load(open(args.sample, encoding="utf-8"))
     t = time.monotonic()
     params = reference.init_on_device(cfg, args.seed)

@@ -53,27 +53,6 @@ def device_info() -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
-def program_config(cfg: dict, **overrides):
-    """The program's LlamaConfig from a configuration file's keys (the
-    source's names). Mistral's block is Llama's equations; its sliding
-    window is not modelled and never binds at the lengths the cells use."""
-    import jax.numpy as jnp
-    from tony_tpu.models.llama import LlamaConfig
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        cfg["torch_dtype"]]
-    kw = dict(vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"],
-              n_layers=cfg["num_hidden_layers"],
-              n_heads=cfg["num_attention_heads"],
-              n_kv_heads=cfg["num_key_value_heads"],
-              ffn_dim=cfg["intermediate_size"],
-              max_seq=cfg["run"]["max_seq"],
-              rope_theta=float(cfg["rope_theta"]),
-              norm_eps=float(cfg["rms_norm_eps"]), dtype=dtype)
-    kw.update(cfg["run"].get("program", {}))
-    kw.update(overrides)
-    return LlamaConfig(**kw)
-
-
 def seeded_init(init, config, seed: int):
     """The program's own initialiser `init(config, key)`, on the device
     from the seed, in the dtype the weights are served or trained in: one

@@ -2,8 +2,9 @@
 benchmark/metrics/<name>.py with `read(run)`; it returns None where it
 finds nothing to read, and the harness then leaves the metric out.
 
-Names the readers take from the program: the jitted programs
-`jit__decode_sample_step` and `jit__admit_step` (serve/engine.py),
+Operations and bytes are counted by the `counts.py` of the cell's family
+(`run.family.counts`). Names the readers take from the program: the jitted
+programs `jit__decode_sample_step` and `jit__admit_step` (serve/engine.py),
 `jit_train_step` (train/step.py), and the Pallas kernels `tony_flash_fwd`,
 `tony_flash_bwd_dq`, `tony_flash_bwd_dkv` (ops/attention.py).
 """
@@ -12,7 +13,7 @@ from __future__ import annotations
 
 import statistics
 
-from lib import counts, loadgen, peaks, stats, trace
+from lib import loadgen, peaks, stats, trace
 
 DECODE_PROGRAM = "jit__decode_sample_step"
 ADMIT_PROGRAM = "jit__admit_step"
@@ -67,7 +68,7 @@ def decode_hbm_pct(run):
     ctx = mean_context_tokens(run)
     if step_ms is None or ctx is None or not on_chip(run):
         return None
-    need = counts.decode_step_bytes(run.config, [ctx])
+    need = run.family.counts.decode_step_bytes(run.config, [ctx])
     peak = peaks.peaks_of(run.device["kind"])["hbm_bytes_per_s"]
     return 100.0 * need / (step_ms / 1e3) / peak
 
@@ -78,7 +79,7 @@ def flash_roofline(run):
     they took."""
     if not run.trace or not on_chip(run):
         return None
-    pk = peaks.peaks_of(run.device["kind"])
+    pk, counts = peaks.peaks_of(run.device["kind"]), run.family.counts
     b, s = int(run.mix["batch_size"]), int(run.mix["seq_len"])
     least = took = 0.0
     bound = set()

@@ -40,7 +40,7 @@ class Run:
         self.bench, self.cell = spec["bench"], spec["cell"]
         self.config, self.mix = spec["config"], spec["mix"]
         self.config_path, self.mix_path = spec["config_path"], spec["mix_path"]
-        self.metrics_dir = spec["metrics_dir"]
+        self.metrics_dir, self.family = spec["metrics_dir"], spec["family"]
         # absolute: the launched process runs in its container's directory
         self.out_dir = os.path.abspath(args.out or os.path.join(
             orchestrate.ROOT, "benchmark_out", self.cell["name"]))

@@ -7,7 +7,7 @@ Moves train_tokens_per_s."""
 
 import statistics
 
-from lib import counts, peaks
+from lib import peaks
 
 
 def read(run):
@@ -16,7 +16,8 @@ def read(run):
         return None
     ends = [w["window_t0"]] + w["step_ends"]
     step_s = statistics.median(b - a for a, b in zip(ends, ends[1:]))
-    flops = counts.train_flops_per_token(run.config, int(run.mix["seq_len"]))
+    flops = run.family.counts.train_flops_per_token(
+        run.config, int(run.mix["seq_len"]))
     peak = peaks.peaks_of(run.device["kind"])["bf16_flops_per_s"]
     return (100.0 * flops * w["tokens_per_step"] / step_s
             / (peak * run.device["count"]))

@@ -4,7 +4,9 @@
   python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (benchmark/configs/) and a traffic mix
-(benchmark/traffic/); the mix's `kind` chooses the runner. With --trace 0
+(benchmark/traffic/); the mix's `kind` chooses the runner, and the
+configuration's `family` the files that know its architecture
+(benchmark/families/). With --trace 0
 the last line carries the cell's end-to-end metrics, with --trace 1 its
 per-layer metrics (each read by benchmark/metrics/<name>.py), `busy_s` /
 `window_s` and the breakdown. This process never imports jax: the launched
@@ -29,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -83,6 +86,11 @@ def main() -> int:
     run.compare("failed", run.failed, 0,
                 f"of {run.attempted} attempted")
     correct = bool(run.compares) and all(c["ok"] for c in run.compares)
+    # each number compared beside its limit: the last lines on standard
+    # error, and the last key of the result line
+    compared = {c["name"]: {
+        "value": c["value"] if math.isfinite(c["value"]) else str(c["value"]),
+        "limit": c["limit"], "ok": c["ok"]} for c in run.compares}
     if run.device.get("platform") == "tpu":
         print("facts " + json.dumps(run.facts), flush=True)
 
@@ -110,6 +118,12 @@ def main() -> int:
         device["window_s"] = run.trace["window_s"]
         result["breakdown"] = {"device_ops": run.trace.get("device_ops", []),
                                "idle_gaps": run.trace.get("idle_gaps", [])}
+    result["compared"] = compared
+    sys.stdout.flush()
+    for name, c in compared.items():
+        print(f"compared {name} value={c['value']} limit={c['limit']} "
+              f"{'ok' if c['ok'] else 'FAIL'}", file=sys.stderr)
+    sys.stderr.flush()
     if device["platform"] != "tpu":
         # a rehearsal: show what a run would print, claim nothing
         print(f"platform: {device['platform']} (a rehearsal: no device "

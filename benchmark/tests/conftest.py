@@ -23,12 +23,16 @@ def rehearse(workload: str, *extra: str, seconds: float = 4, seed: int = 7,
            str(seed), "--seconds", str(seconds), "--trace", "0", *extra]
     if out:
         cmd += ["--out", out]
-    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                       timeout=600)
+    return rehearsal_line(subprocess.run(
+        cmd, env=env, capture_output=True, text=True, timeout=600))
+
+
+def rehearsal_line(r: subprocess.CompletedProcess) -> dict:
+    """What the `rehearsal` line of a finished --rehearse run says."""
     assert r.returncode == 3, r.stdout[-3000:] + r.stderr[-3000:]
     assert "platform: cpu" in r.stdout
     lines = [ln for ln in r.stdout.splitlines() if ln.startswith("rehearsal ")]
     assert lines, r.stdout[-3000:]
     got = json.loads(lines[-1][len("rehearsal "):])
-    got["stdout"] = r.stdout
+    got["stdout"], got["stderr"] = r.stdout, r.stderr
     return got

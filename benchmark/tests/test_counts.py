@@ -4,7 +4,8 @@ import os
 import pytest
 from conftest import BENCH
 
-from lib import counts, peaks
+from families.llama import counts
+from lib import peaks
 
 
 def _cfg(name):
