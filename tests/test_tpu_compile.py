@@ -288,3 +288,81 @@ def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
     assert total < HBM_USABLE, f"{total / GiB:.2f} GiB"
     # and with room: the process also holds the prefetched batches
     assert total < 13 * GiB, f"{total / GiB:.2f} GiB"
+
+
+# -- layers of several kinds: the sala-longdoc cell's two programs ---------
+
+def _sala_cell(one_chip):
+    """benchmark/configs/minicpm-sala-serve.json as the program has it:
+    MiniCPM-SALA widths, 4 periods of one sparse + three lightning layers,
+    16 slots x 36864 tokens; abstract weights and cache."""
+    from tony_tpu.models import sala
+
+    config = sala.SalaConfig(
+        n_layers=16, max_seq=36864,
+        mixer_types=((sala.SPARSE,) + (sala.LIGHTNING,) * 3) * 4)
+    slots, budget = 16, 36864
+    params = jax.tree.map(
+        lambda l: _sds(l.shape, l.dtype, one_chip),
+        jax.eval_shape(partial(sala.sala_init, config),
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(
+        lambda l: _sds(l.shape, l.dtype, one_chip),
+        jax.eval_shape(lambda: sala.empty_cache(config, slots, budget)))
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache.values())
+    return config, params, cache, cache_bytes, slots, budget
+
+
+def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip):
+    """One decode step of the hybrid model at the cell's shapes: the K/V
+    rows, the compressed keys and the lightning states (2.9 GB together)
+    are aliased in and out, nothing cache-sized is copied (no K/V slab,
+    no copy of the 0.4 GB of states, no transposed stack of weights: each
+    of those was there before it was repaired, 1.2 to 1.7 GB of
+    temporaries), and the step holds each kind's block once: its program
+    text is 0.50 MB, three times that of Mistral's one block (0.17 MB: two
+    kinds of block, the selection, two kernels), where sixteen unrolled
+    layers would be some sixteen times it."""
+    from tony_tpu.serve.engine import _decode_sample_step
+
+    config, params, cache, cache_bytes, slots, budget = _sala_cell(one_chip)
+    compiled = _decode_sample_step.lower(
+        params, config, cache, _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip), 0.0, 0, 1.0).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    text = compiled.as_text()
+    slab = (slots, config.n_kv_heads, budget, config.head_dim)
+    assert _slab_results_outside_fusions(text, slab) == []
+    for kernel in ("tony_sparse_read", "tony_lightning_step"):
+        assert kernel in text, kernel
+    # two kinds of block, each once: not sixteen unrolled layers
+    assert text.count("tony_lightning_step") < 8
+    assert len(text) < 1.2e6, len(text)
+
+
+def test_sala_admission_of_32768_tokens_fits_beside_weights_and_cache(
+        one_chip):
+    """The longest admission of the cell (a 32768-token prompt, batch 1):
+    the block-sparse and chunked-lightning kernels lower, the temporaries
+    stay under 2.5 GB (the MLP, the norms and the rotation run over row
+    blocks; heads are never transposed in float32), and weights, cache
+    and temporaries fit the chip together."""
+    from tony_tpu.serve.engine import _admit_step
+
+    config, params, cache, cache_bytes, slots, _ = _sala_cell(one_chip)
+    compiled = _admit_step.lower(
+        params, config, cache, _sds((32768,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((2,), jnp.uint32, one_chip),
+        0.0, 0, 1.0, False, _sds((), jnp.int32, one_chip), False).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    text = compiled.as_text()
+    for kernel in ("tony_sparse_attn", "tony_lightning_chunk"):
+        assert kernel in text, kernel

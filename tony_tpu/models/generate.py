@@ -91,6 +91,40 @@ def _warn_moe_below_capacity(config: LlamaConfig, who: str = "decode"
             f"for train/serve parity", stacklevel=3)
 
 
+def cache_by_kind(config) -> bool:
+    """True for a model whose layers are of several kinds and whose cache
+    therefore has other leaves than K/V rows (models/sala.py): `prefill`,
+    `decode_step` and `empty_cache` hand such a config to its own module.
+    A Python test at trace time: a LlamaConfig's programs hold no trace of
+    it."""
+    return getattr(config, "has_recurrent_state", False)
+
+
+def empty_cache(config, n_slots: int, token_budget: int,
+                quant_cache: bool = False) -> dict[str, jax.Array]:
+    """The zero serving cache of `n_slots` slots of `token_budget` tokens,
+    in exactly the tree `prefill` writes (int8 layout included, which
+    `window_logits` detects by structure). Every leaf has the slot on
+    axis 1: that is all an admission needs to know to write one."""
+    if cache_by_kind(config):
+        if quant_cache:
+            raise ValueError(
+                "quant_cache: this model's cache is by layer kind "
+                "(compressed keys, recurrent state); it has no int8 form")
+        from tony_tpu.models import sala
+        return sala.empty_cache(config, n_slots, token_budget)
+    shape = (config.n_layers, n_slots, config.n_kv_heads, token_budget,
+             config.head_dim)
+    if quant_cache:
+        scale = shape[:-1] + (1,)
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
+                "k_scale": jnp.zeros(scale, jnp.float32),
+                "v_scale": jnp.zeros(scale, jnp.float32)}
+    return {"k": jnp.zeros(shape, config.dtype),
+            "v": jnp.zeros(shape, config.dtype)}
+
+
 def new_cache_rows(k, v, dtype, quant: bool):
     """What the cache stores for new K/V rows (B, Hkv, W, hd), quantized
     iff `quant` (an int8 cache) and cast to the cache's `dtype` otherwise.
@@ -119,14 +153,20 @@ def write_cache_rows(cache, rows, offsets):
     loop that carries it — with the cache donated (or carried by an outer
     loop) each is an in-place write of L x Hkv x W rows, and nothing
     cache-sized is sliced, copied or stacked. A scatter would do it in one
-    op, but forces a cache layout that brings slab copies back."""
+    op, but forces a cache layout that brings slab copies back. `offsets`
+    may also be {name: (B,)}: leaves that advance at different paces
+    (models/sala.py) are still written by the one loop."""
+    per_leaf = offsets if isinstance(offsets, dict) else dict.fromkeys(
+        cache, offsets)
+
     def write_row(b, cache):
         return {name: lax.dynamic_update_slice(
                     arr, lax.dynamic_slice_in_dim(rows[name], b, 1, axis=1),
-                    (0, b, 0, offsets[b], 0))
+                    (0, b, 0, per_leaf[name][b], 0))
                 for name, arr in cache.items()}
 
-    return lax.fori_loop(0, offsets.shape[0], write_row, cache)
+    n_rows = next(iter(per_leaf.values())).shape[0]
+    return lax.fori_loop(0, n_rows, write_row, cache)
 
 
 def _cache_attention(q, k_cache, v_cache, k_new, v_new, lens) -> jax.Array:
@@ -173,6 +213,9 @@ def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
     decode bandwidth is cache-read-bound, so halving cache bytes is the
     long-context serving lever the way weight int8 is the short-context
     one."""
+    if cache_by_kind(config):
+        from tony_tpu.models import sala
+        return sala.prefill(params, tokens, config, cache_len)
     b, p = tokens.shape
     nkv, hd = config.n_kv_heads, config.head_dim
     cos, sin = rope_tables(config, cache_len)
@@ -278,8 +321,12 @@ def decode_step(params: Params, config: LlamaConfig,
     the continuous-batching shape (serve/engine.py), where every batch
     row is an independent request slot at its own sequence position.
     Returns (logits (B, V), updated cache)."""
+    pos = jnp.broadcast_to(pos, token.shape)
+    if cache_by_kind(config):
+        from tony_tpu.models import sala
+        return sala.decode_step(params, config, cache, token, pos)
     logits, cache = window_logits(params, config, cache, token[:, None],
-                                  jnp.broadcast_to(pos, token.shape))
+                                  pos)
     return logits[:, 0], cache
 
 

@@ -37,7 +37,8 @@ LOG = logging.getLogger(__name__)
 def build_arg_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog="tony_tpu.serve")
     p.add_argument("--config", default="tiny",
-                   help="model preset (models/llama.py PRESETS / MoE)")
+                   help="model preset (models/llama.py PRESETS / MoE / "
+                        "models/sala.py)")
     p.add_argument("--checkpoint-dir", default="",
                    help="restore params from the latest checkpoint here "
                         "(the examples/llama-pretrain format)")
@@ -155,8 +156,14 @@ def _load_model(args):
     import jax.numpy as jnp
 
     from tony_tpu.models.moe import is_moe_preset
+    from tony_tpu.models.sala import is_sala_preset
 
-    if is_moe_preset(args.config):
+    if is_sala_preset(args.config):
+        # layers of several kinds; the engine asks the model for its cache
+        from tony_tpu.models import sala
+        config = sala.get_sala_config(args.config)
+        params = sala.sala_init(config, jax.random.PRNGKey(0))
+    elif is_moe_preset(args.config):
         from tony_tpu.models.moe import get_moe_config, moe_init
         base = get_moe_config(args.config)
         # no-drop capacity: serve-side decode equals the training forward

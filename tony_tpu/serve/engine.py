@@ -72,7 +72,8 @@ from jax import lax
 
 from tony_tpu import constants as C
 from tony_tpu.models.generate import (
-    _sample, _warn_moe_below_capacity, decode_step, prefill,
+    _sample, _warn_moe_below_capacity, cache_by_kind, decode_step,
+    empty_cache, prefill,
 )
 from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.observability.spans import Phases, span
@@ -144,17 +145,32 @@ class RequestHandle:
         self._queue: "queue.Queue" = queue.Queue()
 
     # engine side -------------------------------------------------------
-    def _push(self, token: int, now: float) -> None:
+    # A token or the end reaches the caller in two halves: the record
+    # (`tokens`, the stamps, `finish_reason`) and the wake-up of whoever
+    # waits on the stream. The engine's loop makes the second only after
+    # it has dispatched the next step (`_deliver`), so the woken handler
+    # thread runs beside the device and not between two steps.
+    def _record(self, token: int, now: float) -> None:
         if self.first_token_at is None:
             self.first_token_at = now
         self.tokens.append(token)
-        self._queue.put(token)
 
-    def _finish(self, reason: str, now: float) -> None:
+    def _record_finish(self, reason: str, now: float) -> None:
         self.finish_reason = reason
         self.finished_at = now
-        self.done.set()
-        self._queue.put(_DONE)
+
+    def _wake(self, item) -> None:
+        if item is _DONE:
+            self.done.set()
+        self._queue.put(item)
+
+    def _push(self, token: int, now: float) -> None:
+        self._record(token, now)
+        self._wake(token)
+
+    def _finish(self, reason: str, now: float) -> None:
+        self._record_finish(reason, now)
+        self._wake(_DONE)
 
     # caller side -------------------------------------------------------
     def cancel(self) -> None:
@@ -247,6 +263,14 @@ class EngineStats:
     decode_steps_total: int = 0
     decode_slot_steps_total: int = 0
     admissions_total: int = 0
+    # a model with sparse-attention layers (config.sparse_read_blocks): of
+    # the blocks of context its decoded tokens had, summed over tokens (a
+    # sparse layer each), how many one attended to — their ratio is how
+    # sparse the cache reads were — and admissions whose prompt was short
+    # enough to be attended densely
+    sparse_blocks_attended_total: int = 0
+    sparse_context_blocks_total: int = 0
+    dense_path_admissions_total: int = 0
     # per decode step, the host's share of the gap between two steps:
     # from the previous step's tokens landing on the host to this step's
     # dispatch returning, less the admissions in between (`prefill_s`
@@ -326,9 +350,12 @@ def _admit_step(params: Params, config: LlamaConfig, cache,
         cache_len = cache["k"].shape[3]
         logits, pc = prefill(params, prompt[None, :], config, cache_len,
                              quant_cache=quant_cache)
+        # what the admission writes: every leaf of the model's cache has
+        # the slot on axis 1 (K/V rows (L, 1, Hkv, S, d); a model whose
+        # cache is by layer kind adds leaves of its own)
         out = {}
         for name, arr in cache.items():
-            row = pc[name].astype(arr.dtype)           # (L, 1, Hkv, S, d)
+            row = pc[name].astype(arr.dtype)
             out[name] = lax.dynamic_update_slice_in_dim(arr, row, slot,
                                                         axis=1)
     tok0 = _sample(logits, temperature, top_k, key, top_p)[0]
@@ -396,6 +423,15 @@ class ContinuousBatchingEngine:
         # work out after admission, "decode" replicas accept /v1/migrate
         # installs, "both" (default) is the classic monolithic replica
         self.role = role if role in ("prefill", "decode", "both") else "both"
+        if cache_by_kind(config) and (prefix_sharing or self.role != "both"):
+            # a page of K/V rows is not a prefix of such a model: its
+            # lightning layers' state after the prefix would have to be
+            # kept a page too (a later PR: docs/SERVING.md)
+            raise ValueError(
+                "prefix_sharing and the prefill/decode roles (K/V "
+                "migration) are refused for a model with recurrent state: "
+                "a slot's cache is not a function of its K/V rows alone")
+        self._sparse_reads = getattr(config, "sparse_read_blocks", None)
         self._cache = self._empty_cache()
         # paged prefix-shared KV pool (serve/kvcache.py); None = sharing
         # OFF, which keeps the admission path byte-identical to the
@@ -446,6 +482,8 @@ class ContinuousBatchingEngine:
         # anchor of stats.step_host_s; None while nothing decodes)
         self._steps = 0
         self._tokens_landed_at: Optional[float] = None
+        # (handle, token or _DONE) recorded and not yet handed over
+        self._undelivered: list[tuple[RequestHandle, object]] = []
         # observability hook: called (outside the engine lock) with each
         # RequestHandle as it finishes — serve/frontend turns these into
         # request-trace hops
@@ -455,17 +493,8 @@ class ContinuousBatchingEngine:
         """Zero cache in prefill's exact tree layout (quant included) so
         decode_step's structure-based int8 detection sees the same tree
         the offline path builds."""
-        c = self.config
-        shape = (c.n_layers, self.n_slots, c.n_kv_heads,
-                 self.token_budget, c.head_dim)
-        if self.quant_cache:
-            scale = shape[:-1] + (1,)
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(scale, jnp.float32),
-                    "v_scale": jnp.zeros(scale, jnp.float32)}
-        return {"k": jnp.zeros(shape, c.dtype),
-                "v": jnp.zeros(shape, c.dtype)}
+        return empty_cache(self.config, self.n_slots, self.token_budget,
+                           self.quant_cache)
 
     # -- intake ---------------------------------------------------------
     def submit(self, prompt: list[int], max_new_tokens: int,
@@ -528,6 +557,10 @@ class ContinuousBatchingEngine:
         the stepper installs it into a slot with `install_rows` (no
         prefill is ever paid here). Same backpressure contract as
         submit() — 400/429/503 mapping is identical."""
+        if cache_by_kind(self.config):
+            raise ValueError(
+                "migration is refused for a model with recurrent state: "
+                "its K/V rows alone do not make a slot")
         prompt = [int(t) for t in meta.get("prompt") or []]
         max_new = int(meta.get("max_new_tokens", 0))
         pos = int(meta.get("pos", -1))
@@ -660,10 +693,33 @@ class ContinuousBatchingEngine:
 
     # -- stepping -------------------------------------------------------
     def step(self) -> bool:
+        """One engine iteration, with everything it produced handed to the
+        callers before it returns: what a caller that steps the engine
+        itself sees. The loop thread calls `_step`, which leaves a decode
+        step's wake-ups to the next iteration (`_deliver`)."""
+        busy = self._step()
+        self._deliver()
+        return busy
+
+    def _deliver(self) -> None:
+        """Wake the callers of the tokens and endings recorded since the
+        last call, in the order they were recorded."""
+        undelivered, self._undelivered = self._undelivered, []
+        for handle, item in undelivered:
+            handle._wake(item)
+
+    def _step(self) -> bool:
         """One engine iteration: reap cancelled slots, admit as many queued
         requests as there are free slots, then decode every active slot one
         token. Returns True when any work happened (the loop's idle
         signal).
+
+        A decode step's tokens are recorded when they land and their
+        callers woken a little later: before the next admission, else
+        once the next decode step is dispatched, else when nothing is
+        active. A handler thread takes the GIL to write its chunk; woken
+        between two steps, each open stream kept the loop from its next
+        dispatch (0.11 ms a stream on the chip).
 
         The iteration is tiled by `tony.engine.*` spans on the profiler's
         clock (observability/spans.py; docs/OBSERVABILITY.md lists them):
@@ -687,6 +743,7 @@ class ContinuousBatchingEngine:
                     admitted=self.stats.admissions_total - before)
             if not active:
                 self._tokens_landed_at = None
+                self._deliver()
                 return admitted
             ph.enter("tony.engine.decode.prepare")
             self._key, step_key = jax.random.split(self._key)
@@ -697,13 +754,21 @@ class ContinuousBatchingEngine:
                 self.params, self.config, self._cache, tokens, pos,
                 step_key, self.temperature, self.top_k, self.top_p)
             dispatched_at = time.monotonic()
+            attended = context = 0
+            if self._sparse_reads is not None:
+                for slot in active:
+                    a, c = self._sparse_reads(slot.pos + 1)
+                    attended, context = attended + a, context + c
             with self._lock:
                 self.stats.decode_steps_total += 1
                 self.stats.decode_slot_steps_total += len(active)
+                self.stats.sparse_blocks_attended_total += attended
+                self.stats.sparse_context_blocks_total += context
                 if self._tokens_landed_at is not None:
                     self.stats.step_host_s.append(
                         dispatched_at - self._tokens_landed_at - admit_s)
             ph.enter("tony.engine.decode.wait")
+            self._deliver()
             nxt_np = np.asarray(jax.device_get(nxt))
             if self._test_decode_delay_s > 0:
                 # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
@@ -718,7 +783,8 @@ class ContinuousBatchingEngine:
                 self._pos_np[slot.index] = slot.pos
                 self._tokens_np[slot.index] = token
                 slot.emitted += 1
-                slot.handle._push(token, now)
+                slot.handle._record(token, now)
+                self._undelivered.append((slot.handle, token))
                 with self._lock:
                     self.stats.tokens_emitted += 1
                     self.stats.itl_s.append(now - slot.last_emit_at)
@@ -727,10 +793,9 @@ class ContinuousBatchingEngine:
             ph.enter("tony.engine.release")
             # the step's device arrays die here, inside a leaf, and not a
             # moment later at the return: freeing a device buffer releases
-            # the GIL, and the handler threads the pushes just woke then
-            # take it in turn before the loop gets it back (on the chip
-            # that wait was the longest piece of a step's host time, and
-            # lay between two steps, under no span)
+            # the GIL, and whatever thread wants it takes it before the
+            # loop gets it back: a wait that would otherwise lie between
+            # two steps, under no span
             del nxt, tokens, pos, step_key
             return True
 
@@ -740,6 +805,7 @@ class ContinuousBatchingEngine:
             free = next((s for s in self._slots if not s.active), None)
             if free is None:
                 break
+            self._deliver()       # not behind the seconds of an admission
             with Phases("tony.engine.admit") as ph:
                 ph.enter("tony.engine.admit.prepare")
                 with self._lock:
@@ -826,8 +892,11 @@ class ContinuousBatchingEngine:
         self._pos_np[slot.index] = slot.pos
         self._tokens_np[slot.index] = tok0
         handle._push(tok0, now)
+        dense = self._sparse_reads is not None and \
+            self.config.dense_context(len(handle.prompt))
         with self._lock:
             self.stats.admissions_total += 1
+            self.stats.dense_path_admissions_total += int(dense)
             self.stats.tokens_emitted += 1
             self.stats.ttft_s.append(now - handle.submitted_at)
             self.stats.queue_wait_s.append(handle.queue_wait_s)
@@ -964,7 +1033,8 @@ class ContinuousBatchingEngine:
         # overwrites it
         slot.pos = self.token_budget - 1
         self._pos_np[slot.index] = slot.pos
-        handle._finish(reason, now)
+        handle._record_finish(reason, now)
+        self._undelivered.append((handle, _DONE))
         with self._lock:
             self.stats.requests_finished += 1
         sink = self.on_request_finished
@@ -990,7 +1060,7 @@ class ContinuousBatchingEngine:
         while not self._stop.is_set():
             beacon.beat()
             try:
-                busy = self.step()
+                busy = self._step()
             except Exception:  # noqa: BLE001 — a poisoned step must not
                 LOG.exception("engine step failed")    # wedge the server
                 busy = False
@@ -1008,6 +1078,7 @@ class ContinuousBatchingEngine:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        self._deliver()
         now = time.monotonic()
         with self._lock:
             pending = list(self._pending)
@@ -1056,6 +1127,11 @@ class ContinuousBatchingEngine:
             }
             if self.kv_pool is not None:
                 snap.update(self.kv_pool.stats_fields())
+            if self._sparse_reads is not None:
+                for name in ("sparse_blocks_attended_total",
+                             "sparse_context_blocks_total",
+                             "dense_path_admissions_total"):
+                    snap[name] = getattr(self.stats, name)
             itl = _percentile(self.stats.itl_s, 0.50)
             if itl is not None:
                 snap["itl_p50_ms"] = itl * 1000.0
