@@ -1,7 +1,8 @@
-"""When a decode step's tokens reach the callers: recorded as they land,
-their waiters woken once the loop has dispatched the next step (or before
-an admission, or when nothing is active), never out of order, and all of
-them by the time a caller's own `step()` returns. All tier-1 fast.
+"""When a decode step's tokens reach the callers. The loop keeps one step
+in flight: step N's tokens are read, and recorded, after step N+1 was
+dispatched, and their waiters woken once step N+2 was (or before an
+admission, or when nothing is active), never out of order, and all of them
+by the time a caller's own `step()` returns. All tier-1 fast.
 """
 
 from __future__ import annotations
@@ -51,9 +52,12 @@ def test_the_loops_step_records_a_token_and_wakes_its_waiter_a_step_later(
         model):
     engine = _engine(model)
     a = engine.submit(_prompt(model[1], 5, 1), 6)
-    assert engine._step()               # admission (handed over) + one step
-    assert len(a.tokens) == 2
+    assert engine._step()               # admission (handed over) + dispatch
+    assert len(a.tokens) == 1           # that step is in flight, unread
     assert _woken(a) == a.tokens[:1]
+    assert engine._step()               # the next dispatch, then its read
+    assert len(a.tokens) == 2
+    assert _woken(a) == []              # recorded; no one woken yet
     assert engine._step()
     assert len(a.tokens) == 3
     assert _woken(a) == a.tokens[1:2]   # the step before's, not this one's
@@ -64,12 +68,15 @@ def test_the_loops_step_records_a_token_and_wakes_its_waiter_a_step_later(
 
 
 def test_the_waiters_are_woken_after_the_next_dispatch(model, monkeypatch):
+    """... and both before the read of the step in flight: an iteration is
+    dispatch N+1, the wake-ups of N-1's tokens, the read of N."""
     engine = _engine(model)
-    a = engine.submit(_prompt(model[1], 5, 2), 6)
+    a = engine.submit(_prompt(model[1], 5, 2), 8)
+    engine._step()
     engine._step()
     events = []
     program = engine_mod._decode_sample_step
-    deliver = engine._deliver
+    deliver, device_get = engine._deliver, jax.device_get
 
     def dispatch(*args, **kw):
         events.append("dispatch")
@@ -80,11 +87,16 @@ def test_the_waiters_are_woken_after_the_next_dispatch(model, monkeypatch):
             events.append("wake")
         deliver()
 
+    def reading(x):
+        events.append("read")
+        return device_get(x)
+
     monkeypatch.setattr(engine_mod, "_decode_sample_step", dispatch)
     monkeypatch.setattr(engine, "_deliver", delivering)
+    monkeypatch.setattr(engine_mod.jax, "device_get", reading)
     engine._step()
     engine._step()
-    assert events == ["dispatch", "wake", "dispatch", "wake"]
+    assert events == ["dispatch", "wake", "read"] * 2
     assert a.finish_reason is None
 
 
@@ -102,7 +114,10 @@ def test_the_end_follows_the_last_token_and_is_not_seen_before_it(model):
     engine = _engine(model)
     a = engine.submit(_prompt(model[1], 5, 4), 3)
     engine._step()
-    engine._step()                      # the third token ends the request
+    engine._step()                      # the last step a length allows
+    assert engine._in_flight is not None and a.finish_reason is None
+    assert engine._step()               # dispatches nothing, reads it:
+    assert engine._in_flight is None    # the third token ends the request
     assert a.finish_reason == "length" and len(a.tokens) == 3
     assert not a.done.is_set()
     assert _woken(a) == a.tokens[:2]
@@ -115,6 +130,7 @@ def test_an_admission_does_not_hold_back_the_step_before_it(
         model, monkeypatch):
     engine = _engine(model)
     a = engine.submit(_prompt(model[1], 5, 5), 8)
+    engine._step()
     engine._step()
     engine._step()
     assert engine._undelivered
@@ -130,6 +146,10 @@ def test_an_admission_does_not_hold_back_the_step_before_it(
     engine._step()
     assert seen == [([], 3)]            # all three of a's were out already
     assert _woken(b) == b.tokens[:1]    # b's first token is not deferred
+    # the step in flight across the admission was read after it, and the
+    # next one carries both streams
+    assert len(a.tokens) == 4 and a.finish_reason is None
+    assert len(engine._in_flight.riders) == 2
 
 
 def test_a_cancelled_stream_ends_after_its_tokens(model):
@@ -150,8 +170,9 @@ def test_stop_hands_over_what_the_loop_still_held(model):
     engine._step()
     engine._step()
     held = len(engine._undelivered)
-    assert held == 1
-    engine.stop()
+    assert held == 1 and engine._in_flight is not None
+    engine.stop()                       # lands the step in flight, too
+    assert len(a.tokens) == 3
     assert _woken(a) == a.tokens + [engine_mod._DONE]
     assert a.finish_reason == "shutdown"
 

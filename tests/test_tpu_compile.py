@@ -240,7 +240,8 @@ def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
         _abstract_params(config, one_chip), config, cache,
         _sds((slots,), jnp.int32, one_chip),
         _sds((slots,) if per_row else (), jnp.int32, one_chip),
-        _sds((2,), jnp.uint32, one_chip), 0.0, 0, 1.0).compile()
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
+        0.0, 0, 1.0).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 256e6, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= cache_bytes
@@ -330,7 +331,8 @@ def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip):
     compiled = _decode_sample_step.lower(
         params, config, cache, _sds((slots,), jnp.int32, one_chip),
         _sds((slots,), jnp.int32, one_chip),
-        _sds((2,), jnp.uint32, one_chip), 0.0, 0, 1.0).compile()
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
+        0.0, 0, 1.0).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= cache_bytes
@@ -356,8 +358,9 @@ def test_sala_admission_of_32768_tokens_fits_beside_weights_and_cache(
 
     config, params, cache, cache_bytes, slots, _ = _sala_cell(one_chip)
     compiled = _admit_step.lower(
-        params, config, cache, _sds((32768,), jnp.int32, one_chip),
-        _sds((), jnp.int32, one_chip), _sds((2,), jnp.uint32, one_chip),
+        params, config, cache, _sds((slots,), jnp.int32, one_chip),
+        _sds((32768,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
         0.0, 0, 1.0, False, _sds((), jnp.int32, one_chip), False).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes

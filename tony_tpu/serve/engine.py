@@ -21,6 +21,12 @@ without ever changing a shape:
   next queued request. Garbage K/V an idle slot may write is always masked
   (positions >= the slot's length) and overwritten by the next admission
   or decode write, so recycling needs no cache scrubbing.
+- **One step in flight**: the sampled tokens stay on the device (a step's
+  output vector is the next step's input as it is), so the loop dispatches
+  step N+1 before it reads step N's tokens and the host's bookkeeping runs
+  beside the device, not between two steps. A finish by length is known a
+  step ahead; an `eos` is seen one step late (that slot rides in N+1 and
+  the token is dropped, `decode_slot_steps_discarded_total`).
 
 Composes with the offline path's levers: int8 KV cache (`quant_cache`,
 shared `new_cache_rows`), int8 weights (quantized params pass straight
@@ -30,9 +36,10 @@ independence).
 
 Sampling: greedy (`temperature=0`) is THE contract — bit-identical to
 offline greedy. Temperature/top-k/top-p are engine-wide settings (one
-compiled step, not per-request variants); sampled streams draw per-step
-keys and are reproducible per (seed, admission order) but intentionally
-not pinned against the offline oracle.
+compiled step, not per-request variants); sampled streams fold the
+draw's number into the engine's base key inside the jitted step and are
+reproducible per (seed, admission order) but intentionally not pinned
+against the offline oracle.
 
 Prefix sharing (`prefix_sharing=True`, serve/kvcache.py): admission
 first gathers any radix-indexed prefix pages into the slot row
@@ -149,7 +156,7 @@ class RequestHandle:
     # (`tokens`, the stamps, `finish_reason`) and the wake-up of whoever
     # waits on the stream. The engine's loop makes the second only after
     # it has dispatched the next step (`_deliver`), so the woken handler
-    # thread runs beside the device and not between two steps.
+    # thread runs beside the device and not before a dispatch.
     def _record(self, token: int, now: float) -> None:
         if self.first_token_at is None:
             self.first_token_at = now
@@ -219,13 +226,31 @@ class RequestHandle:
 class _Slot:
     index: int
     handle: Optional[RequestHandle] = None
-    pos: int = 0          # next cache position the decode writes at
+    pos: int = 0          # cache position the next dispatched step writes at
     emitted: int = 0      # generated tokens so far (incl. the prefill one)
+    dispatched: int = 0   # `emitted` plus the token of a step in flight
     last_emit_at: float = 0.0   # inter-token latency anchor
 
     @property
     def active(self) -> bool:
         return self.handle is not None
+
+    @property
+    def rides(self) -> bool:
+        """Whether the next decode step makes a token for this slot: an
+        active one whose stream the step in flight does not end by length
+        (known a step ahead; an eos is not, and rides once more)."""
+        return (self.handle is not None
+                and self.dispatched < self.handle.max_new_tokens)
+
+
+@dataclass
+class _Flight:
+    """A dispatched decode step whose tokens the host has not read."""
+    tokens: jax.Array     # the step's sampled tokens, (n_slots,), on device
+    # who held which slot when it was dispatched, and the row it wrote:
+    # a token is booked only for a handle that still holds its slot
+    riders: list[tuple[_Slot, RequestHandle, int]]
 
 
 @dataclass
@@ -257,12 +282,19 @@ class EngineStats:
     migrated_out: int = 0
     migrated_in: int = 0
     # the loop's own counters (cumulative): decode steps dispatched, the
-    # active slots summed over them (their ratio is the mean batch a step
-    # carried), and requests admitted into a slot (prefilled or migrated
-    # in)
+    # slots whose token was kept summed over them (their ratio is the mean
+    # batch a step carried), and requests admitted into a slot (prefilled
+    # or migrated in)
     decode_steps_total: int = 0
     decode_slot_steps_total: int = 0
     admissions_total: int = 0
+    # the step in flight: steps dispatched while the one before had not
+    # been read (over decode_steps_total: how often the pipeline engaged;
+    # 0 for a caller that steps by hand), and slot-steps run for a stream
+    # that had ended by the time their token was read (an eos is seen one
+    # step late; a cancellation may be)
+    decode_steps_overlapped_total: int = 0
+    decode_slot_steps_discarded_total: int = 0
     # a model with sparse-attention layers (config.sparse_read_blocks): of
     # the blocks of context its decoded tokens had, summed over tokens (a
     # sparse layer each), how many one attended to — their ratio is how
@@ -271,11 +303,13 @@ class EngineStats:
     sparse_blocks_attended_total: int = 0
     sparse_context_blocks_total: int = 0
     dense_path_admissions_total: int = 0
-    # per decode step, the host's share of the gap between two steps:
-    # from the previous step's tokens landing on the host to this step's
-    # dispatch returning, less the admissions in between (`prefill_s`
-    # holds those, device and host together). No sample for the first
-    # step after the engine sat idle.
+    # per decode iteration, the loop thread's time outside its wait on
+    # the device: from the previous read of a step's tokens returning to
+    # the next one starting (booking, release, reap, prepare, dispatch,
+    # the wake-ups), less the admissions in between (`prefill_s` holds
+    # those, device and host together). With a step in flight this is
+    # the time that has to fit under a device step, not time added to
+    # it. No sample for the first step after the engine sat idle.
     step_host_s: collections.deque = field(
         default_factory=lambda: collections.deque(maxlen=2048))
 
@@ -310,17 +344,28 @@ def _phase_percentiles(snap: dict, key: str, samples, scale: float = 1.0
 # pins that in the compiled step (no slab-sized op, no cache-sized
 # temporary — passing the slabs through the layer scan cost 41 of 58.8 ms
 # a step under this same donation, PERF.md PR 27)
+def _draw_key(key: jax.Array, draw: jax.Array, temperature: float):
+    """The key of one sampling draw: the engine's base key with the draw's
+    number folded in, inside the jitted program, so the host splits no key
+    between two steps. Greedy sampling reads no key: both arguments are
+    then unused by the program and never uploaded."""
+    return jax.random.fold_in(key, draw) if temperature > 0.0 else key
+
+
 @partial(jax.jit, static_argnames=("config", "temperature", "top_k",
                                    "top_p"), donate_argnames=("cache",))
 def _decode_sample_step(params: Params, config: LlamaConfig, cache,
                         tokens: jax.Array, pos: jax.Array, key: jax.Array,
-                        temperature: float, top_k: int, top_p: float):
+                        draw: jax.Array, temperature: float, top_k: int,
+                        top_p: float):
     """One continuous-batching step: decode every slot's previous token at
     its own position, sample the next. ONE compile per (config, n_slots,
     token_budget) — slot occupancy, positions, and request boundaries are
-    all data, never shapes."""
+    all data, never shapes. `tokens` is the vector the step (or admission)
+    before returned, still on the device; the result is the next call's."""
     logits, cache = decode_step(params, config, cache, tokens, pos)
-    nxt = _sample(logits, temperature, top_k, key, top_p)
+    nxt = _sample(logits, temperature, top_k,
+                  _draw_key(key, draw, temperature), top_p)
     return nxt, cache
 
 
@@ -328,13 +373,15 @@ def _decode_sample_step(params: Params, config: LlamaConfig, cache,
                                    "top_p", "quant_cache", "shared"),
          donate_argnames=("cache",))
 def _admit_step(params: Params, config: LlamaConfig, cache,
-                prompt: jax.Array, slot: jax.Array, key: jax.Array,
-                temperature: float, top_k: int, top_p: float,
-                quant_cache: bool, start: jax.Array, shared: bool = False):
+                tokens: jax.Array, prompt: jax.Array, slot: jax.Array,
+                key: jax.Array, draw: jax.Array, temperature: float,
+                top_k: int, top_p: float, quant_cache: bool,
+                start: jax.Array, shared: bool = False):
     """Admission: prefill one prompt (batch 1) and write its K/V (+ scales
-    when int8) into the shared cache's `slot` row. Returns (first sampled
-    token, cache). One compile per distinct prompt length — the slot index
-    is data.
+    when int8) into the shared cache's `slot` row, and its first sampled
+    token into the device's token vector. Returns (first sampled token,
+    tokens, cache). One compile per distinct prompt length — the slot
+    index is data.
 
     shared=False (the default engine path) is byte-identical to the
     pre-paging admission: full flash prefill of the whole prompt; `start`
@@ -358,8 +405,15 @@ def _admit_step(params: Params, config: LlamaConfig, cache,
             row = pc[name].astype(arr.dtype)
             out[name] = lax.dynamic_update_slice_in_dim(arr, row, slot,
                                                         axis=1)
-    tok0 = _sample(logits, temperature, top_k, key, top_p)[0]
-    return tok0, out
+    tok0 = _sample(logits, temperature, top_k,
+                   _draw_key(key, draw, temperature), top_p)[0]
+    return tok0, tokens.at[slot].set(tok0), out
+
+
+@jax.jit
+def _seed_token(tokens: jax.Array, slot: jax.Array, token: jax.Array):
+    """A migrated-in slot's next input token into the device's vector."""
+    return tokens.at[slot].set(token)
 
 
 def decode_step_cache_size() -> int:
@@ -444,11 +498,16 @@ class ContinuousBatchingEngine:
                 n_pages=kv_pages, n_slots=n_slots,
                 quant_cache=quant_cache)
         self.prefix_sharing = self.kv_pool is not None
+        # the base key; a draw (a decode step, an admission) folds its
+        # number into it inside the jitted program (`_draw_key`)
         self._key = jax.random.PRNGKey(seed)
-        # host mirrors of the per-slot device state; re-uploaded per step
-        # (a (B,) int32 H2D per token — noise next to the decode itself)
-        self._tokens_np = np.zeros((n_slots,), np.int32)
-        self._pos_np = np.zeros((n_slots,), np.int32)
+        self._draws = 0
+        # each slot's last sampled token, on the device: a decode step's
+        # result is the next one's input as it is, an admission writes its
+        # first token into it, and the host reads a step's copy a step late
+        self._tokens = jnp.zeros((n_slots,), jnp.int32)
+        # the one decode step dispatched and not yet read (`_step`)
+        self._in_flight: Optional[_Flight] = None
         self._slots = [_Slot(i) for i in range(n_slots)]
         self._pending: collections.deque[RequestHandle] = collections.deque()
         self._pending_tokens = 0   # queued prompt+max_new total
@@ -477,11 +536,13 @@ class ContinuousBatchingEngine:
         except ValueError:
             self._test_decode_delay_s = 0.0
         self.stats = EngineStats()
-        # step() calls so far (the `step` attribute of the loop's spans),
-        # and when the last decode step's tokens landed on the host (the
-        # anchor of stats.step_host_s; None while nothing decodes)
+        # step() calls so far (the `step` attribute of the loop's spans);
+        # when the last read of a decode step's tokens returned (the
+        # anchor of stats.step_host_s; None while nothing decodes) and
+        # the seconds of admissions since
         self._steps = 0
-        self._tokens_landed_at: Optional[float] = None
+        self._read_ended_at: Optional[float] = None
+        self._admit_s = 0.0
         # (handle, token or _DONE) recorded and not yet handed over
         self._undelivered: list[tuple[RequestHandle, object]] = []
         # observability hook: called (outside the engine lock) with each
@@ -693,11 +754,12 @@ class ContinuousBatchingEngine:
 
     # -- stepping -------------------------------------------------------
     def step(self) -> bool:
-        """One engine iteration, with everything it produced handed to the
-        callers before it returns: what a caller that steps the engine
-        itself sees. The loop thread calls `_step`, which leaves a decode
-        step's wake-ups to the next iteration (`_deliver`)."""
-        busy = self._step()
+        """One engine iteration, with the decode step it dispatched landed
+        and everything it produced handed to the callers before it
+        returns: a caller that steps the engine itself sees each token on
+        the step that made it. The loop thread calls `_step`, which leaves
+        that step in flight and its wake-ups to the next iteration."""
+        busy = self._step(land=True)
         self._deliver()
         return busy
 
@@ -708,18 +770,45 @@ class ContinuousBatchingEngine:
         for handle, item in undelivered:
             handle._wake(item)
 
-    def _step(self) -> bool:
+    def _step(self, land: bool = False) -> bool:
         """One engine iteration: reap cancelled slots, admit as many queued
-        requests as there are free slots, then decode every active slot one
-        token. Returns True when any work happened (the loop's idle
-        signal).
+        requests as there are free slots, dispatch decode step N+1 over
+        every slot that rides, wake the callers of what was recorded,
+        then wait for step N's tokens and book them. Returns True when any
+        work happened (the loop's idle signal).
 
-        A decode step's tokens are recorded when they land and their
-        callers woken a little later: before the next admission, else
-        once the next decode step is dispatched, else when nothing is
-        active. A handler thread takes the GIL to write its chunk; woken
-        between two steps, each open stream kept the loop from its next
-        dispatch (0.11 ms a stream on the chip).
+        Exactly one decode step is in flight between two iterations of
+        the loop thread, so the host's work runs beside the device. What
+        step N+1 needs is known without N's tokens: its input tokens are
+        N's result on the device; a riding slot's position is its last
+        plus one; a finish by length is known from `slot.dispatched`;
+        a cancellation was read in `reap`. Only an `eos` in N is seen a
+        step late: that slot rides in N+1, and its token there is dropped
+        when it is read (`_land`: the handle no longer holds the slot).
+        `land=True` (a caller's own `step()`) lands N+1 before returning.
+
+        What a dropped slot-step wrote is harmless, by the invariant the
+        parked row relies on: a free slot's rows are masked for its next
+        occupant until that occupant's own writes cover them. The late
+        step wrote one row inside the old stream's budget, as does the
+        step after a stream's last by length, in which the slot does not
+        ride and stays at its next row; for a model with recurrent state
+        it also updated the slot's `state`, `tail` and `ck` leaves, as
+        every step does for a parked slot. The slot's next admission is
+        dispatched after it, so device order puts the admission's writes
+        last: `shared=False` replaces the slot's whole row of every leaf,
+        the recurrent state included; `shared=True` (K/V leaves only)
+        gathers pages into rows [0, start) and prefills [start, prompt),
+        which are also all that `_seal_prefix` copies out; rows past the
+        prompt stay masked until the new stream's decode reaches them,
+        each written before it is read.
+
+        A step's tokens are recorded when they are read and their callers
+        woken a little later: before the next admission, else once the
+        next decode step is dispatched, else when nothing is active. A
+        handler thread takes the GIL to write its chunk; woken before a
+        dispatch, each open stream kept the loop from it (a slope of
+        0.11 ms a stream in `sala-longdoc`'s gaps: PERF.md, PR 30).
 
         The iteration is tiled by `tony.engine.*` spans on the profiler's
         clock (observability/spans.py; docs/OBSERVABILITY.md lists them):
@@ -737,67 +826,101 @@ class ContinuousBatchingEngine:
             before = self.stats.admissions_total
             t_admit = time.monotonic()
             admitted = self._admit_pending() or reaped
-            admit_s = time.monotonic() - t_admit
-            active = [s for s in self._slots if s.active]
-            ph.note(active=len(active),
+            self._admit_s += time.monotonic() - t_admit
+            riders = [s for s in self._slots if s.rides]
+            ph.note(active=len(riders),
                     admitted=self.stats.admissions_total - before)
-            if not active:
-                self._tokens_landed_at = None
+            landing = []
+            if self._in_flight is not None:
+                landing, self._in_flight = [self._in_flight], None
+            if not riders and not landing:
+                self._read_ended_at = None
                 self._deliver()
                 return admitted
-            ph.enter("tony.engine.decode.prepare")
-            self._key, step_key = jax.random.split(self._key)
-            tokens = jnp.asarray(self._tokens_np)
-            pos = jnp.asarray(self._pos_np)
-            ph.enter("tony.engine.decode.dispatch")
-            nxt, self._cache = _decode_sample_step(
-                self.params, self.config, self._cache, tokens, pos,
-                step_key, self.temperature, self.top_k, self.top_p)
-            dispatched_at = time.monotonic()
-            attended = context = 0
-            if self._sparse_reads is not None:
-                for slot in active:
-                    a, c = self._sparse_reads(slot.pos + 1)
-                    attended, context = attended + a, context + c
-            with self._lock:
-                self.stats.decode_steps_total += 1
-                self.stats.decode_slot_steps_total += len(active)
-                self.stats.sparse_blocks_attended_total += attended
-                self.stats.sparse_context_blocks_total += context
-                if self._tokens_landed_at is not None:
-                    self.stats.step_host_s.append(
-                        dispatched_at - self._tokens_landed_at - admit_s)
-            ph.enter("tony.engine.decode.wait")
-            self._deliver()
-            nxt_np = np.asarray(jax.device_get(nxt))
-            if self._test_decode_delay_s > 0:
-                # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
-                # decode by a fixed per-step delay — the slow-hop-attribution
-                # e2e's guilty replica
-                time.sleep(self._test_decode_delay_s)
-            now = self._tokens_landed_at = time.monotonic()
-            ph.enter("tony.engine.emit")
-            for slot in active:
-                token = int(nxt_np[slot.index])
-                slot.pos += 1
-                self._pos_np[slot.index] = slot.pos
-                self._tokens_np[slot.index] = token
-                slot.emitted += 1
-                slot.handle._record(token, now)
-                self._undelivered.append((slot.handle, token))
+            if riders:
+                ph.enter("tony.engine.decode.prepare")
+                # every slot is stepped; one that does not ride stays where
+                # it is (a freed one at its parked row, `_finish_slot`). A
+                # fresh array a step: the call may still be reading the
+                # one before
+                pos = np.fromiter((s.pos for s in self._slots), np.int32,
+                                  self.n_slots)
+                ph.enter("tony.engine.decode.dispatch")
+                self._tokens, self._cache = _decode_sample_step(
+                    self.params, self.config, self._cache, self._tokens,
+                    pos, self._key, self._next_draw(), self.temperature,
+                    self.top_k, self.top_p)
+                flight = _Flight(self._tokens,
+                                 [(s, s.handle, s.pos) for s in riders])
+                for slot in riders:
+                    slot.pos += 1
+                    slot.dispatched += 1
                 with self._lock:
-                    self.stats.tokens_emitted += 1
-                    self.stats.itl_s.append(now - slot.last_emit_at)
-                slot.last_emit_at = now
-                self._maybe_finish(slot, token, now)
+                    self.stats.decode_steps_total += 1
+                    self.stats.decode_steps_overlapped_total += bool(landing)
+                if land:
+                    landing.append(flight)
+                else:
+                    self._in_flight = flight
+            if not landing:
+                self._deliver()     # the first step after an idle engine
+            for flight in landing:
+                self._land(flight, ph)
             ph.enter("tony.engine.release")
-            # the step's device arrays die here, inside a leaf, and not a
+            # the read step's record dies here, inside a leaf, and not a
             # moment later at the return: freeing a device buffer releases
             # the GIL, and whatever thread wants it takes it before the
-            # loop gets it back: a wait that would otherwise lie between
-            # two steps, under no span
-            del nxt, tokens, pos, step_key
+            # loop gets it back: a wait that would otherwise lie under no
+            # span
+            del landing, flight
             return True
+
+    def _next_draw(self) -> np.int32:
+        """The number of the next sampling draw (`_draw_key`)."""
+        self._draws = (self._draws + 1) & 0x7FFFFFFF
+        return np.int32(self._draws)
+
+    def _land(self, flight: _Flight, ph: Phases) -> None:
+        """Wait for a dispatched step's tokens (`decode.wait`, after the
+        wake-ups the loop held back) and book them (`emit`)."""
+        ph.enter("tony.engine.decode.wait")
+        self._deliver()
+        started = time.monotonic()
+        if self._read_ended_at is not None:
+            with self._lock:
+                self.stats.step_host_s.append(
+                    started - self._read_ended_at - self._admit_s)
+        nxt_np = np.asarray(jax.device_get(flight.tokens))
+        if self._test_decode_delay_s > 0:
+            # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
+            # decode by a fixed per-step delay — the slow-hop-attribution
+            # e2e's guilty replica
+            time.sleep(self._test_decode_delay_s)
+        now = self._read_ended_at = time.monotonic()
+        self._admit_s = 0.0
+        ph.enter("tony.engine.emit")
+        gaps, attended, context = [], 0, 0
+        for slot, handle, pos in flight.riders:
+            if slot.handle is not handle:
+                continue    # the stream ended while this step was in flight
+            token = int(nxt_np[slot.index])
+            if self._sparse_reads is not None:
+                a, c = self._sparse_reads(pos + 1)
+                attended, context = attended + a, context + c
+            slot.emitted += 1
+            handle._record(token, now)
+            self._undelivered.append((handle, token))
+            gaps.append(now - slot.last_emit_at)
+            slot.last_emit_at = now
+            self._maybe_finish(slot, token, now)
+        with self._lock:
+            self.stats.tokens_emitted += len(gaps)
+            self.stats.itl_s.extend(gaps)
+            self.stats.decode_slot_steps_total += len(gaps)
+            self.stats.decode_slot_steps_discarded_total += (
+                len(flight.riders) - len(gaps))
+            self.stats.sparse_blocks_attended_total += attended
+            self.stats.sparse_context_blocks_total += context
 
     def _admit_pending(self) -> bool:
         admitted = False
@@ -834,7 +957,6 @@ class ContinuousBatchingEngine:
         # span, its `prepare` leaf open since before the dequeue.
         t_dequeue = time.monotonic()
         handle.queue_wait_s = t_dequeue - handle.submitted_at
-        self._key, req_key = jax.random.split(self._key)
         pool = self.kv_pool
         start = 0
         depth = 0
@@ -854,18 +976,21 @@ class ContinuousBatchingEngine:
                                 kvc.SCRATCH_PAGE, np.int32)
                 table[:depth] = page_ids
                 self._cache = kvc.gather_pages(
-                    self._cache, pool.pool, jnp.asarray(table),
-                    jnp.int32(slot.index))
+                    self._cache, pool.pool, table, np.int32(slot.index))
                 start = depth * pool.page_size
                 handle.kv_matched_tokens = start
                 handle.kv_match_s = time.monotonic() - t_dequeue
-        prompt = jnp.asarray(handle.prompt[start:], jnp.int32)
-        slot_dev, start_dev = jnp.int32(slot.index), jnp.int32(start)
+        # host arrays straight into the jitted call: an upload each, and
+        # no eager program (a `jnp.int32(...)` is one) ahead of it. Behind
+        # the decode step in flight by device order, so it overwrites what
+        # that step wrote to this slot (`_step`)
+        prompt = np.asarray(handle.prompt[start:], np.int32)
         ph.enter("tony.engine.admit.dispatch")
-        tok0_dev, self._cache = _admit_step(
-            self.params, self.config, self._cache, prompt, slot_dev,
-            req_key, self.temperature, self.top_k, self.top_p,
-            self.quant_cache, start_dev, pool is not None)
+        tok0_dev, self._tokens, self._cache = _admit_step(
+            self.params, self.config, self._cache, self._tokens, prompt,
+            np.int32(slot.index), self._key, self._next_draw(),
+            self.temperature, self.top_k, self.top_p, self.quant_cache,
+            np.int32(start), pool is not None)
         ph.enter("tony.engine.admit.wait")
         tok0 = int(jax.device_get(tok0_dev))
         ph.enter("tony.engine.admit.book")
@@ -887,10 +1012,8 @@ class ContinuousBatchingEngine:
         handle.admitted_at = now
         slot.handle = handle
         slot.pos = len(handle.prompt)
-        slot.emitted = 1
+        slot.emitted = slot.dispatched = 1
         slot.last_emit_at = now
-        self._pos_np[slot.index] = slot.pos
-        self._tokens_np[slot.index] = tok0
         handle._push(tok0, now)
         dense = self._sparse_reads is not None and \
             self.config.dense_context(len(handle.prompt))
@@ -952,9 +1075,8 @@ class ContinuousBatchingEngine:
             newly.append(digest)
             parent = digest
         if newly:
-            pool.pool = kvc.seal_pages(pool.pool, self._cache,
-                                       jnp.asarray(table),
-                                       jnp.int32(slot.index))
+            pool.pool = kvc.seal_pages(pool.pool, self._cache, table,
+                                       np.int32(slot.index))
             for digest in newly:
                 pool.unpin(digest)
 
@@ -963,7 +1085,8 @@ class ContinuousBatchingEngine:
         """Host-side copy of the slot's computed K/V rows [0, pos) plus
         the sampler state a decode replica needs to continue exactly
         where this admission stopped (tok0's own K/V is written by the
-        FIRST decode step, there as here)."""
+        FIRST decode step, there as here). The read follows the admission
+        on the device, and so the decode step in flight before it."""
         leaves = {}
         for name, arr in self._cache.items():
             row = np.asarray(jax.device_get(arr[:, slot.index]))
@@ -991,19 +1114,19 @@ class ContinuousBatchingEngine:
             full = np.zeros((l, 1, h, s, d), leaf.dtype)
             full[:, 0, :, :pos, :] = leaf
             rows[name] = jnp.asarray(full)
-        slot_dev = jnp.int32(slot.index)
+        slot_dev = np.int32(slot.index)
         ph.enter("tony.engine.admit.dispatch")
         self._cache = kvc.install_rows(self._cache, rows, slot_dev)
+        self._tokens = _seed_token(self._tokens, slot_dev,
+                                   np.int32(install["tok0"]))
         ph.enter("tony.engine.admit.book")
         now = time.monotonic()
         handle.prefill_s = now - t_dequeue
         handle.admitted_at = now
         slot.handle = handle
         slot.pos = pos
-        slot.emitted = int(install.get("emitted", 1))
+        slot.emitted = slot.dispatched = int(install.get("emitted", 1))
         slot.last_emit_at = now
-        self._pos_np[slot.index] = pos
-        self._tokens_np[slot.index] = int(install["tok0"])
         with self._lock:
             self.stats.queue_wait_s.append(handle.queue_wait_s)
             self.stats.prefill_s.append(handle.prefill_s)
@@ -1026,13 +1149,14 @@ class ContinuousBatchingEngine:
 
     def _finish_slot(self, slot: _Slot, reason: str, now: float) -> None:
         """Free a slot (eos/length latch, or a cancelled request) and
-        recycle it immediately."""
+        recycle it immediately. A decode step in flight may still hold a
+        token for the handle: `_land` drops it (`_step` says why what that
+        step wrote does no harm)."""
         handle, slot.handle = slot.handle, None
         # park the freed slot's decode writes at the last budget row:
         # always masked for the next occupant until its own decode
         # overwrites it
         slot.pos = self.token_budget - 1
-        self._pos_np[slot.index] = slot.pos
         handle._record_finish(reason, now)
         self._undelivered.append((handle, _DONE))
         with self._lock:
@@ -1075,9 +1199,20 @@ class ContinuousBatchingEngine:
         with finish_reason='shutdown' so no caller blocks forever."""
         self._stop.set()
         self._work.set()
+        joined = True
         if self._thread is not None:
             self._thread.join(timeout=5.0)
+            joined = not self._thread.is_alive()
             self._thread = None
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None and joined:
+            # the step the loop left in flight: its tokens were made, so
+            # their streams get them before they end
+            try:
+                with Phases("tony.engine.step", step=self._steps) as ph:
+                    self._land(flight, ph)
+            except Exception:  # noqa: BLE001 — shutdown must not hang
+                LOG.exception("the step in flight was not read")
         self._deliver()
         now = time.monotonic()
         with self._lock:
@@ -1124,6 +1259,10 @@ class ContinuousBatchingEngine:
                 "decode_slot_steps_total":
                     self.stats.decode_slot_steps_total,
                 "admissions_total": self.stats.admissions_total,
+                "decode_steps_overlapped_total":
+                    self.stats.decode_steps_overlapped_total,
+                "decode_slot_steps_discarded_total":
+                    self.stats.decode_slot_steps_discarded_total,
             }
             if self.kv_pool is not None:
                 snap.update(self.kv_pool.stats_fields())
@@ -1144,8 +1283,9 @@ class ContinuousBatchingEngine:
             _phase_percentiles(snap, "prefill_s", self.stats.prefill_s)
             _phase_percentiles(snap, "decode_ms_per_token",
                                self.stats.itl_s, scale=1000.0)
-            # the host's share of the gap between two decode steps
-            # (/v1/metrics only: the counterpart of the loop's spans)
+            # the loop thread's time a decode iteration, outside its wait
+            # on the device (/v1/metrics only: the counterpart of the
+            # loop's spans)
             _phase_percentiles(snap, "step_host_ms",
                                self.stats.step_host_s, scale=1000.0)
             return snap
