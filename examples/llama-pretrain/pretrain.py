@@ -151,8 +151,8 @@ def main() -> int:
             grad_accum=args.grad_accum,
             eval_every=args.eval_every,
             master_weights=args.master_weights,
-            # MFU/goodput accounting (observability/perf.py): MoE configs
-            # report on ACTIVE params via their flops_per_token override
+            # MFU/goodput accounting (observability/perf.py), on the
+            # benchmark's scale; MoE configs count ACTIVE matmul params
             flops_per_token=config.flops_per_token(seq)),
         param_axes=param_axes,
         eval_data_iter=(_eval_stream(args, seq, config, process_index)

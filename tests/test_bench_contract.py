@@ -1,153 +1,61 @@
-"""The bench measurement contract: the driver keeps only a ~2 KB tail of
-stdout and parses the final line from it, so that line must be ONE compact
-JSON object."""
+"""The host control-plane harness's contract (tools/control_plane_bench.py):
+what it appends to its history, what every appended line discloses, and
+how tools/bench_compare.py gates that history. CPU numbers under CPU
+names; the chip's numbers are benchmark/'s."""
 
-import importlib.util
 import json
 import os
+import subprocess
 import sys
 
-_spec = importlib.util.spec_from_file_location(
-    "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-bench = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(bench)
+import tools.control_plane_bench as bench
 
 import pytest  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
 def _isolated_bench_paths(tmp_path, monkeypatch):
-    """EVERY path bench can write rides through these module globals;
-    redirecting them wholesale means no test can ever leak a fabricated
-    measurement into the real tools/ history."""
+    """The one path the harness writes rides through this module global;
+    redirecting it means no test can ever leak a fabricated measurement
+    into the real tools/ history."""
     tools = tmp_path / "tools"
     tools.mkdir()
-    monkeypatch.setattr(bench, "_TOOLS_DIR", str(tools))
     monkeypatch.setattr(bench, "_HISTORY_PATH",
                         str(tools / "bench_history.jsonl"))
     monkeypatch.setattr(bench, "_commit_stamp", lambda: "testhead")
     yield tools
 
 
-def test_compact_is_single_bounded_line():
-    s = bench._compact("a\nb\r\n  c  \n" + "x" * 500, 40)
-    assert "\n" not in s and len(s) <= 40
-    assert bench._compact("short", 100) == "short"
-
-
-def test_emit_line_is_bounded_and_parseable(capsys):
-    result = {
-        "metric": bench.METRIC, "value": 0.0, "unit": "%MFU",
-        "vs_baseline": 0.0,
-        "scraped_metrics": "e" * 2000,
-        "am_startup_latency": {"runs": 3, "pad": "q" * 2000},
-        "error": "z" * 2000,
-    }
-    bench._emit(result)
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    assert len(line) <= 1500, len(line)
-    parsed = json.loads(line)
-    # the headline fields survive every truncation
-    for key in ("metric", "value", "unit", "vs_baseline"):
-        assert key in parsed, key
-    # dropped fields are recorded
-    assert "truncated" in parsed
-
-
-def test_emit_small_result_untouched(capsys):
-    result = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
-              "vs_baseline": 1.702}
-    bench._emit(result)
-    line = capsys.readouterr().out.strip()
-    assert json.loads(line) == result
-
-
-def test_input_stall_field_from_prefetch_feed():
-    """The overlapped-input contract: the bench's timed region must pull
-    its batches through the prefetch path, and the stall helper turns its
-    accounting into the headline `input_stall_ms_per_step` field."""
-    from tony_tpu.train.data import PrefetchIterator
-
-    feed = PrefetchIterator(bench._lm_feed(64, 2, 8), depth=2,
-                            transfer=lambda b: b)
-    try:
-        for _ in range(2):        # warmup pulls, outside the timed region
-            next(feed)
-        snap = feed.stall_snapshot()
-        for _ in range(3):
-            batch = next(feed)
-        assert set(batch) == {"inputs", "targets"}
-        assert batch["inputs"].shape == (2, 8)
-        stall = bench._input_stall_ms_per_step(feed, snap, 3)
-        assert stall >= 0.0
-    finally:
-        feed.close()
-
-
-def test_input_stall_fails_loudly_when_prefetch_bypassed():
-    """A plain iterator silently replacing the prefetch path must raise,
-    not report an MFU that hides input serialization."""
-    with pytest.raises(TypeError, match="prefetch"):
-        bench._input_stall_ms_per_step(iter([{"inputs": None}]), (0.0, 0),
-                                       1)
-    # a feed that exists but starved/was not consumed also fails
-    from tony_tpu.train.data import PrefetchIterator
-
-    feed = PrefetchIterator(bench._lm_feed(64, 2, 8), depth=1,
-                            transfer=lambda b: b)
-    try:
-        with pytest.raises(ValueError, match="bypassed or starved"):
-            bench._input_stall_ms_per_step(feed, feed.stall_snapshot(), 3)
-    finally:
-        feed.close()
-
-
-def test_emit_preserves_input_stall_field(capsys):
-    """input_stall_ms_per_step is a headline field: it must survive
-    _emit's truncation ladder (it is not in drop_order)."""
-    result = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
-              "vs_baseline": 1.702, "input_stall_ms_per_step": 0.41,
-              "prefetch_depth": 2,
-              "scraped_metrics": "e" * 2000, "error": "z" * 2000}
-    bench._emit(result)
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    parsed = json.loads(line)
-    assert parsed["input_stall_ms_per_step"] == 0.41
-    assert parsed["prefetch_depth"] == 2
-
-
-def test_history_append_and_regression_verdict(_isolated_bench_paths,
-                                               capsys):
-    """Self-defending bench: every _emit appends a commit-stamped line
-    to bench_history.jsonl, and bench_compare flags a >2% drop vs the
-    best same-backend baseline (value<=0 fallback markers are skipped
-    both as baseline and as the judged entry)."""
+def test_history_append_and_regression_verdict(_isolated_bench_paths):
+    """Every gated headline is appended commit- and time-stamped to
+    bench_history.jsonl, and bench_compare flags a >2% worsening against
+    the best same-backend baseline (value<=0 markers are skipped both as
+    baseline and as the judged entry)."""
     from tools.bench_compare import compare, load_history
-    good = {"metric": bench.METRIC, "value": 68.08, "unit": "%MFU",
-            "vs_baseline": 1.702, "device": "TPU v5 lite"}
-    wedged = {"metric": bench.METRIC, "value": 0.0, "unit": "%MFU",
-              "vs_baseline": 0.0, "backend": "tpu"}
-    bad = {"metric": bench.METRIC, "value": 60.0, "unit": "%MFU",
-           "vs_baseline": 1.5, "device": "TPU v5 lite"}
-    for r in (good, wedged, bad):
-        bench._emit(r)
-    capsys.readouterr()
+    base = {"metric": "control_plane_all_registered", "unit": "s",
+            "backend": "cpu", "width": 1024}
+    good, wedged, bad = (dict(base, value=v) for v in (0.365, 0.0, 0.5))
+    for r in (good, wedged, dict(bad, error="dropped", scraped_metrics="x")):
+        bench._append_history(r)
     entries = load_history(str(_isolated_bench_paths
                                / "bench_history.jsonl"))
     assert len(entries) == 3
-    assert all(e["commit"] == "testhead" for e in entries)
+    assert all(e["commit"] == "testhead" and e["measured_at"]
+               for e in entries)
+    # heavy diagnostic fields never reach the history
+    assert not any("error" in e or "scraped_metrics" in e for e in entries)
     verdicts = compare(entries, threshold_pct=2.0)
     assert len(verdicts) == 1          # one (metric, backend) group
     v = verdicts[0]
-    assert v["backend"] == "tpu" and v["regression"] is True
-    assert v["baseline"] == 68.08 and v["value"] == 60.0
+    assert v["backend"] == "cpu" and v["regression"] is True
+    assert v["baseline"] == 0.365 and v["value"] == 0.5
     # within threshold → no regression
-    ok = compare([good, dict(good, value=67.5)], threshold_pct=2.0)
+    ok = compare([good, dict(good, value=0.37)], threshold_pct=2.0)
     assert ok[0]["regression"] is False
-    # lower-is-better units judge in the other direction
-    lat = [{"metric": "p99", "value": 1.0, "unit": "s", "backend": "cpu"},
-           {"metric": "p99", "value": 1.5, "unit": "s", "backend": "cpu"}]
-    assert compare(lat, threshold_pct=2.0)[0]["regression"] is True
+    # a unit that is not a time or a size judges higher-is-better
+    rate = [{"metric": "r", "value": 10.0, "unit": "1/s", "backend": "cpu"},
+            {"metric": "r", "value": 8.0, "unit": "1/s", "backend": "cpu"}]
+    assert compare(rate, threshold_pct=2.0)[0]["regression"] is True
     # bytes (the control-plane spec fan-out gate) are lower-is-better
     # too: a chatty regression — spec bytes creeping back up — must fail
     fanout = [{"metric": "control_plane_spec_bytes", "value": 1.0e6,
@@ -159,46 +67,57 @@ def test_history_append_and_regression_verdict(_isolated_bench_paths,
                    threshold_pct=2.0)[0]["regression"] is False
 
 
-def test_fleet_headlines_append_and_compare_round_trip(tmp_path,
-                                                       monkeypatch):
-    """serve_bench --fleet's two headlines ride the same history →
-    bench_compare gate as bench.py's: the throughput entry (tok/s)
-    judges higher-is-better, the TTFT tail entry (unit "s") judges
-    lower-is-better, and both carry the commit stamp + the cpu-by-
-    contract tpu_unavailable_reason marker."""
-    import tools.serve_bench as sb
-    from tools.bench_compare import compare, load_history
+_STUB_LEG_METRICS = ("control_plane_spec_bytes", "control_plane_hb_p95",
+                     "control_plane_all_registered",
+                     "control_plane_resize_roundtrip")
+_GATED_METRICS = _STUB_LEG_METRICS + (
+    "control_plane_real_all_running", "resize_grow_latency",
+    "control_plane_am_recovery")
 
-    hist = tmp_path / "bench_history.jsonl"
-    monkeypatch.setattr(sb, "HISTORY_PATH", str(hist))
-    monkeypatch.setattr(sb, "_commit_stamp", lambda: "fleethead")
-    sb.append_history({"metric": "serving_fleet_tokens_per_sec",
-                       "value": 400.0, "unit": "tok/s", "replicas": 4})
-    sb.append_history({"metric": "serving_fleet_ttft_p95_s",
-                       "value": 0.10, "unit": "s", "replicas": 4})
-    # a later, worse run: slower fleet AND a fatter TTFT tail
-    sb.append_history({"metric": "serving_fleet_tokens_per_sec",
-                       "value": 300.0, "unit": "tok/s", "replicas": 4})
-    sb.append_history({"metric": "serving_fleet_ttft_p95_s",
-                       "value": 0.15, "unit": "s", "replicas": 4})
+
+def test_smallest_leg_runs_as_a_script_and_appends_its_headlines(tmp_path):
+    """`python tools/control_plane_bench.py` from the checkout's root —
+    the stub storm alone, at a width of 8 — prints ONE JSON line that
+    names the CPU, and appends exactly the stub leg's gated headlines to
+    the history it was pointed at."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    hist = tmp_path / "history.jsonl"
+    env = dict(os.environ, TONY_BENCH_HISTORY_PATH=str(hist),
+               TONY_CP_WIDTHS="8", TONY_CP_REAL_WIDTHS="",
+               TONY_CP_RECOVERY_WIDTH="", JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join("tools", "control_plane_bench.py")],
+        cwd=root, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = proc.stdout.strip().splitlines()
+    assert len(lines) == 1, lines
+    result = json.loads(lines[0])
+    assert result["metric"] == "control_plane"
+    assert result["backend"] == "cpu"
+    assert "error" not in result and "real_error" not in result
+    row = result["control_plane"]["widths"][0]
+    assert row["width"] == 8 and row["registered"] and row["bounded"]
+    from tools.bench_compare import load_history
     entries = load_history(str(hist))
-    assert len(entries) == 4
-    assert all(e["commit"] == "fleethead" and e["backend"] == "cpu"
-               and e["tpu_unavailable_reason"].startswith("not-applicable")
-               for e in entries)
-    verdicts = {v["metric"]: v for v in compare(entries, threshold_pct=2.0)}
-    assert verdicts["serving_fleet_tokens_per_sec"]["regression"] is True
-    assert verdicts["serving_fleet_ttft_p95_s"]["regression"] is True
-    # ...and an IMPROVED run passes both gates (ttft lower = better)
-    sb.append_history({"metric": "serving_fleet_tokens_per_sec",
-                       "value": 450.0, "unit": "tok/s", "replicas": 4})
-    sb.append_history({"metric": "serving_fleet_ttft_p95_s",
-                       "value": 0.08, "unit": "s", "replicas": 4})
-    verdicts = {v["metric"]: v
-                for v in compare(load_history(str(hist)),
-                                 threshold_pct=2.0)}
-    assert verdicts["serving_fleet_tokens_per_sec"]["regression"] is False
-    assert verdicts["serving_fleet_ttft_p95_s"]["regression"] is False
+    assert tuple(e["metric"] for e in entries) == _STUB_LEG_METRICS
+    assert all(e["backend"] == "cpu" and e["value"] > 0
+               and e["unit"] in ("s", "ms", "bytes") and e["width"] == 8
+               and "profiler_overhead_pct" in e for e in entries)
+
+
+def test_checked_in_history_holds_only_what_the_harness_emits():
+    """tools/bench_history.jsonl is the control-plane harness's record
+    and nothing else's: every line is one of its gated CPU headlines in
+    a lower-is-better unit, and bench_compare passes it. A rate, an MFU
+    or a latency of the chip belongs to PERF_LEDGER.jsonl."""
+    from tools.bench_compare import DEFAULT_HISTORY, compare, load_history
+    entries = load_history(DEFAULT_HISTORY)
+    assert entries
+    for e in entries:
+        assert e["metric"] in _GATED_METRICS, e["metric"]
+        assert e["backend"] == "cpu" and e["unit"] in ("s", "ms", "bytes")
+    assert not [v for v in compare(entries, threshold_pct=2.0)
+                if v["regression"]]
 
 
 @pytest.mark.warmpool
@@ -291,146 +210,3 @@ def test_cp_disclosure_stamps_warm_fields():
     d = bench._cp_disclosure({"warm": False, "spawn_s": 0.6})
     assert d["warm_pool"] is False
     assert "cold_baseline_s" not in d
-
-
-@pytest.mark.kv
-def test_prefix_reuse_headlines_gate_units_and_disclosure(tmp_path,
-                                                          monkeypatch):
-    """serve_bench --prefix-reuse appends ONLY a strict double win (ON
-    beats OFF on throughput AND TTFT), every line carries the KV
-    hit-rate disclosure next to the number it justifies, and the two
-    headlines ride the same bench_compare gate: tok/s judged
-    higher-is-better, unit "s" judged lower-is-better."""
-    import tools.serve_bench as sb
-    from tools.bench_compare import compare, load_history
-
-    on = {"tokens_per_sec": 120.0, "ttft_p95_s": 0.040,
-          "kv_hit_rate_pct": 55.4, "requests_errored": 0}
-    off = {"tokens_per_sec": 100.0, "ttft_p95_s": 0.050,
-           "requests_errored": 0}
-    entries = sb.build_prefix_history_entries(on, off, "bench_350m", 0.6)
-    assert [e["metric"] for e in entries] == [
-        "serving_prefix_tokens_per_sec", "serving_prefix_ttft_p95_s"]
-    assert entries[0]["unit"] == "tok/s" and entries[0]["value"] == 120.0
-    assert entries[1]["unit"] == "s" and entries[1]["value"] == 0.040
-    for e in entries:
-        # the disclosure contract: hit rate + baseline on EVERY line
-        assert e["kv_hit_rate_pct"] == 55.4
-        assert e["reuse_ratio"] == 0.6
-        assert e["baseline_tokens_per_sec"] == 100.0
-        assert e["baseline_ttft_p95_s"] == 0.050
-        assert e["model"] == "bench_350m"
-
-    # the gate: a tps win with a ttft LOSS appends nothing (and vice
-    # versa) — half-wins would poison the baseline for later commits
-    assert sb.build_prefix_history_entries(
-        {**on, "ttft_p95_s": 0.060}, off, "bench_350m", 0.6) == []
-    assert sb.build_prefix_history_entries(
-        {**on, "tokens_per_sec": 90.0}, off, "bench_350m", 0.6) == []
-    # degenerate measurements and errored rounds append nothing
-    assert sb.build_prefix_history_entries(
-        {**on, "tokens_per_sec": 0.0}, off, "bench_350m", 0.6) == []
-    assert sb.build_prefix_history_entries(
-        on, {**off, "ttft_p95_s": 0.0}, "bench_350m", 0.6) == []
-    assert sb.build_prefix_history_entries(
-        {**on, "requests_errored": 2}, off, "bench_350m", 0.6) == []
-    assert sb.build_prefix_history_entries(
-        on, {**off, "requests_errored": 1}, "bench_350m", 0.6) == []
-
-    # append → bench_compare round trip: a later WORSE run regresses on
-    # both gates, a later better run passes both
-    hist = tmp_path / "bench_history.jsonl"
-    monkeypatch.setattr(sb, "HISTORY_PATH", str(hist))
-    monkeypatch.setattr(sb, "_commit_stamp", lambda: "prefixhead")
-    for e in entries:
-        sb.append_history(e)
-    worse = sb.build_prefix_history_entries(
-        {"tokens_per_sec": 101.0, "ttft_p95_s": 0.049,
-         "kv_hit_rate_pct": 12.0, "requests_errored": 0},
-        off, "bench_350m", 0.6)
-    for e in worse:
-        sb.append_history(e)
-    loaded = load_history(str(hist))
-    assert len(loaded) == 4
-    assert all(e["commit"] == "prefixhead" and e["backend"] == "cpu"
-               for e in loaded)
-    verdicts = {v["metric"]: v for v in compare(loaded, threshold_pct=2.0)}
-    assert verdicts["serving_prefix_tokens_per_sec"]["regression"] is True
-    assert verdicts["serving_prefix_ttft_p95_s"]["regression"] is True
-    for e in sb.build_prefix_history_entries(
-            {"tokens_per_sec": 130.0, "ttft_p95_s": 0.035,
-             "kv_hit_rate_pct": 60.0, "requests_errored": 0},
-            off, "bench_350m", 0.6):
-        sb.append_history(e)
-    verdicts = {v["metric"]: v
-                for v in compare(load_history(str(hist)),
-                                 threshold_pct=2.0)}
-    assert verdicts["serving_prefix_tokens_per_sec"]["regression"] is False
-    assert verdicts["serving_prefix_ttft_p95_s"]["regression"] is False
-
-
-@pytest.mark.reqtrace
-def test_ttft_attribution_stamps_are_sum_consistent():
-    """Every serve_bench JSON line's TTFT-attribution disclosure must be
-    sum-consistent AS EMITTED: the rounded components plus unattributed
-    equal the rounded total exactly, so a reader can audit where the p95
-    first-token time went without re-deriving anything."""
-    import tools.serve_bench as sb
-
-    attr = sb.ttft_attribution(0.050, queue_wait_s=0.010,
-                               prefill_s=0.020, route_ms=4.0,
-                               migrate_ms=3.0)
-    keys = {"ttft_attr_route_ms", "ttft_attr_queue_ms",
-            "ttft_attr_prefill_ms", "ttft_attr_migrate_ms",
-            "ttft_attr_decode_ms", "ttft_attr_unattributed_ms",
-            "ttft_attr_total_ms"}
-    assert set(attr) == keys
-    assert attr["ttft_attr_route_ms"] == 4.0
-    assert attr["ttft_attr_queue_ms"] == pytest.approx(10.0)
-    assert attr["ttft_attr_decode_ms"] == pytest.approx(17.0)  # remainder
-    assert attr["ttft_attr_total_ms"] == pytest.approx(54.0)   # route+ttft
-    # the contract: rounded parts sum to the rounded total EXACTLY
-    parts = sum(v for k, v in attr.items() if k != "ttft_attr_total_ms")
-    assert parts == attr["ttft_attr_total_ms"]
-
-    # phase breakdown unknown (fleet path through the router): nothing
-    # is guessed — decode stays 0 and the gap lands in unattributed
-    blind = sb.ttft_attribution(0.050)
-    assert blind["ttft_attr_decode_ms"] == 0.0
-    assert blind["ttft_attr_unattributed_ms"] == pytest.approx(50.0)
-    parts = sum(v for k, v in blind.items() if k != "ttft_attr_total_ms")
-    assert parts == blind["ttft_attr_total_ms"]
-
-    # awkward floats cannot break the emitted-sum identity
-    messy = sb.ttft_attribution(0.0333333, queue_wait_s=0.0111111,
-                                prefill_s=0.0077777, route_ms=1.2345678)
-    parts = sum(v for k, v in messy.items() if k != "ttft_attr_total_ms")
-    assert round(parts, 3) == messy["ttft_attr_total_ms"]
-
-
-@pytest.mark.reqtrace
-@pytest.mark.serving
-def test_serve_bench_single_engine_line_carries_attribution(monkeypatch,
-                                                            capsys):
-    """The single-engine serve_bench JSON line stamps the attribution
-    next to the TTFT it explains (run the smallest real round rather
-    than trusting the helper was wired in)."""
-    import tools.serve_bench as sb
-
-    monkeypatch.setattr(sys, "argv",
-                        ["serve_bench", "--config", "tiny",
-                         "--requests", "4", "--max-new", "4",
-                         "--slots", "2", "--rate", "50"])
-    assert sb.main() == 0
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    result = json.loads(line)
-    assert result["metric"] == "serve_tokens_per_sec"
-    assert result["ttft_attr_total_ms"] >= result["ttft_attr_queue_ms"]
-    parts = sum(v for k, v in result.items()
-                if k.startswith("ttft_attr_")
-                and k != "ttft_attr_total_ms")
-    assert parts == pytest.approx(result["ttft_attr_total_ms"], abs=0.01)
-
-
-if __name__ == "__main__":
-    sys.exit(0)

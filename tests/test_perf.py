@@ -183,10 +183,9 @@ def test_peak_flops_and_mfu_shared_definition():
     # the v5e chip names itself "TPU v5 lite": matched on purpose
     assert perf.peak_flops(_Dev()) == 197e12
     assert perf.peak_flops(_Dev(kind="TPU v5p")) == 459e12
-    # bench re-exports the SAME objects — one definition repo-wide
-    import bench
-    assert bench.peak_flops is perf.peak_flops
-    assert bench.PEAK_FLOPS is perf.PEAK_FLOPS
+    # the tools import the SAME function — one definition repo-wide
+    import tools.aot_rank as aot_rank
+    assert aot_rank.peak_flops is perf.peak_flops
     mfu = perf.mfu_pct(1000.0, 197e6, _Dev())
     assert mfu == pytest.approx(0.1)
     assert perf.mfu_pct(1000.0, 0.0, _Dev()) == 0.0
@@ -217,15 +216,114 @@ def test_mfu_reported_for_llama_and_moe():
     assert llama.flops_per_token(64) > 0
     assert moe.flops_per_token(64) > 0
     assert moe.active_params() < moe.num_params()
-    # flops derive from active params: an all-experts accounting would
-    # exceed this bound
     d, f, L = moe.dim, moe.ffn_dim, moe.n_layers
-    dense_total = 6.0 * moe.num_params() + 12 * L * d * 64
-    assert moe.flops_per_token(64) < dense_total
     expected_active = (type(llama).num_params(moe)
                        + L * ((moe.top_k - 1) * 3 * d * f
                               + d * moe.n_experts))
     assert moe.active_params() == expected_active
+
+
+# One count of a training FLOP: the program's `flops_per_token` is the
+# benchmark's `train_flops_per_token` (behind `mfu_pct`), to the last digit.
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def _benchmark_on_path():
+    import sys
+    if _BENCH not in sys.path:
+        sys.path.insert(0, _BENCH)      # its modules import `lib`
+
+
+def _llama_family():
+    """The benchmark's `llama` family and the train cell's configuration
+    file, found as the harness finds them."""
+    _benchmark_on_path()
+    from lib import spec
+    path = os.path.join(_BENCH, "configs", "mistral-7b-train.json")
+    with open(path, encoding="utf-8") as f:
+        cfg = json.load(f)
+    return spec.load_family(path, cfg), cfg
+
+
+def _as_source_keys(config) -> dict:
+    """A LlamaConfig's widths under the names a configuration file
+    gives them (what the family's counts.py reads)."""
+    return {"hidden_size": config.dim, "intermediate_size": config.ffn_dim,
+            "num_attention_heads": config.n_heads,
+            "num_key_value_heads": config.n_kv_heads,
+            "num_hidden_layers": config.n_layers,
+            "vocab_size": config.vocab_size}
+
+
+@pytest.mark.parametrize("name", [
+    "mistral-7b-train", "llama3_8b", "llama3_70b", "llama3_1b_proxy",
+    "bench_350m", "tiny"])
+def test_program_counts_a_training_flop_as_the_benchmark_does(name):
+    from tony_tpu.models.llama import get_config
+    family, cfg = _llama_family()
+    if name == "mistral-7b-train":      # as the family's program.py maps it
+        config = family.program.program_config(cfg)
+    else:
+        config = get_config(name)
+        cfg = _as_source_keys(config)
+    for seq in (config.max_seq, 64, 333):
+        assert config.flops_per_token(seq) == \
+            family.counts.train_flops_per_token(cfg, seq)
+    assert config.flops_per_token() == config.flops_per_token(config.max_seq)
+    # the sizes both report agree too; what is not a matmul is the
+    # embedding table and the norms
+    assert config.num_params() == family.counts.total_params(cfg)
+    assert config.matmul_params() == family.counts.matmul_params(cfg)
+    assert config.num_params() - config.matmul_params() == (
+        config.vocab_size * config.dim
+        + (2 * config.n_layers + 1) * config.dim)
+
+
+def test_train_cell_shape_reads_the_ledgers_mfu():
+    """`train-4k`'s shape at the ledger's rate: 7.833 GFLOP a token (the
+    parent counted 9.123) and, at 14 568 tokens/s on a v5e, the ledger's
+    `mfu_pct` 57.92 (PERF.md section 6, PR 33)."""
+    family, cfg = _llama_family()
+    config = family.program.program_config(cfg)
+    flops = config.flops_per_token(4096)
+    assert flops == 7_832_862_720.0
+    assert perf.mfu_pct(14568, flops, _Dev()) == pytest.approx(
+        57.92, abs=0.005)
+
+
+@pytest.mark.parametrize("name", ["moe_tiny", "mixtral_proxy"])
+def test_moe_counts_active_matmuls_and_causal_attention(name):
+    """6 x the weights a token is multiplied by — attention projections,
+    top_k of the n_experts MLPs, the router, the head; no table, no norm
+    — plus causal attention, as the dense count."""
+    from tony_tpu.models.moe import get_moe_config
+    moe = get_moe_config(name)
+    d, f, L, hd = moe.dim, moe.ffn_dim, moe.n_layers, moe.head_dim
+    per_layer = (2 * d * moe.n_heads * hd + 2 * d * moe.n_kv_heads * hd
+                 + moe.top_k * 3 * d * f + d * moe.n_experts)
+    active_matmul = L * per_layer + d * moe.vocab_size
+    assert moe.matmul_params() == active_matmul
+    assert moe.active_params() - moe.matmul_params() == (
+        moe.vocab_size * d + (2 * L + 1) * d)
+    for seq in (64, moe.max_seq):
+        assert moe.flops_per_token(seq) == (
+            6.0 * active_matmul + 6 * L * moe.n_heads * hd * seq)
+
+
+def test_program_and_benchmark_agree_on_every_peak_both_name():
+    """The trainer cannot import benchmark/, so the program keeps its own
+    table of peaks; wherever both tables name a device they hold the same
+    bf16 FLOP/s, and the benchmark's device is in the program's."""
+    _benchmark_on_path()
+    from lib.peaks import PEAKS
+    shared = set(PEAKS) & set(perf.PEAK_FLOPS)
+    assert "TPU v5 lite" in shared and set(PEAKS) <= set(perf.PEAK_FLOPS)
+    for kind in shared:
+        assert perf.PEAK_FLOPS[kind] == PEAKS[kind]["bf16_flops_per_s"]
+        assert perf.peak_flops(_Dev(kind=kind)) == \
+            PEAKS[kind]["bf16_flops_per_s"]
 
 
 def test_tokens_in_batch_shapes():

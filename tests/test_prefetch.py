@@ -11,6 +11,7 @@
 """
 
 import queue
+import sys
 import threading
 import time
 
@@ -18,7 +19,8 @@ import numpy as np
 import pytest
 
 from tony_tpu.train.data import (
-    PrefetchIterator, _synthetic_tokens_loop, global_batch_iterator,
+    PrefetchIterator, _affine_prefix_tokens, _synthetic_tokens_loop,
+    global_batch_iterator,
     synthetic_linreg, synthetic_mnist, synthetic_tokens,
 )
 
@@ -207,25 +209,54 @@ def test_vectorized_tokens_obey_recurrence():
     assert np.isin(diff, (0, 1)).all()
 
 
+def _lines_run_for_a_batch(it, *functions) -> int:
+    """Python lines executed inside `functions` while `it` makes one
+    batch. Every such line is a bounded number of numpy calls over whole
+    (batch, ...) arrays, so this counts the host's dispatches — the work
+    — and times nothing."""
+    codes = {f.__code__ for f in functions}
+    lines = 0
+
+    def tracer(frame, event, _arg):
+        nonlocal lines
+        if frame.f_code not in codes:
+            return None
+        if event == "line":
+            lines += 1
+        return tracer
+
+    before = sys.gettrace()
+    sys.settrace(tracer)
+    try:
+        next(it)
+    finally:
+        sys.settrace(before)
+    return lines
+
+
 def test_vectorized_tokens_speedup_at_long_seq():
-    """The acceptance bar: >=5x host-side batch generation at
-    seq_len >= 1024. The loop reference pays O(seq) numpy dispatches per
-    batch; the scan pays ~2*log2(seq). Median-of-3 timing to keep the
-    assertion robust on loaded CI hosts (observed ~10-20x)."""
-    batch, seq, vocab = 4, 2048, 128256
-    vec = synthetic_tokens(batch, seq, vocab)
-    ref = _synthetic_tokens_loop(batch, seq, vocab)
-    next(vec), next(ref)   # warm allocators
+    """What the vectorization is for, counted and not timed: the loop
+    reference pays O(seq) numpy dispatches a batch, the scan
+    O(log2(seq)) — at seq 2048 well over the 5x the change was accepted
+    on, and one more round, not twice the work, for twice the length."""
+    batch, vocab = 4, 128256
 
-    def med3(it):
-        times = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            next(it)
-            times.append(time.perf_counter() - t0)
-        return sorted(times)[1]
+    def scan_lines(seq):
+        return _lines_run_for_a_batch(
+            synthetic_tokens(batch, seq, vocab), synthetic_tokens,
+            _affine_prefix_tokens)
 
-    t_ref, t_vec = med3(ref), med3(vec)
-    assert t_ref / t_vec >= 5.0, (
-        f"vectorized synthetic_tokens only {t_ref / t_vec:.1f}x faster "
-        f"(loop {t_ref * 1e3:.2f} ms vs vec {t_vec * 1e3:.2f} ms)")
+    def loop_lines(seq):
+        return _lines_run_for_a_batch(
+            _synthetic_tokens_loop(batch, seq, vocab),
+            _synthetic_tokens_loop)
+
+    rounds = 11                                     # log2(2048)
+    scan, loop = scan_lines(2048), loop_lines(2048)
+    assert loop >= 2 * 2048                         # a step: `for` + update
+    assert scan <= 5 * rounds + 20, scan
+    assert loop / scan >= 5.0, (loop, scan)
+    per_round = scan - scan_lines(1024)
+    assert 0 < per_round <= 5
+    assert scan_lines(4096) - scan == per_round
+    assert loop_lines(4096) >= 2 * loop - 20

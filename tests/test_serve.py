@@ -284,7 +284,7 @@ def test_frontend_backpressure_fills_429_then_drains_and_accepts(model):
         assert e.value.headers.get("Retry-After")
         # the shed request is a first-class SLI now: the admission
         # counters feed the reject-rate burn-rate alert rule, and the
-        # scrape carries them (serve_bench's scraped-metrics contract)
+        # scrape carries them
         snap = engine.snapshot()
         assert snap["requests_rejected"] == 1
         assert snap["requests_submitted"] == 2     # the two held ones

@@ -86,7 +86,8 @@ def _run_entry(spec: dict) -> int:
         return int(executor_main() or 0)
     if entry == "script":
         # bench/test harness entry: load a module by path and call one
-        # of its functions with the spec argv (bench.py cp_pool_main)
+        # of its functions with the spec argv
+        # (tools/control_plane_bench.py cp_pool_main)
         import importlib.util
         mod_spec = importlib.util.spec_from_file_location(
             "_tony_warm_script", spec["path"])

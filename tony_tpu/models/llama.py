@@ -65,7 +65,8 @@ class LlamaConfig:
     remat: bool = True
     # remat policy: "save_flash" keeps the flash-attention residuals
     # (out+lse, named in ops/attention.py) so the backward replay never
-    # re-runs the fwd kernel — +2.3pp MFU on v5e for ~64MB/layer of bf16;
+    # re-runs the fwd kernel, for ~64MB/layer of bf16 (the train cell
+    # runs it; against "full" it is not measured, PERF.md);
     # "full" rematerializes everything (minimum memory)
     remat_policy: str = "save_flash"
     # sequence-parallel flavor when the mesh shards seq: "ring" streams K/V
@@ -96,20 +97,33 @@ class LlamaConfig:
         return self.dim // self.n_heads
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Approx training FLOPs/token (fwd+bwd ≈ 6N + attention term)."""
-        n = self.num_params()
+        """Training FLOPs/token: forward + backward (3x forward) of the
+        matmuls and of causal attention. The embedding lookup, the norms
+        and recomputed (rematerialised) operations are not counted — the
+        benchmark's count (`mfu_pct` in PERF.md), to the last digit."""
         s = seq_len or self.max_seq
-        attn = 12 * self.n_layers * self.dim * s  # causal: ~half of 2*2*3
-        return 6.0 * n + attn
+        # QK^T and PV, 4*s*hd a head forward, halved under the causal mask
+        attn = 6 * self.n_layers * self.n_heads * self.head_dim * s
+        return 6.0 * self.matmul_params() + attn
 
-    def num_params(self) -> int:
-        d, f, v = self.dim, self.ffn_dim, self.vocab_size
-        hd = self.head_dim
+    def _layer_matmul_params(self) -> int:
+        """One block's attention projections and SwiGLU MLP."""
+        d, hd = self.dim, self.head_dim
         attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
             + (self.n_heads * hd) * d
-        mlp = 3 * d * f
-        per_layer = attn + mlp + 2 * d
+        return attn + 3 * d * self.ffn_dim
+
+    def num_params(self) -> int:
+        d, v = self.dim, self.vocab_size
+        per_layer = self._layer_matmul_params() + 2 * d
         return v * d + self.n_layers * per_layer + d + d * v
+
+    def matmul_params(self) -> int:
+        """Weights a token is multiplied by: every block's projections
+        and MLP, and the output head. The embedding table is a lookup and
+        the norms are elementwise."""
+        return (self.n_layers * self._layer_matmul_params()
+                + self.dim * self.vocab_size)
 
 
 # Presets. llama3_8b mirrors BASELINE.json's target model; the tiny/bench
@@ -331,8 +345,8 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array,
     directly makes the SPMD partitioner inherit the operand's embed-dim
     sharding on the output, and resharding THAT to ("batch","seq",None)
     triggers XLA's "Involuntary full rematerialization" fallback (the
-    warning in MULTICHIP_r03's dense leg). Constraining the ids to the
-    batch layout and un-sharding the table's embed dim first (the
+    warning the multichip dryrun's dense leg printed). Constraining the
+    ids to the batch layout and un-sharding the table's embed dim first (the
     standard FSDP weight all-gather) flips the partitioner to its
     masked-local-gather + all-reduce(tp) path: no replication, and the
     collectives are the same shapes FSDP pays for every weight."""

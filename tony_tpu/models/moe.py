@@ -12,9 +12,8 @@ makes the mesh's `ep` axis real. Design:
   2*T*(E*C)*D = O(k*T^2*D) MXU FLOPs, which at mixtral_proxy scale
   (T=16k, D=2048, k=2) EXCEEDS the expert matmul FLOPs themselves
   (VERDICT r2 item 4). Every shape stays static, so XLA still compiles
-  one program. Measured on a live v5e chip (mixtral_proxy dims, 4 layers,
-  batch 2 x 4096): sparse 242 ms/step vs dense 303 ms/step — and the
-  dense gap grows quadratically with tokens per step.
+  one program. The two modes' step times on the chip are not measured
+  (no cell of the benchmark runs an MoE; PERF.md section 7).
 - **Dense dispatch (dispatch_mode="dense")**: the GShard/Switch one-hot
   einsum formulation, kept as a fallback because its all-to-all insertion
   under an `ep`-sharded mesh is driven purely by shardings (no gather
@@ -85,14 +84,13 @@ class MoEConfig(LlamaConfig):
         return dense + L * ((self.top_k - 1) * 3 * d * f
                             + d * self.n_experts)
 
-    def flops_per_token(self, seq_len=None) -> float:
-        """Approx training FLOPs/token on ACTIVE parameters (6N_active +
-        attention term) — without this override MFU/goodput would read
-        the inherited dense accounting, which for a top-k router is
-        wrong by a factor of ~E/k on the MLP term."""
-        s = seq_len or self.max_seq
-        attn = 12 * self.n_layers * self.dim * s
-        return 6.0 * self.active_params() + attn
+    def matmul_params(self) -> int:
+        """ACTIVE weights a token is multiplied by: the dense count with
+        the one MLP per layer swapped for `top_k` expert MLPs + the
+        router."""
+        d, f, L = self.dim, self.ffn_dim, self.n_layers
+        return super().matmul_params() + L * (
+            (self.top_k - 1) * 3 * d * f + d * self.n_experts)
 
 
 PRESETS = {

@@ -12,9 +12,10 @@ module turns those raw signals into *performance* answers:
   stall boundaries — the hot loop gains no host sync. By construction
   the phase durations sum to wall clock exactly; the e2e test pins the
   flushed `goodput.json` to within 1%.
-- **MFU** (`peak_flops` / `mfu_pct`): the single peak-FLOPs table and
-  MFU formula shared by bench.py, tools/tune_mfu.py, and the trainer's
-  goodput metrics — one definition repo-wide.
+- **MFU** (`peak_flops` / `mfu_pct`): the program's peak-FLOPs table
+  and MFU formula, behind the trainer's goodput metrics. The table
+  agrees with `benchmark/lib/peaks.py` wherever both name a device
+  (tests/test_perf.py); the trainer cannot import `benchmark/`.
 - **Goodput aggregation** (`aggregate_goodput`): the AM folds per-task
   ledgers (arriving as GOODPUT_* gauges over the metrics RPC) plus the
   fault-tolerance layer's relaunch downtime into a job-level
@@ -28,9 +29,9 @@ module turns those raw signals into *performance* answers:
   `jax.profiler` for N steps, and publishes the artifact back through
   the metrics RPC so the AM can link it into history.
 
-No jax import at module level: bench.py's supervisor process imports
-`peak_flops` from here and must stay pure-stdlib until the measurement
-child runs.
+No jax import at module level: control-plane processes (the AM's
+goodput aggregation, the SLO watchdog) import this module and never
+claim a chip.
 """
 
 from __future__ import annotations
@@ -46,7 +47,7 @@ from typing import Callable, Optional
 LOG = logging.getLogger(__name__)
 
 # ---------------------------------------------------------------------------
-# peak FLOPs + MFU — the one definition bench.py / tune_mfu / trainer share
+# peak FLOPs + MFU
 # ---------------------------------------------------------------------------
 
 # bf16 peak FLOP/s of one chip, keyed by `device_kind` exactly as jax

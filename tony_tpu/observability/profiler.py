@@ -442,9 +442,8 @@ class StallWatchdog(threading.Thread):
 
 def enable_crash_dumps(*sigs: int) -> bool:
     """``faulthandler.enable()`` + an all-thread stack dump on each given
-    signal — the one shared extraction of the setup bench.py used to
-    duplicate. Long-running ``__main__``s pass SIGUSR2 only (they own
-    their SIGTERM handlers); bench children also pass SIGTERM."""
+    signal. Long-running ``__main__``s pass SIGUSR2 only (they own
+    their SIGTERM handlers)."""
     ok = True
     try:
         faulthandler.enable()

@@ -3,9 +3,8 @@
 The ONE encoder shared by the AM's ``/metrics`` endpoint and the serving
 frontend's ``/v1/metrics`` — name sanitization, label escaping, and
 NaN/±Inf formatting live here and nowhere else. The parser exists for
-the round-trip tests and for tools/serve_bench.py's scrape; it handles
-exactly what the encoder emits (plus comments/blank lines), not the full
-OpenMetrics grammar.
+the round-trip tests; it handles exactly what the encoder emits (plus
+comments/blank lines), not the full OpenMetrics grammar.
 
 A *family* is ``{"name": str, "type": "counter"|"gauge"|"untyped",
 "help": str, "samples": [(labels_dict, value), ...]}`` — the shape

@@ -28,8 +28,8 @@ O(buckets)-per-window alternative the skew surfaces read from:
   ``relaunch_after_windows`` windows is nominated for the PR-2
   task-attempt relaunch machinery.
 
-Stdlib only — bench.py's supervisor imports this before any jax child
-runs, and the AM must never grow a heavy dependency for observability.
+Stdlib only — the AM must never grow a heavy dependency for
+observability.
 """
 
 from __future__ import annotations

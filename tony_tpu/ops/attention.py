@@ -38,9 +38,9 @@ from tony_tpu.ops.vma import (
     batch_axes_dividing, match_vma as _like_vma, mosaic_region, say_once,
 )
 
-# 512x512 measured 2.05x faster than 128x128 on v5e (28.7 vs 14.0 TF/s,
-# B4 H16 S4096 hd128 causal fwd) — bigger q blocks amortize the K/V stream
-# and feed the MXU full tiles; >=1024 plateaus and 2048 blows compile.
+# bigger q blocks amortize the K/V stream and feed the MXU full tiles;
+# 2048 blows compile. Which tile is fastest on the chip is not measured
+# (PERF.md; the benchmark runs this one).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 # inside the Pallas kernels a sequence no longer than one block is padded
@@ -705,7 +705,7 @@ def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, kv_len):
     # policy keeps exactly the flash residuals: the backward replay then
     # skips re-running the fwd kernel (the single most expensive recompute
     # in a rematted transformer block) for ~1 GB of saved bf16 at
-    # llama3_1b_proxy scale — measured +2.3pp MFU on v5e (65.5 -> 67.8)
+    # llama3_1b_proxy scale
     from jax.ad_checkpoint import checkpoint_name
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")

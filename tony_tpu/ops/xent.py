@@ -3,8 +3,7 @@
 The unfused path materializes logits (B, S, V) in f32 — 2.1 GB at
 llama3_1b_proxy bench shapes (B4 x S4096 x V32k) — plus the same again for
 dlogits in the backward, and keeps softmax statistics as autodiff residuals.
-On a 16 GB v5e that HBM is the binding constraint on batch size (SURVEY.md
-§6 / BASELINE.md: the MFU north star is single-chip Llama pretrain).
+On a 16 GB v5e that HBM is the binding constraint on batch size.
 
 This op never materializes more than one sequence-chunk of logits at a time:
 

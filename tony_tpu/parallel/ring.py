@@ -50,7 +50,7 @@ def _blocks(s: int) -> tuple[int, int]:
     """Largest standard block sizes that divide the local chunk (the flash
     entry clamps block > s down to s, so s itself always works). Reads the
     defaults off the module at call time so block-size sweeps that mutate
-    them (tools/tune_mfu.py) reach the ring path too."""
+    them (tools/aot_rank.py) reach the ring path too."""
     bq, bk = _attn.DEFAULT_BLOCK_Q, _attn.DEFAULT_BLOCK_K
     for b in (bq, 256, 128):
         if s % b == 0:

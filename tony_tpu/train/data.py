@@ -81,8 +81,8 @@ def _synthetic_tokens_loop(batch_size: int, seq_len: int, vocab_size: int,
                            seed: int = 0, process_index: int = 0
                            ) -> Iterator[dict[str, np.ndarray]]:
     """Reference O(seq_len)-dispatch implementation of synthetic_tokens —
-    the oracle for the vectorization regression test and the host-side
-    speedup benchmark (tests/test_prefetch.py)."""
+    the oracle the vectorized scan must match bit for bit, and the
+    O(seq) side of its count of work (tests/test_prefetch.py)."""
     rng = np.random.default_rng(seed * 1_000_003 + process_index)
     while True:
         toks = np.empty((batch_size, seq_len + 1), np.int32)
@@ -179,8 +179,8 @@ class PrefetchIterator:
 
     Stall accounting: `stall_s` accumulates wall time the consumer spent
     blocked inside `next()` and `batches` counts yields — the source of
-    the bench's `input_stall_ms_per_step` (a healthy overlapped pipeline
-    shows ~0 ms/step after the pipeline-fill first batch).
+    the goodput ledger's `input_stall` phase (a healthy overlapped
+    pipeline shows ~0 ms/step after the pipeline-fill first batch).
     """
 
     def __init__(self, local_iter: Iterator[dict], mesh=None,

@@ -1,8 +1,8 @@
-"""Offline screening of tuner variants with the real XLA:TPU compiler.
+"""Offline screening of train-step variants with the real XLA:TPU compiler.
 
 JAX's AOT path runs the REAL XLA:TPU compiler against a detached
 TopologyDescription — no chip needed. So without spending chip time, every
-tools/tune_mfu.py variant can be compiled for an actual v5e target and
+variant below can be compiled for an actual v5e target and
 screened by its compiled HBM plan (argument + temp bytes vs the 16 GiB
 chip) and a roofline bound (model-accounted FLOPs vs MXU peak, XLA
 'bytes accessed' vs HBM bandwidth).
@@ -32,20 +32,112 @@ from functools import partial
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
 
-from bench import peak_flops  # noqa: E402
-from tune_mfu import VARIANTS, build_config, variant_globals  # noqa: E402
-from tony_tpu.models.llama import llama_init, llama_loss  # noqa: E402
+from tony_tpu.models.llama import (  # noqa: E402
+    get_config, llama_init, llama_loss,
+)
+from tony_tpu.observability.perf import peak_flops  # noqa: E402
 from tony_tpu.train.step import make_train_step  # noqa: E402
 
 V5E_HBM = 16 * 1024 ** 3
 RESULT_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "aot_rank_result.json")
+
+# The single-chip levers of the llama3_1b_proxy train step: batch size,
+# remat on/off/policy, sequence length, fused cross-entropy chunk, flash
+# tile. What each costs in time is not measured (PERF.md).
+VARIANTS: dict[str, dict] = {
+    "base_b4":   dict(batch=4, seq=4096),
+    "fullremat_b4": dict(batch=4, seq=4096, remat_policy="full"),
+    "b8":        dict(batch=8, seq=4096),
+    "b2":        dict(batch=2, seq=4096),
+    # this tool's own verdict on noremat_b2, noremat_b4 and unfused_b8:
+    # 19.64G / 31.31G / 18.18G against 15.75G of HBM
+    "noremat_b2": dict(batch=2, seq=4096, remat=False),
+    "noremat_b4": dict(batch=4, seq=4096, remat=False),
+    "dots_b4":   dict(batch=4, seq=4096, policy="dots_with_no_batch_dims_saveable"),
+    "seq8k_b2":  dict(batch=2, seq=8192),
+    # fused chunked LM-head CE (preset default is xent_chunk=1024;
+    # 0 = full-logits path) — the lever that freed ~4 GB for b8
+    "unfused_b4": dict(batch=4, seq=4096, xent_chunk=0),
+    "unfused_b8": dict(batch=8, seq=4096, xent_chunk=0),
+    "xc512_b8":  dict(batch=8, seq=4096, xent_chunk=512),
+    "xc2048_b8": dict(batch=8, seq=4096, xent_chunk=2048),
+    # flash-kernel tiles beside DEFAULT_BLOCK_Q/K = 512
+    "blk1024_b4": dict(batch=4, seq=4096, flash_block=1024),
+    "blk256_b4": dict(batch=4, seq=4096, flash_block=256),
+    "blkq1024k512_b4": dict(batch=4, seq=4096, flash_block_q=1024,
+                            flash_block_k=512),
+    "b6":        dict(batch=6, seq=4096),
+    "seq8k_b4":  dict(batch=4, seq=8192),
+    "seq2k_b8":  dict(batch=8, seq=2048),
+    # one layer of the 8B geometry, small vocab so embed/head don't
+    # dominate
+    "L8b_b1":    dict(model="8b_layer", batch=1, seq=4096),
+    "L8b_b2":    dict(model="8b_layer", batch=2, seq=4096),
+    "L8b_b4":    dict(model="8b_layer", batch=4, seq=4096),
+    "L8b_blk1024_b2": dict(model="8b_layer", batch=2, seq=4096,
+                           flash_block=1024),
+    "L8b_noremat_b1": dict(model="8b_layer", batch=1, seq=4096,
+                           remat=False),
+    "L8b_noremat_b2": dict(model="8b_layer", batch=2, seq=4096,
+                           remat=False),
+}
+
+
+def build_config(spec: dict):
+    """Resolve a variant spec's preset + config overrides."""
+    overrides = {}
+    if not spec.get("remat", True):
+        overrides["remat"] = False
+    if "remat_policy" in spec:
+        overrides["remat_policy"] = spec["remat_policy"]
+    if "xent_chunk" in spec:
+        overrides["xent_chunk"] = spec["xent_chunk"]
+    if spec.get("model") == "8b_layer":
+        return get_config("llama3_8b", n_layers=1, vocab_size=8192,
+                          max_seq=spec["seq"], **overrides)
+    return get_config("llama3_1b_proxy", max_seq=spec["seq"], **overrides)
+
+
+class variant_globals:
+    """Context manager applying a spec's module-global knobs (flash
+    block sizes, checkpoint policy) and restoring them on exit."""
+
+    def __init__(self, spec: dict):
+        self.spec = spec
+
+    def __enter__(self):
+        import tony_tpu.models.llama as llama_mod
+        import tony_tpu.ops.attention as attn_mod
+        self._llama_mod, self._attn_mod = llama_mod, attn_mod
+        self._real_ckpt = None
+        self._saved_blocks = (attn_mod.DEFAULT_BLOCK_Q,
+                              attn_mod.DEFAULT_BLOCK_K)
+        policy = self.spec.get("policy")
+        if policy is not None:
+            pol = getattr(jax.checkpoint_policies, policy)
+            self._real_ckpt = jax.checkpoint
+            llama_mod.jax.checkpoint = partial(self._real_ckpt,
+                                               policy=pol)
+        attn_mod.DEFAULT_BLOCK_Q = self.spec.get(
+            "flash_block_q",
+            self.spec.get("flash_block", self._saved_blocks[0]))
+        attn_mod.DEFAULT_BLOCK_K = self.spec.get(
+            "flash_block_k",
+            self.spec.get("flash_block", self._saved_blocks[1]))
+        return self
+
+    def __exit__(self, *exc):
+        (self._attn_mod.DEFAULT_BLOCK_Q,
+         self._attn_mod.DEFAULT_BLOCK_K) = self._saved_blocks
+        if self._real_ckpt is not None:
+            self._llama_mod.jax.checkpoint = self._real_ckpt
+        return False
 
 
 def _single_v5e_mesh():

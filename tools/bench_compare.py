@@ -1,21 +1,20 @@
-"""Flag bench regressions against the best same-backend baseline.
+"""Flag regressions of the host control-plane harness against the best
+same-backend baseline.
 
-The self-defending half of the bench (ROADMAP item 5): `bench.py`
-appends every emitted headline to `tools/bench_history.jsonl`; this tool
-compares the LATEST entry of each (metric, backend) group against the
-BEST prior same-backend value and exits nonzero when the drop exceeds
-the threshold (default 2%) — so a perf regression fails loudly at the
-bench instead of silently eroding the trajectory (the r03→r04 blindness
-this guards against).
+`tools/control_plane_bench.py` appends every gated headline (CPU numbers
+under CPU names: `control_plane_*`, `resize_*`) to
+`tools/bench_history.jsonl`; this tool compares the LATEST entry of each
+(metric, backend) group against the BEST prior same-backend value and
+exits nonzero when the drop exceeds the threshold (default 2%). Speed on
+the chip is not judged here: that is `benchmark/` and
+`PERF_LEDGER.jsonl`.
 
 Rules:
-- groups are (metric, backend): a CPU-fallback line can never be judged
-  against an on-chip baseline;
-- value <= 0 entries (old no-chip fallback headlines pinned value to 0.0)
-  are markers, not measurements — skipped both as baseline and as the
-  judged entry;
+- groups are (metric, backend);
+- value <= 0 entries are markers (a failed or withheld run), not
+  measurements — skipped both as baseline and as the judged entry;
 - direction comes from the unit: seconds/ms/bytes are lower-is-better,
-  everything else (MFU %, tokens/sec) higher-is-better.
+  everything else higher-is-better.
 
 Run: python tools/bench_compare.py [--threshold-pct 2]
      [--history tools/bench_history.jsonl] [--metric NAME]

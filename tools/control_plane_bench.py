@@ -1,400 +1,35 @@
-"""Flagship benchmark: Llama pretrain throughput on one chip.
+"""Host control-plane harness: how the orchestrator (session, liveliness
+monitor, spec-diff protocol, warm pool, AM recovery) behaves at gang
+widths no test reaches.
 
-Prints ONE JSON line:
-  {"metric": ..., "value": N, "unit": ..., "vs_baseline": N}
-
-The reference publishes no benchmark numbers (BASELINE.md); the driver's
-north star is >=40% MFU on the Llama JAX pretrain, so `vs_baseline` is
-MFU / 40%. The accelerator leg runs the llama3_1b_proxy config in bf16
-(pallas flash attention, remat, donated buffers) and additionally times
-one 8B-shaped layer so the 1B->8B extrapolation is grounded. It runs ONE
-child process under a deadline: the child either measures on a TPU or
-fails, and then `bench.py` exits non-zero and prints no MFU — a missing
-chip is never answered with a number from another backend. The parent
-is pure stdlib and never imports jax, so the chip belongs to the child.
-
-The control-plane legs (`--control-plane`, `--cp-pool`, the startup
-latency child) are host measurements and run on the CPU.
+`python tools/control_plane_bench.py` runs every leg (see
+`control_plane_main`) and prints ONE JSON line; `--cp-pool` is the child
+mode its real-executor legs spawn. Everything here is a host measurement:
+it runs on the CPU, its numbers carry CPU names, and nothing in it
+touches a chip. Speed on the chip is the benchmark's business
+(`python3 benchmark/run.py`, `BENCHMARK.json`, `PERF.md`).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import signal
 import subprocess
 import sys
 import time
 
-BUDGET_SEC = float(os.environ.get("TONY_BENCH_WATCHDOG_SEC", "480"))
-METRIC = "llama_pretrain_mfu_single_chip"
-
-# The peak-FLOPs table and MFU formula live in observability/perf.py —
-# ONE definition shared with tools/tune_mfu.py and the trainer's goodput
-# metrics. perf.py is stdlib-only at import time, so the parent never
-# touches a backend. Re-exported here because
-# tune_mfu and older tooling import them from bench.
-from tony_tpu.observability.perf import (  # noqa: F401
-    PEAK_FLOPS, mfu_pct, peak_flops,
-)
-
-
-# ---------------------------------------------------------------------------
-# child: the actual measurement (runs under a parent-enforced deadline)
-# ---------------------------------------------------------------------------
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# run as a script (also by the pools it spawns from another cwd): the
+# package is found from the checkout, not from an installation
+sys.path.insert(0, _REPO_ROOT)
 
 _T0 = time.monotonic()
 
 
-def _lm_feed(vocab_size: int, batch_size: int, seq: int, seed: int = 1):
-    """Host-side {'inputs','targets'} stream for the bench hot loop —
-    fresh synthetic batches every step, fed through PrefetchIterator so
-    generation + H2D overlap the previous train step exactly like the
-    trainer's input path (docs/HOTLOOP.md). Local imports keep the
-    parent process pure-stdlib."""
-    import numpy as np
-
-    from tony_tpu.train.data import synthetic_tokens
-
-    for b in synthetic_tokens(batch_size, seq, vocab_size, seed=seed):
-        toks = b["tokens"]
-        yield {"inputs": np.ascontiguousarray(toks[:, :-1]),
-               "targets": np.ascontiguousarray(toks[:, 1:])}
-
-
-def _input_stall_ms_per_step(feed, snapshot, steps: int) -> float:
-    """Per-step input stall over a timed region, from stall snapshots
-    taken before/after it. Fails LOUDLY when `feed` is not the
-    prefetching path — the bench contract requires the overlapped input
-    pipeline, and a silent fallback to a plain iterator would report an
-    MFU that hides input serialization (tests/test_bench_contract.py)."""
-    snap = getattr(feed, "stall_snapshot", None)
-    if snap is None:
-        raise TypeError(
-            "bench input feed bypasses the prefetch path: "
-            f"{type(feed).__name__} has no stall accounting")
-    stall_s, batches = snap()
-    s0, n0 = snapshot
-    used = batches - n0
-    if used < max(1, steps):
-        raise ValueError(
-            f"prefetch feed yielded {used} batches in a {steps}-step "
-            f"timed region — the prefetch path was bypassed or starved")
-    return 1000.0 * (stall_s - s0) / used
-
-
 def _mark(msg: str) -> None:
-    """Progress marker on stderr — the parent's diagnosis tail."""
+    """Progress marker on stderr."""
     print(f"[bench +{time.monotonic() - _T0:.1f}s] {msg}", file=sys.stderr,
           flush=True)
-
-
-def child_main() -> None:
-    # If the parent SIGTERMs us (deadline), dump stacks first so the
-    # parent can report WHERE init/compile was when it was cut.
-    from tony_tpu.observability.profiler import enable_crash_dumps
-    enable_crash_dumps(signal.SIGTERM)
-
-    from functools import partial
-
-    _mark("importing jax")
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from tony_tpu.models.llama import get_config, llama_init, llama_loss
-    from tony_tpu.train.step import make_train_step
-
-    _mark("initializing backend (first device touch)")
-    dev = jax.devices()[0]
-    _mark(f"backend up: platform={dev.platform} "
-          f"kind={getattr(dev, 'device_kind', '?')}")
-    if dev.platform != "tpu":
-        # no number from another backend under this metric's name
-        raise SystemExit(
-            f"bench: no TPU (jax found platform {dev.platform!r}); the "
-            f"accelerator leg measures on a TPU or fails")
-    # the persistent XLA compilation cache, where every trainer and
-    # replica of this checkout keeps it
-    from tony_tpu.utils.compilecache import enable_compile_cache
-    _mark(f"compile cache at {enable_compile_cache(jax)}")
-
-    config = get_config("llama3_1b_proxy")
-    seq, steps, warmup = 4096, 10, 2
-    # one batch, read from the compiler and not from an OOM retry: the
-    # v5e compile of this step reports (memory_analysis) 6.36 GiB of
-    # arguments + 5.42 GiB of temporaries at batch 2 — the size
-    # chip_smoke.py runs and tests/test_tpu_compile.py holds. Batch 4
-    # (16.1 GiB by the same sum) also ran on the chip; which batch a
-    # benchmark cell uses is the benchmark PR's to choose (ROADMAP A1)
-    batch_size = 2
-
-    def measure(tag, cfg):
-        """Compile+warmup+time one config. Returns (stats, params).
-
-        The input path is the OVERLAPPED one the trainer uses: a
-        PrefetchIterator feeds fresh synthetic batches (background host
-        generation + H2D, 2-deep on device), so the measured MFU
-        reflects the real hot loop — and its stall accounting yields
-        the `input_stall_ms_per_step` headline field."""
-        from tony_tpu.train.data import PrefetchIterator
-
-        optimizer = optax.adamw(3e-4)
-        train_step = make_train_step(partial(llama_loss, config=cfg),
-                                     optimizer)
-        params = llama_init(cfg, jax.random.PRNGKey(0))
-        opt_state = jax.jit(optimizer.init)(params)
-        feed = PrefetchIterator(
-            _lm_feed(cfg.vocab_size, batch_size, seq), depth=2)
-        _mark(f"[{tag}] compiling + warmup (batch {batch_size})")
-        try:
-            for _ in range(warmup):
-                params, opt_state, loss = train_step(
-                    params, opt_state, next(feed))
-            jax.block_until_ready(loss)
-        except BaseException:
-            feed.close()
-            raise
-
-        _mark(f"[{tag}] timing")
-        # finally: the feed's producer thread and its on-device batches
-        # must not outlive the region
-        try:
-            snap = feed.stall_snapshot()
-            t0 = time.monotonic()
-            for _ in range(steps):
-                params, opt_state, loss = train_step(params, opt_state,
-                                                     next(feed))
-            jax.block_until_ready(loss)
-            dt = time.monotonic() - t0
-            final_loss = float(loss)
-            stall_ms = _input_stall_ms_per_step(feed, snap, steps)
-            prefetch_depth = feed.depth
-        finally:
-            feed.close()
-        tokens_per_step = batch_size * seq
-        tok_s = tokens_per_step * steps / dt
-        mfu_pct = (100.0 * tok_s * cfg.flops_per_token(seq)
-                   / peak_flops(dev))
-        return {
-            "config": f"xc{cfg.xent_chunk}-b{batch_size}",
-            "value": round(mfu_pct, 2),
-            "tokens_per_sec_per_chip": round(tok_s, 1),
-            "step_time_s": round(dt / steps, 4),
-            "batch_tokens": tokens_per_step,
-            "input_stall_ms_per_step": round(stall_ms, 3),
-            "prefetch_depth": prefetch_depth,
-            "final_loss": round(final_loss, 4),
-        }, params
-
-    def headline(stats):
-        return {
-            "metric": METRIC,
-            # self-description: every result line names the device that
-            # measured it
-            "backend": dev.platform,
-            "value": stats["value"],
-            "unit": "%MFU",
-            "vs_baseline": round(stats["value"] / 40.0, 3),
-            "tokens_per_sec_per_chip": stats["tokens_per_sec_per_chip"],
-            "step_time_s": stats["step_time_s"],
-            "input_stall_ms_per_step": stats["input_stall_ms_per_step"],
-            "prefetch_depth": stats["prefetch_depth"],
-            "model": "llama3_1b_proxy",
-            "config": stats["config"],
-            "batch_tokens": stats["batch_tokens"],
-            "device": dev.device_kind,
-            "device_count": jax.device_count(),
-            "final_loss": stats["final_loss"],
-        }
-
-    child_deadline = float(os.environ.get(
-        "TONY_BENCH_CHILD_DEADLINE", "0"))
-
-    def headroom() -> float:
-        """Seconds left before the parent's SIGTERM (inf if unknown)."""
-        if child_deadline <= 0:
-            return float("inf")
-        return child_deadline - (time.monotonic() - _T0)
-
-    stats, params = measure("main", config)
-    result = headline(stats)
-
-    # emit the HEADLINE now: each metadata bench below pays its own
-    # multi-10s compile, and a deadline kill mid-metadata must not
-    # cost the measurement (the parent parses the LAST JSON line;
-    # killed children yield their most recent print)
-    print(json.dumps(result), flush=True)
-    # Each metadata bench pays its own compile. Gate on headroom so the
-    # child finishes CLEAN before the parent's SIGTERM — a deadline kill
-    # mid-metadata labels the complete headline 'partial'.
-    meta_benches = (
-        ("llama3_8b_layer",
-         lambda: _bench_8b_layer(jax, jnp, optax, dev)),
-        ("longseq",
-         lambda: _bench_longseq_layer(jax, jnp, optax, dev)),
-        ("decode", lambda: _bench_decode(jax, jnp, config, params,
-                                         headroom)),
-    )
-    for name, fn in meta_benches:
-        if headroom() < 75.0:
-            _mark(f"skipping {name} bench: headroom "
-                  f"{headroom():.0f}s")
-            result[f"{name}_skipped"] = "deadline headroom"
-            continue
-        try:
-            result.update(fn())
-        except Exception as e:  # metadata — never sink the headline
-            _mark(f"{name} bench failed: {type(e).__name__}: {e}")
-            result[f"{name}_error"] = _compact(
-                f"{type(e).__name__}: {e}", 160)
-    print(json.dumps(result), flush=True)   # headline + metadata so far
-    # live duty-cycle path (task_monitor's stall-detection source):
-    # present where the host runs the libtpu metrics service, absent
-    # elsewhere — record WHICH, as evidence either way, never fail the
-    # bench on it
-    try:
-        from tony_tpu.executor.tpu_metrics import LibtpuMetricsClient
-        mc = LibtpuMetricsClient(timeout_sec=2.0)
-        duty = mc.duty_cycle_pct(strict=True)
-        if duty is not None:
-            result["libtpu_duty_cycle_pct"] = round(duty, 2)
-            _mark(f"libtpu {mc.addr} live: duty_cycle={duty:.2f}%")
-        else:
-            result["libtpu_metrics"] = "no-duty-cycle-frame"
-            _mark(f"libtpu {mc.addr} answered but returned no "
-                  f"duty-cycle frame")
-    except Exception as e:  # noqa: BLE001
-        result["libtpu_metrics"] = _compact(
-            f"unreachable: {type(e).__name__}: {e}", 80)
-        _mark(f"libtpu metrics unreachable: "
-              f"{type(e).__name__}: {e}")
-
-    print(json.dumps(result), flush=True)
-
-
-def startup_main() -> None:
-    """AM job-startup latency (the second BASELINE.json metric next to
-    throughput): submit a 2-worker no-op gang through the REAL
-    client->AM->executor chain on the local backend and measure
-    submit -> all-workers-RUNNING and submit -> SUCCEEDED. Pure
-    orchestrator path — no jax import, a host measurement. Prints one
-    JSON line consumed by the parent as
-    bench metadata. Reference analogue: TonY's client submit ->
-    container-allocation -> task-registration path (TonyClient.java
-    monitorApplication + AM ContainerLauncher), for which the reference
-    publishes no numbers (BASELINE.md)."""
-    import statistics
-
-    # a host measurement: the no-op containers must not claim a chip
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-    to_running, to_done = [], []
-    runs = int(os.environ.get("TONY_STARTUP_BENCH_RUNS", "3"))
-    for i in range(runs):
-        r = _gang_run(width=2, hb_ms=100,
-                      command=f"{sys.executable} -c pass")
-        _mark(f"startup run {i}: ok={r['ok']} total={r['total_s']:.2f}s "
-              f"running={r.get('all_running_s')}")
-        if r["ok"]:
-            to_done.append(r["total_s"])
-            if "all_running_s" in r:
-                to_running.append(r["all_running_s"])
-    result = {"runs": len(to_done), "backend": "cpu"}
-    if len(to_done) < runs:
-        result["failed_runs"] = runs - len(to_done)
-        result["error"] = (f"{runs - len(to_done)}/{runs} gang runs did "
-                           f"not SUCCEED — orchestrator path unhealthy")
-    if to_running:
-        result["submit_to_all_running_p50_s"] = round(
-            statistics.median(to_running), 3)
-    if to_done:
-        result["submit_to_succeeded_p50_s"] = round(
-            statistics.median(to_done), 3)
-    # emit the small-gang numbers NOW: if the width storm below blows
-    # the parent's deadline, the kill still leaves this complete JSON
-    # line on stdout (the parent parses the LAST parseable line)
-    print(json.dumps(result), flush=True)
-    width = int(os.environ.get("TONY_STARTUP_BENCH_WIDTH", "48"))
-    if width > 0:
-        result["gang_width"] = _width_gang_run(width)
-        print(json.dumps(result), flush=True)
-
-
-def _gang_run(width: int, hb_ms: int, command: str,
-              remote: bool = False) -> dict:
-    """One no-op gang of `width` workers through the real
-    client->AM->executor chain; returns {ok, total_s, times (per-task
-    submit->RUNNING, sorted), all_running_s}. remote=True runs over the
-    ExecTransport remote backend (the multi-host double)."""
-    import tempfile
-
-    from tony_tpu.client.tony_client import TonyClient
-    from tony_tpu.conf import keys as K
-    from tony_tpu.conf.configuration import TonyConfiguration
-
-    with tempfile.TemporaryDirectory() as td:
-        conf = TonyConfiguration()
-        conf.set(K.CLUSTER_WORKDIR, os.path.join(td, "c"), "bench")
-        conf.set(K.TASK_HEARTBEAT_INTERVAL_MS, hb_ms, "bench")
-        conf.set(K.AM_MONITOR_INTERVAL_MS, max(100, hb_ms // 2), "bench")
-        conf.set(K.AM_STOP_POLL_TIMEOUT_MS, 1000, "bench")
-        if remote:
-            conf.set(K.CLUSTER_BACKEND, "remote", "bench")
-            conf.set(K.CLUSTER_NODES, f"nodeW:{width}", "bench")
-            conf.set(K.CLUSTER_NODE_TRANSPORT, "exec", "bench")
-            conf.set(K.CLUSTER_NODE_ROOT, os.path.join(td, "n"), "bench")
-            conf.set(K.STAGING_LOCATION, os.path.join(td, "s"), "bench")
-        client = TonyClient(conf)
-        client.init([
-            "--conf", f"tony.worker.instances={width}",
-            "--conf", f"tony.worker.command={command}"])
-        t0 = time.monotonic()
-        seen: dict[int, float] = {}
-        all_running = []
-
-        def on_tasks(infos):
-            now = time.monotonic() - t0
-            for ti in infos:
-                if (ti.name == "worker" and int(ti.index) not in seen
-                        and str(ti.status.value).upper() in
-                        ("RUNNING", "SUCCEEDED")):
-                    seen[int(ti.index)] = now
-            if not all_running and len(seen) >= width:
-                all_running.append(now)
-
-        client.add_listener(on_tasks)
-        ok = client.run()
-        total = time.monotonic() - t0
-    out = {"ok": bool(ok), "total_s": total,
-           "times": sorted(seen.values())}
-    if all_running:
-        out["all_running_s"] = round(all_running[0], 3)
-    return out
-
-
-def _width_gang_run(width: int) -> dict:
-    """Production-width registration storm (VERDICT r4 weak #5): one
-    `width`-task gang over the ExecTransport remote backend, per-task
-    submit->RUNNING times collected through the client listener, p50/p95
-    across tasks + submit->all-running reported. The reference ran gangs
-    this wide in production; the barrier + gRPC server here had only
-    ever seen 2-3 tasks."""
-    import statistics
-
-    r = _gang_run(width=width, hb_ms=500,
-                  command="bash -c 'sleep 0.5'", remote=True)
-    _mark(f"width gang: ok={r['ok']} width={width} "
-          f"registered={len(r['times'])} total={r['total_s']:.2f}s")
-    out = {"width": width, "registered": len(r["times"]), "ok": r["ok"]}
-    times = r["times"]
-    if times:
-        out["task_running_p50_s"] = round(statistics.median(times), 3)
-        out["task_running_p95_s"] = round(
-            times[min(len(times) - 1, int(0.95 * len(times)))], 3)
-    if "all_running_s" in r:
-        out["submit_to_all_running_s"] = r["all_running_s"]
-    return out
 
 
 def _rss_mb() -> float:
@@ -1073,7 +708,7 @@ def _control_plane_real(width: int, sleep_sec: float = 6.0,
 
 
 def cp_pool_main() -> None:
-    """`bench.py --cp-pool host port start count width conf sleep_sec`:
+    """`--cp-pool host port start count width conf sleep_sec`:
     one executor-pool subprocess of the real-gang control-plane bench —
     hosts `count` REAL TaskExecutor instances on threads (sharing this
     process's interpreter: 1024 full python processes would measure the
@@ -1250,7 +885,7 @@ def _am_recovery_disclosure(row: dict) -> dict:
 
 def _control_plane_am_recovery(width: int, kill_after_ms: int = 4000,
                                run_sec: float = 25.0) -> dict:
-    """`bench.py --control-plane` AM-kill leg: run a REAL width-k gang
+    """AM-kill leg of `control_plane_main`: run a REAL width-k gang
     through the full client -> supervised AM -> executor chain, SIGKILL
     the AM mid-run (the TEST_AM_KILL hook, same one the chaos suite
     drives), and let am/supervisor.py relaunch it: the new attempt
@@ -1337,7 +972,7 @@ def _control_plane_am_recovery(width: int, kill_after_ms: int = 4000,
 
 
 def control_plane_main() -> None:
-    """`python bench.py --control-plane`: the control-plane harness —
+    """`python tools/control_plane_bench.py`: the control-plane harness —
     the synthetic-width stub storm at gang widths {48, 256, 1024}
     (TONY_CP_WIDTHS overrides) PLUS real-executor gangs at
     TONY_CP_REAL_WIDTHS (default the same; "" skips the real leg),
@@ -1486,8 +1121,7 @@ def control_plane_main() -> None:
                                           "metric (cpu by contract)",
                 # every history line discloses what the always-on
                 # profiler cost this run (budget: <1%)
-                "profiler_overhead_pct": profiler_overhead_pct,
-                "vs_baseline": 0.0}
+                "profiler_overhead_pct": profiler_overhead_pct}
         for metric, value, unit in (
                 ("control_plane_spec_bytes",
                  widest.get("spec", {}).get("bytes_sent"), "bytes"),
@@ -1565,211 +1199,6 @@ def control_plane_main() -> None:
         sys.exit(1)
 
 
-def _bench_decode(jax, jnp, config, params, headroom=None) -> dict:
-    """KV-cache generation throughput on the bench model (metadata next
-    to the training MFU headline: the inference half of the lifecycle).
-    The timed region is one whole generate() call — prefill of the
-    prompt PLUS the decode scan — and the keys say so; a decode-only
-    number would need a second compile (separate static budget), which
-    isn't worth the bench-budget cost for metadata."""
-    from tony_tpu.models.generate import generate
-
-    _mark("timing KV-cache generate (prefill + decode)")
-    b, p, n = 8, 128, 64
-    prompt = jax.random.randint(jax.random.PRNGKey(5), (b, p), 0,
-                                config.vocab_size, jnp.int32)
-    jax.block_until_ready(
-        generate(params, config, prompt, n))     # compile + warmup
-    t0 = time.monotonic()
-    jax.block_until_ready(generate(params, config, prompt, n))
-    dt = time.monotonic() - t0
-    out = {
-        # new tokens / whole-call time: prefill amortized in, hence
-        # "generate_", not "decode_"
-        "generate_new_tokens_per_sec": round(b * n / dt, 1),
-        "generate_ms_per_new_token": round(dt / n * 1000.0, 3),
-        "generate_batch": b, "generate_prompt_len": p,
-        "generate_new_tokens": n,
-    }
-    if headroom is not None and headroom() < 100.0:
-        # the int8 variant pays its own cold compile (new pytree
-        # structure => retrace); running it into the parent deadline
-        # would label the COMPLETE headline 'partial' — never worth
-        # opportunistic metadata
-        out["generate_int8_skipped"] = "deadline headroom"
-        return out
-    try:
-        # weight-only int8 variant (models/quant.py): decode is
-        # weight-bandwidth-bound, so this is the halved-bytes A/B
-        from tony_tpu.models.quant import quantize_params
-        _mark("timing int8 weight-only generate")
-        qparams = quantize_params(params)
-        jax.block_until_ready(
-            generate(qparams, config, prompt, n))     # compile + warmup
-        t0 = time.monotonic()
-        jax.block_until_ready(generate(qparams, config, prompt, n))
-        dt = time.monotonic() - t0
-        out["generate_int8_new_tokens_per_sec"] = round(b * n / dt, 1)
-        out["generate_int8_ms_per_new_token"] = round(dt / n * 1000.0, 3)
-    except Exception as e:  # variant is opportunistic metadata only
-        _mark(f"int8 generate failed: {type(e).__name__}: {e}")
-        out["generate_int8_error"] = _compact(
-            f"{type(e).__name__}: {e}", 120)
-    return out
-
-
-def _bench_layer(jax, jnp, optax, dev, seq: int, iters: int,
-                 key_base: int, prefix: str, label: str) -> dict:
-    """Time ONE 8B-geometry Llama layer's train step at `seq` (the full
-    8B model — 16 GB params in bf16 + optimizer state — cannot fit a
-    single v5e chip, so per-layer is the grounded measurement; small
-    vocab keeps the embed/head from dominating)."""
-    from functools import partial
-
-    from tony_tpu.models.llama import get_config, llama_init, llama_loss
-    from tony_tpu.train.step import make_train_step
-
-    _mark(f"timing {label} (seq {seq})")
-    config = get_config("llama3_8b", n_layers=1, vocab_size=8192,
-                        max_seq=seq)
-    params = llama_init(config, jax.random.PRNGKey(key_base))
-    optimizer = optax.adamw(3e-4)
-    step = make_train_step(partial(llama_loss, config=config), optimizer)
-    opt_state = jax.jit(optimizer.init)(params)
-    tokens = jax.random.randint(jax.random.PRNGKey(key_base + 1),
-                                (1, seq), 0, config.vocab_size, jnp.int32)
-    batch = {"inputs": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
-    for _ in range(2):
-        params, opt_state, loss = step(params, opt_state, batch)
-    jax.block_until_ready(loss)
-    t0 = time.monotonic()
-    for _ in range(iters):
-        params, opt_state, loss = step(params, opt_state, batch)
-    jax.block_until_ready(loss)
-    layer_ms = (time.monotonic() - t0) / iters * 1000.0
-    flops = seq * config.flops_per_token(seq)  # batch 1
-    return {
-        f"{prefix}_step_ms": round(layer_ms, 2),
-        f"{prefix}_mfu_pct": round(
-            100.0 * flops / (layer_ms / 1e3) / peak_flops(dev), 2),
-    }
-
-
-def _bench_8b_layer(jax, jnp, optax, dev) -> dict:
-    """8B layer geometry (dim 4096 / ffn 14336 / 32 q / 8 kv heads) at
-    seq 4096 — the GQA-native flash fwd+bwd path;
-    reports a x32-layers estimate for the 1B->8B extrapolation."""
-    out = _bench_layer(jax, jnp, optax, dev, seq=4096, iters=5,
-                       key_base=2, prefix="llama3_8b_layer",
-                       label="8B-shaped single layer")
-    out["llama3_8b_est_32layer_step_ms"] = round(
-        out["llama3_8b_layer_step_ms"] * 32, 1)
-    return out
-
-
-def _bench_longseq_layer(jax, jnp, optax, dev) -> dict:
-    """Segmented long-sequence flash (ops/attention.py
-    LONG_SEQ_CHUNK=8192): seq 16384 forces the lse-merge segmentation —
-    the VMEM-capped path had only ever run in interpret mode / AOT
-    compile."""
-    return _bench_layer(jax, jnp, optax, dev, seq=16384, iters=3,
-                        key_base=4, prefix="longseq16k_layer",
-                        label="segmented long-seq layer")
-
-
-# ---------------------------------------------------------------------------
-# parent: supervise one child, diagnose, emit or fail
-# ---------------------------------------------------------------------------
-
-def _supervise(argv: list[str], deadline: float,
-               env: dict | None = None) -> tuple[str, str, str, bool]:
-    """Run one supervised child under a deadline with the
-    SIGTERM(faulthandler dump)->SIGKILL ladder. Returns
-    (stdout, stderr, state, clean_exit) — the single implementation both
-    bench children (tpu, startup) share."""
-    proc = subprocess.Popen(
-        argv, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-        env=env, cwd=os.path.dirname(os.path.abspath(__file__)) or ".")
-    try:
-        out, err = proc.communicate(timeout=deadline)
-        timed_out = False
-    except subprocess.TimeoutExpired:
-        timed_out = True
-        proc.send_signal(signal.SIGTERM)   # triggers faulthandler dump
-        try:
-            out, err = proc.communicate(timeout=15)
-        except subprocess.TimeoutExpired:
-            proc.kill()
-            out, err = proc.communicate()
-    state = (f"timed out after {deadline:.0f}s" if timed_out
-             else f"exited rc={proc.returncode}")
-    return out, err, state, (not timed_out and proc.returncode == 0)
-
-
-def _diag(err: str, state: str, what: str) -> str:
-    """Progress-marker + stderr-tail diagnosis line for a failed child."""
-    marks = [ln for ln in err.splitlines() if ln.startswith("[bench ")]
-    last = marks[-1] if marks else "(no progress marker)"
-    tail = "\n".join(err.strip().splitlines()[-12:])
-    return f"{what} {state}; last progress: {last}; stderr tail:\n{tail}"
-
-
-def _run_child(backend: str, deadline: float) -> tuple[dict | None, str]:
-    """Run one measurement child. Returns (result_json_or_None, diag)."""
-    env = dict(os.environ)
-    # the child plans its metadata benches against the deadline it
-    # actually has
-    env["TONY_BENCH_CHILD_DEADLINE"] = f"{deadline:.0f}"
-    if backend == "startup":
-        # a host measurement: neither the child nor the containers it
-        # spawns may claim a chip
-        env["JAX_PLATFORMS"] = "cpu"
-        # hermetic measurement: a machine-level tony-site.json would
-        # silently override the bench's tempdir workdir + 100ms cadences
-        # (merge_site runs after programmatic sets)
-        env.pop("TONY_CONF_DIR", None)
-    out, err, state, clean = _supervise(
-        [sys.executable, os.path.abspath(__file__), "--child", backend],
-        deadline, env=env)
-    tail = "\n".join(err.strip().splitlines()[-12:])
-    for line in reversed(out.strip().splitlines()):
-        try:
-            parsed = json.loads(line)
-        except ValueError:
-            continue
-        if not isinstance(parsed, dict):
-            # a bare number/null from stray output parses as JSON but
-            # is not a result object
-            continue
-        if not clean:
-            # killed child (deadline): a JSON line printed before the
-            # kill is still a valid partial result — label it
-            parsed["partial"] = state
-        return parsed, tail
-    if clean:
-        return None, f"child exited 0 without JSON; stderr tail:\n{tail}"
-    return None, _diag(err, state, f"{backend} child")
-
-
-def _attach_startup_latency(result: dict, t_start: float,
-                            usable: float) -> None:
-    """Run the orchestrator startup-latency child and attach its numbers
-    as metadata (never sinks the headline measurement)."""
-    remaining = usable - (time.monotonic() - t_start)
-    # 150s ceiling: the small-gang runs take ~10s, the width-48
-    # registration-storm gang adds ~20-60s on a loaded CPU host
-    deadline = max(20.0, min(150.0, remaining))
-    sub, diag = _run_child("startup", deadline)
-    if sub is not None:
-        result["am_startup_latency"] = sub
-    else:
-        result["am_startup_latency"] = {"error": _compact(diag, 160)}
-
-
-_TOOLS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "tools")
-
-
 def _commit_stamp() -> str:
     """Short HEAD hash, or "unknown": the chip machine runs a copy of
     the tree that is not a git repository (git prints to stderr and
@@ -1778,26 +1207,18 @@ def _commit_stamp() -> str:
     try:
         return subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"], capture_output=True,
-            text=True, timeout=10,
-            cwd=os.path.dirname(os.path.abspath(__file__))
+            text=True, timeout=10, cwd=_REPO_ROOT
         ).stdout.strip() or "unknown"
     except Exception:  # noqa: BLE001
         return "unknown"
-
-
-def _compact(s: str, limit: int) -> str:
-    """One physical line, bounded length — safe to embed in the final
-    JSON line (see _emit)."""
-    s = " | ".join(part.strip() for part in str(s).splitlines()
-                   if part.strip())
-    return s[-limit:] if len(s) > limit else s
 
 
 # env-overridable so harnesses (and the contract tests) can redirect
 # the append away from the checked-in trajectory file
 _HISTORY_PATH = os.environ.get(
     "TONY_BENCH_HISTORY_PATH",
-    os.path.join(_TOOLS_DIR, "bench_history.jsonl"))
+    os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                 "bench_history.jsonl"))
 
 
 def _append_history(result: dict) -> None:
@@ -1819,77 +1240,9 @@ def _append_history(result: dict) -> None:
         pass
 
 
-def _emit(result: dict) -> None:
-    """THE measurement contract: the final stdout line is exactly one
-    compact JSON object, short enough to survive a driver that keeps
-    only a ~2 KB tail of stdout. Anything long goes to stderr, never
-    stdout."""
-    drop_order = ("scraped_metrics", "am_startup_latency", "error")
-    # self-description floor: even a line assembled by an older path
-    # says which backend measured it (device "cpu"/"" => cpu)
-    result.setdefault(
-        "backend",
-        "cpu" if str(result.get("device", "")).lower() in ("cpu", "")
-        else "tpu")
-    # ...and EVERY line says why the chip is absent when it is: empty on
-    # an on-chip measurement, "not-applicable: ..." on a host
-    # control-plane line, an explicit marker when an off-chip line
-    # reached here without one — a consumer never has to infer the reason
-    # from which fields happen to exist
-    result.setdefault(
-        "tpu_unavailable_reason",
-        "" if result["backend"] == "tpu"
-        else "unspecified cpu-backend measurement")
-    _append_history(result)
-    line = json.dumps(result, separators=(",", ":"))
-    for key in drop_order:
-        if len(line) <= 1400:
-            break
-        if key in result:
-            result.pop(key)
-            result["truncated"] = (result.get("truncated", "") + f" {key}"
-                                   ).strip()
-            line = json.dumps(result, separators=(",", ":"))
-    if len(line) > 1400:
-        # hard floor: drop_order exhausted but other keys (or the
-        # truncated field itself) still blow the bound — emit a minimal
-        # object that is always parseable rather than a truncated tail
-        line = json.dumps(
-            {"metric": result.get("metric", "unknown"),
-             "value": result.get("value", 0.0),
-             "unit": result.get("unit", ""),
-             "vs_baseline": result.get("vs_baseline", 0.0),
-             "truncated": "hard-floor"},
-            separators=(",", ":"))
-    print(line, flush=True)
-
-
-def main() -> int:
-    """The accelerator leg: ONE child under the budget. It measures on a
-    TPU and the line is emitted, or it fails and so does bench.py —
-    diagnosis on stderr, no result line on stdout."""
-    t_start = time.monotonic()
-    grace = 20.0   # per-child kill grace + spawn overhead
-    usable = max(60.0, BUDGET_SEC - 2 * grace - 15.0)
-    # the startup-latency child needs ~10-60s after the measurement
-    result, diag = _run_child("tpu", max(15.0, usable - 75.0))
-    if result is None or result.get("backend") != "tpu":
-        print(f"[bench parent] no TPU measurement: {diag}",
-              file=sys.stderr, flush=True)
-        return 1
-    _attach_startup_latency(result, t_start, usable)
-    _emit(result)
-    return 0
-
 
 if __name__ == "__main__":
-    if sys.argv[1:3] == ["--child", "startup"]:
-        startup_main()
-    elif sys.argv[1:3] == ["--child", "tpu"]:
-        child_main()
-    elif len(sys.argv) >= 2 and sys.argv[1] == "--control-plane":
-        control_plane_main()
-    elif len(sys.argv) >= 9 and sys.argv[1] == "--cp-pool":
+    if len(sys.argv) >= 9 and sys.argv[1] == "--cp-pool":
         cp_pool_main()
     else:
-        sys.exit(main())
+        control_plane_main()
