@@ -351,3 +351,91 @@ def test_sampled_streams_are_reproducible_by_seed_in_the_loop_and_by_hand(
     assert sampled(8, "step") != first
     greedy, = _by_hand(llama, [(prompt, 16)])
     assert greedy.tokens != first
+
+
+# -- the decode step reads only the rows a slot attends to (PR 35) ----------
+
+def _recorded_dispatches(monkeypatch):
+    """Every dispatched decode step's (pos, attend), as the host arrays
+    the loop handed to the program."""
+    seen = []
+    program = engine_mod._decode_sample_step
+
+    def dispatch(*args, **kw):
+        seen.append((np.array(args[4]), np.array(kw["attend"])))
+        return program(*args, **kw)
+
+    monkeypatch.setattr(engine_mod, "_decode_sample_step", dispatch)
+    return seen
+
+
+def test_a_slot_that_does_not_ride_attends_to_nothing_where_it_is_parked(
+        model, monkeypatch):
+    """Three slots: A decodes on, B ends by length and its slot is parked
+    at the last budget row, the third is never used and sits at row 0.
+    Both are stepped at the row they were stepped at before PR 35, and
+    read no row of the cache; a rider attends to exactly the rows below
+    its position.
+    A model whose cache is by layer kind is handed the same array and
+    keeps no count of it."""
+    seen = _recorded_dispatches(monkeypatch)
+    engine = _engine(model, n_slots=3)
+    budget, (pa, pb) = model["budget"], model["prompts"][:2]
+    a = engine.submit(_prompt(model, pa, 70), 8)
+    b = engine.submit(_prompt(model, pb, 71), 3)
+    _run(engine)
+    assert (len(a.tokens), len(b.tokens)) == (8, 3) and len(seen) == 7
+    for step, (pos, attend) in enumerate(seen):
+        # B's last step (by length, known ahead) is the second; in the
+        # third its slot stays at its next row, and is parked once that
+        # last step has landed
+        at = pb + step if step <= 2 else budget - 1
+        assert list(pos) == [pa + step, at, 0]
+        assert list(attend) == [pa + step, at if step < 2 else 0, 0]
+    snap = engine.snapshot()
+    if model["cfg"].__class__.__name__ == "SalaConfig":
+        assert "cache_rows_read_total" not in snap
+        return
+    chunk = engine._read_chunk
+    assert chunk == 16 and budget % chunk == 0
+    want = sum(int(-(-n // chunk) * chunk) for _, attend in seen
+               for n in attend)
+    assert snap["cache_rows_read_total"] == want > 0
+    assert snap["cache_rows_budget_total"] == len(seen) * 3 * budget
+    assert want < snap["cache_rows_budget_total"]
+
+
+@pytest.mark.parametrize("kernel", ["jnp", "interpreted"])
+def test_a_stream_keeps_its_greedy_tokens_with_slots_freed_and_refilled_around_it(
+        llama, monkeypatch, kernel):
+    """A long stream in slot 0 while slot 1 is freed, parked and admitted
+    again twice around it, in the loop thread's order (a step in flight):
+    its tokens are offline `generate`'s, and so are the others'. Once on
+    the CPU's jnp branch and once with the kernel itself (interpret mode;
+    a budget no other test compiles, since the branch is taken when the
+    step is traced)."""
+    from tony_tpu.ops import cache_attention as ca
+
+    new = (20, 4, 5, 3)
+    prompts = [_prompt(llama, n, 80 + i) for i, n in enumerate((9, 5, 7, 6))]
+    want = [[int(t) for t in gen.generate(
+        llama["params"], llama["cfg"], jnp.asarray([p], jnp.int32), n)[0]]
+        for p, n in zip(prompts, new)]
+    budget = llama["budget"]
+    if kernel == "interpreted":
+        monkeypatch.setattr(ca, "_INTERPRET", True)
+        budget = 80
+    seen = _recorded_dispatches(monkeypatch)
+    engine = _engine(llama, token_budget=budget)
+    handles = [engine.submit(p, n) for p, n in zip(prompts, new)]
+    _run(engine)
+    assert [h.tokens for h in handles] == want
+    # slot 1 was parked between its occupants and read nothing there
+    parked = [attend[1] for pos, attend in seen if pos[1] == budget - 1]
+    assert parked and not any(parked)
+    stats = engine.stats
+    assert stats.decode_slot_steps_discarded_total == 0
+    assert stats.decode_steps_overlapped_total >= stats.decode_steps_total - 1
+    assert 0 < stats.cache_rows_read_total <= stats.cache_rows_budget_total
+    assert stats.cache_rows_read_total == sum(
+        int(-(-n // 16) * 16) for _, attend in seen for n in attend)

@@ -400,3 +400,94 @@ def test_pallas_kernels_pad_a_short_sequence_to_whole_tiles(s, monkeypatch):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=5e-4, err_msg=f"d{name}")
+
+
+# -- ops/cache_attention.py: the decode step's length-aware cache read ------
+
+@pytest.mark.parametrize("budget,dtype,want", [
+    (2048, jnp.bfloat16, 256), (2048, jnp.int8, 256), (640, jnp.bfloat16, 128),
+    (48, jnp.bfloat16, 16), (48, jnp.int8, 0), (80, jnp.float32, 16),
+    (37, jnp.bfloat16, 0)])
+def test_cache_read_chunk_is_whole_tiles_of_the_stored_type(budget, dtype,
+                                                            want):
+    from tony_tpu.ops.cache_attention import read_chunk_rows
+
+    assert read_chunk_rows(budget, dtype) == want
+
+
+@pytest.mark.parametrize("beyond", ["noise", "huge"])
+@pytest.mark.parametrize("layout", ["bf16", "int8"])
+@pytest.mark.parametrize("heads", [(32, 8), (4, 4)], ids=["gqa32-8", "mha4"])
+@pytest.mark.parametrize("window", [1, 4])
+def test_decode_read_kernel_matches_the_jnp_body_and_reads_no_row_past_a_length(
+        window, heads, layout, beyond, monkeypatch):
+    """`tony_decode_read` (interpret mode, chunks of 32 rows) through the
+    dispatcher, against the jnp body on a cache whose rows past each
+    slot's length are zero. The kernel's cache holds noise there, or
+    values that would swamp any softmax: neither reaches the result.
+    Lengths 0, 1, chunk - 1, chunk, chunk + 1 and budget - 1 in one batch;
+    a decode step's window and a speculative one's; grouped and plain
+    heads; both row formats (the int8 one in float32, where the kernel's
+    arithmetic is the body's to rounding)."""
+    from tony_tpu.ops import cache_attention as ca
+
+    monkeypatch.setattr(ca, "READ_CHUNK_ROWS", 32)
+    monkeypatch.setattr(ca, "_INTERPRET", True)
+    (h, g), d, s, n_layers = heads, 32, 128, 2
+    lens = jnp.asarray([0, 1, 31, 32, 33, s - 1], jnp.int32)
+    b = lens.shape[0]
+    quant = layout == "int8"
+    dtype = jnp.float32 if quant else jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(window * 10 + h), 9)
+    q = jax.random.normal(ks[0], (b, h, window, d), dtype)
+    k_new = jax.random.normal(ks[1], (b, g, window, d), dtype)
+    v_new = jax.random.normal(ks[2], (b, g, window, d), dtype)
+    shape = (n_layers, b, g, s, d)
+    held = (jnp.arange(s)[None, :] < lens[:, None])[None, :, None, :, None]
+    if quant:
+        clean = {"k": jax.random.randint(ks[3], shape, -127, 128, jnp.int8),
+                 "v": jax.random.randint(ks[4], shape, -127, 128, jnp.int8),
+                 "k_scale": jax.random.uniform(ks[5], shape[:-1] + (1,),
+                                               jnp.float32, 0.002, 0.02),
+                 "v_scale": jax.random.uniform(ks[6], shape[:-1] + (1,),
+                                               jnp.float32, 0.002, 0.02)}
+        junk = {"k": jax.random.randint(ks[7], shape, -127, 128, jnp.int8),
+                "v": jax.random.randint(ks[8], shape, -127, 128, jnp.int8),
+                "k_scale": clean["k_scale"], "v_scale": clean["v_scale"]}
+        if beyond == "huge":
+            junk = {"k": jnp.full(shape, 127, jnp.int8),
+                    "v": jnp.full(shape, -127, jnp.int8),
+                    "k_scale": jnp.full(shape[:-1] + (1,), 1e3),
+                    "v_scale": jnp.full(shape[:-1] + (1,), 1e3)}
+    else:
+        clean = {"k": jax.random.normal(ks[3], shape, dtype),
+                 "v": jax.random.normal(ks[4], shape, dtype)}
+        junk = {"k": jax.random.normal(ks[7], shape, dtype),
+                "v": jax.random.normal(ks[8], shape, dtype)}
+        if beyond == "huge":
+            junk = {"k": jnp.full(shape, 3e4, dtype),
+                    "v": jnp.full(shape, -3e4, dtype)}
+    zero = {name: jnp.where(held, leaf, jnp.zeros((), leaf.dtype))
+            for name, leaf in clean.items()}
+    dirty = {name: jnp.where(held, clean[name], junk[name]) for name in clean}
+    layer = jnp.asarray([1], jnp.int32)
+    got = ca.cache_attention(layer, lens, q, k_new, v_new, dirty)
+    scales = tuple(zero[n][..., 0] for n in ("k_scale", "v_scale")
+                   if n in zero)
+    want = ca._attend_jnp(layer, lens, q.reshape(b, g, h // g * window, d),
+                          k_new, v_new, zero["k"], zero["v"], *scales,
+                          window=window).reshape(q.shape)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    tol = 2e-5 if quant else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    # and the body reads none of it either (masked before the softmax)
+    again = ca._attend_jnp(
+        layer, lens, q.reshape(b, g, h // g * window, d), k_new, v_new,
+        dirty["k"], dirty["v"],
+        *(dirty[n][..., 0] for n in ("k_scale", "v_scale") if n in dirty),
+        window=window).reshape(q.shape)
+    np.testing.assert_allclose(np.asarray(again, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)

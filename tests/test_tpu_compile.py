@@ -16,6 +16,7 @@ file for the same reason, and compile in the test's own process.
 """
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -193,7 +194,6 @@ def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
     cache in HBM. Parameters and views (get-tuple-element, bitcast) move
     nothing and do not count; nor does an int8 cache's slice of a layer's
     row scales (last dimension 1: 1/32 of the slab's bytes)."""
-    import re
     dims = ",".join(str(d) for d in slab)
     shaped = re.compile(r" = \w+\[(1,)*%s\]" % dims)
     view = re.compile(r" = \S+ (parameter|get-tuple-element|bitcast)\(")
@@ -207,20 +207,11 @@ def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
     return found
 
 
-@pytest.mark.parametrize("per_row", [True, False], ids=["per-row", "scalar"])
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
-        one_chip, quant, per_row):
-    """The serving engine's decode program at the chat-steady cell's
-    shapes (benchmark/configs/mistral-7b-serve.json: Mistral-7B widths, 16
-    layers, 32 slots x 2048 tokens, cache donated). Before PR 27 it held a
-    second whole cache in temporaries (4.83 GB) and made three passes over
-    the cache per token: `dynamic-slice_bitcast_fusion`, `copy` and
-    `dynamic-update-slice`, each with a slab-shaped result, per layer
-    (41 of 58.8 ms a step on the chip). Now the layer loop only reads the
-    cache and the rows are written in place after it."""
+def _chat_steady_cell(one_chip, quant):
+    """benchmark/configs/mistral-7b-serve.json as the program has it:
+    Mistral-7B widths, 16 layers, 32 slots x 2048 tokens, a bf16 or an
+    int8 cache; abstract weights and cache."""
     from tony_tpu.models.llama import LlamaConfig
-    from tony_tpu.serve.engine import _decode_sample_step
 
     config = LlamaConfig(vocab_size=32000, dim=4096, n_layers=16, n_heads=32,
                          n_kv_heads=8, ffn_dim=14336, max_seq=4096,
@@ -236,9 +227,28 @@ def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
                                jnp.float32, one_chip)
     cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
                       for c in cache.values())
+    return (config, _abstract_params(config, one_chip), cache, cache_bytes,
+            slots, slab)
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["per-row", "scalar"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
+        one_chip, quant, per_row):
+    """The serving engine's decode program at the chat-steady cell's
+    shapes (benchmark/configs/mistral-7b-serve.json: Mistral-7B widths, 16
+    layers, 32 slots x 2048 tokens, cache donated). Before PR 27 it held a
+    second whole cache in temporaries (4.83 GB) and made three passes over
+    the cache per token: `dynamic-slice_bitcast_fusion`, `copy` and
+    `dynamic-update-slice`, each with a slab-shaped result, per layer
+    (41 of 58.8 ms a step on the chip). Now the layer loop only reads the
+    cache and the rows are written in place after it."""
+    from tony_tpu.serve.engine import _decode_sample_step
+
+    config, params, cache, cache_bytes, slots, slab = _chat_steady_cell(
+        one_chip, quant)
     compiled = _decode_sample_step.lower(
-        _abstract_params(config, one_chip), config, cache,
-        _sds((slots,), jnp.int32, one_chip),
+        params, config, cache, _sds((slots,), jnp.int32, one_chip),
         _sds((slots,) if per_row else (), jnp.int32, one_chip),
         _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
         0.0, 0, 1.0).compile()
@@ -247,6 +257,39 @@ def test_decode_step_updates_the_cache_in_place_at_the_cell_shapes(
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
     assert _slab_results_outside_fusions(compiled.as_text(), slab) == []
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_decode_step_reads_the_cache_through_the_length_aware_kernel(
+        one_chip, quant):
+    """The decode program as the engine calls it since PR 35 (positions
+    and attend lengths, two host arrays) at the chat-steady cell's shapes:
+    `tony_decode_read` is in the compiled program, once (one layer body,
+    scanned), it takes the whole cache where it lies — the temporaries
+    stay far under one layer's K slab (134 MB bf16; 0.3 MB measured here,
+    34 MB with an int8 cache, whose row scales change layout once a step)
+    — and weights, cache and temporaries fit the chip (11.8 GB of
+    arguments in bf16). Before it the step's two largest operations were
+    float32 reads of every layer's whole slab (5.6 of 17.0 ms on the chip:
+    PERF.md, PR 35)."""
+    from tony_tpu.serve.engine import _decode_sample_step
+
+    config, params, cache, cache_bytes, slots, slab = _chat_steady_cell(
+        one_chip, quant)
+    per_slot = _sds((slots,), jnp.int32, one_chip)
+    compiled = _decode_sample_step.lower(
+        params, config, cache, per_slot, per_slot,
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
+        0.0, 0, 1.0, attend=per_slot).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%tony_decode_read[.\d]* = [^=]*? custom-call\(",
+                          text)) == 1, text.count("tony_decode_read")
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (48e6 if quant else 8e6), \
+        mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    assert _slab_results_outside_fusions(text, slab) == []
 
 
 def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):

@@ -10,11 +10,11 @@ with no model code, SURVEY.md §2.3):
   over the prompt (narrow GQA K/V), capturing each layer's K/V as scan
   outputs.
 - **Decode step**: one token per step (`decode_step`, the W=1 case of
-  `window_logits`). The layer loop only READS the cache: attention is a
-  masked einsum against the rows already there plus the new row straight
-  from registers, grouped by GQA head group (no K/V repeat
-  materialization — (B, G, rep, W, d) x (B, G, S, d)). The new rows of
-  all layers are written once, after the loop, in place
+  `window_logits`). The layer loop only READS the cache, and of it only
+  the rows each batch row attends to (`ops/cache_attention.py`: the
+  kernel `tony_decode_read` on a TPU) plus the new row straight from
+  registers, grouped by GQA head group (no K/V repeat materialization).
+  The new rows of all layers are written once, after the loop, in place
   (`write_cache_rows`) — no layer's slab is ever sliced out, copied or
   stacked back.
 - **Sampling**: greedy (temperature 0) or temperature + optional top-k
@@ -41,6 +41,7 @@ from tony_tpu.models.quant import (
     dequantize_layer, dequantize_rows, maybe_dequantize, quantize_rows,
 )
 from tony_tpu.ops.attention import NEG_INF, flash_attention
+from tony_tpu.ops.cache_attention import cache_attention
 from tony_tpu.ops.rmsnorm import rms_norm
 from tony_tpu.ops.rope import apply_rope
 
@@ -169,39 +170,6 @@ def write_cache_rows(cache, rows, offsets):
     return lax.fori_loop(0, n_rows, write_row, cache)
 
 
-def _cache_attention(q, k_cache, v_cache, k_new, v_new, lens) -> jax.Array:
-    """Attention of W new positions against the cache AND themselves.
-
-    q: (B, H, W, hd) for window rows at batch row b's positions lens[b]..
-    lens[b]+W-1; caches (B, Hkv, S, hd) are only READ, and only columns
-    < lens[b] count (whatever lies beyond is stale and masked); k_new/
-    v_new (B, Hkv, W, hd) are the window's own rows, attended from
-    registers with the within-window causal mask — they are not in the
-    cache yet (`write_cache_rows` stores them after the layer loop).
-    One softmax over both sets of scores: every position attends to
-    exactly its rows 0..position. GQA grouped einsum — K/V never
-    repeated."""
-    b, nh, w, hd = q.shape
-    nkv, s = k_cache.shape[1], k_cache.shape[2]
-    rep = nh // nkv
-    qg = q.reshape(b, nkv, rep, w, hd).astype(jnp.float32) * hd ** -0.5
-    old = jnp.einsum("bgrwd,bgsd->bgrws", qg,
-                     k_cache.astype(jnp.float32))      # (B,G,rep,W,S)
-    col = lax.broadcasted_iota(jnp.int32, old.shape, 4)
-    old = jnp.where(col < lens[:, None, None, None, None], old, NEG_INF)
-    new = jnp.einsum("bgrwd,bgud->bgrwu", qg,
-                     k_new.astype(jnp.float32))        # (B,G,rep,W,W)
-    causal = (lax.broadcasted_iota(jnp.int32, new.shape, 4)
-              <= lax.broadcasted_iota(jnp.int32, new.shape, 3))
-    new = jnp.where(causal, new, NEG_INF)
-    probs = jax.nn.softmax(jnp.concatenate([old, new], axis=-1), axis=-1)
-    out = (jnp.einsum("bgrws,bgsd->bgrwd", probs[..., :s],
-                      v_cache.astype(jnp.float32))
-           + jnp.einsum("bgrwu,bgud->bgrwd", probs[..., s:],
-                        v_new.astype(jnp.float32)))    # (B,G,rep,W,hd)
-    return out.reshape(b, nh, w, hd).astype(q.dtype)
-
-
 def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
             cache_len: int, quant_cache: bool = False
             ) -> tuple[jax.Array, dict[str, jax.Array]]:
@@ -257,7 +225,7 @@ def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
 
 def window_logits(params: Params, config: LlamaConfig,
                   cache: dict[str, jax.Array], tokens: jax.Array,
-                  lens: jax.Array
+                  lens: jax.Array, attend: Optional[jax.Array] = None
                   ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Forward a (B, W) token window against per-row cache lengths — THE
     decode-side forward: `decode_step` is its W=1 case, the speculative
@@ -271,40 +239,47 @@ def window_logits(params: Params, config: LlamaConfig,
     (prefill's quant_cache=True) is detected by tree structure — a static
     property under jit, so both layouts share this function.
 
-    The layer loop only READS the cache (its scan `xs`): each layer
-    attends to the rows below `lens` plus the window's own rows from
-    registers, and hands the new rows out as `ys`. They are written once,
-    after the loop, in place (`write_cache_rows`). Passing updated slabs
-    back through the scan instead costs a slice, a copy and a write-back
-    of every layer's whole slab per token."""
+    `attend` (B,), where given, is how many cached rows each batch row
+    attends to, in place of lens: 0 for a row whose result the caller
+    throws away (the serving engine's slots that do not ride), which then
+    reads nothing of the cache. Where its window is written is still
+    lens[b].
+
+    The layer loop only READS the cache, which it closes over whole: each
+    layer attends to its own rows below `attend` (`cache_attention`: only
+    those leave HBM) plus the window's own rows from registers, and hands
+    the new rows out as `ys`. They are written once, after the loop, in
+    place (`write_cache_rows`). Passing updated slabs back through the
+    scan instead costs a slice, a copy and a write-back of every layer's
+    whole slab per token."""
     quant = "k_scale" in cache
     b, w = tokens.shape
     cache_len = cache["k"].shape[3]
     cos, sin = rope_tables(config, cache_len)
     positions = lens[:, None] + jnp.arange(w, dtype=lens.dtype)[None, :]
+    attend = jnp.minimum(lens if attend is None else attend,
+                         cache_len).astype(jnp.int32)
     x = embed_lookup(params["embed"], tokens, config)   # (B, W, D)
 
-    def body(x, layer_and_cache):
-        layer, c = layer_and_cache
+    def body(x, layer_and_index):
+        layer, index = layer_and_index
         # int8-quantized layers dequantize HERE, inside the scan body
         layer = dequantize_layer(layer)
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
         q, k, v = qkv_proj(h, layer, config)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-        rows, k_new, v_new = new_cache_rows(k, v, c["k"].dtype, quant)
-        # dequantized views feed straight into the attention einsums:
-        # XLA fuses the int8 read + row scale into the operand load
-        kc = dequantize_rows(c["k"], c["k_scale"]) if quant else c["k"]
-        vc = dequantize_rows(c["v"], c["v_scale"]) if quant else c["v"]
-        attn = _cache_attention(q, kc, vc, k_new, v_new, lens)
+        rows, k_new, v_new = new_cache_rows(k, v, cache["k"].dtype, quant)
+        attn = cache_attention(index[None], attend, q, k_new, v_new, cache)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, w, -1)
         x = x + jnp.einsum("bsh,hd->bsd", attn, layer["wo"])
         h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
         x = x + _mlp(h, layer, config)
         return x, rows
 
-    x, rows = lax.scan(body, x, (params["layers"], cache))
+    x, rows = lax.scan(body, x, (params["layers"],
+                                 jnp.arange(config.n_layers,
+                                            dtype=jnp.int32)))
     cache = write_cache_rows(cache, rows, lens)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
     logits = jnp.einsum("bwd,dv->bwv", x,
@@ -315,18 +290,21 @@ def window_logits(params: Params, config: LlamaConfig,
 
 def decode_step(params: Params, config: LlamaConfig,
                 cache: dict[str, jax.Array], token: jax.Array,
-                pos: jax.Array) -> tuple[jax.Array, dict[str, jax.Array]]:
+                pos: jax.Array, attend: Optional[jax.Array] = None
+                ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """One decode step. token: (B,) int32; pos: scalar int32 (the position
     the token occupies) or (B,) int32 per-row positions — the latter is
     the continuous-batching shape (serve/engine.py), where every batch
     row is an independent request slot at its own sequence position.
+    `attend` as in `window_logits`; a model whose cache is by layer kind
+    reads by its own rule and takes no notice of it.
     Returns (logits (B, V), updated cache)."""
     pos = jnp.broadcast_to(pos, token.shape)
     if cache_by_kind(config):
         from tony_tpu.models import sala
         return sala.decode_step(params, config, cache, token, pos)
     logits, cache = window_logits(params, config, cache, token[:, None],
-                                  pos)
+                                  pos, attend)
     return logits[:, 0], cache
 
 

@@ -15,7 +15,10 @@ without ever changing a shape:
   per-row positions (each slot at its own sequence length); rows are
   independent, so an active slot's tokens are bit-identical to decoding
   that request alone — and therefore to the offline `generate()` oracle
-  (pinned by tests/test_serve.py, staggered arrivals included).
+  (pinned by tests/test_serve.py, staggered arrivals included). A slot
+  whose token nobody will read (free, or past its stream's last step) is
+  stepped too, attending to nothing: the step's attention moves only the
+  rows below each slot's attend length (ops/cache_attention.py).
 - **Latch + recycle**: per-slot eos/budget latches run host-side on the
   sampled tokens; the moment a row finishes its slot is recycled for the
   next queued request. Garbage K/V an idle slot may write is always masked
@@ -84,6 +87,7 @@ from tony_tpu.models.generate import (
 )
 from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.observability.spans import Phases, span
+from tony_tpu.ops.cache_attention import read_chunk_rows
 from tony_tpu.serve import kvcache as kvc
 
 LOG = logging.getLogger(__name__)
@@ -303,6 +307,16 @@ class EngineStats:
     sparse_blocks_attended_total: int = 0
     sparse_context_blocks_total: int = 0
     dense_path_admissions_total: int = 0
+    # a model whose cache is K/V rows alone: of the rows the cache holds
+    # (slots x token budget, a layer), summed over decode steps, how many a
+    # step's attention read — each slot's attend length, 0 for one that
+    # does not ride, rounded up to the kernel's chunk
+    # (ops/cache_attention.py). Reckoned on the host by the TPU kernel's
+    # rule, not read back from it (the jnp body of other platforms reads
+    # the whole budget); their ratio is how much of the budget a step
+    # still reads
+    cache_rows_read_total: int = 0
+    cache_rows_budget_total: int = 0
     # per decode iteration, the loop thread's time outside its wait on
     # the device: from the previous read of a step's tokens returning to
     # the next one starting (booking, release, reap, prepare, dispatch,
@@ -357,13 +371,16 @@ def _draw_key(key: jax.Array, draw: jax.Array, temperature: float):
 def _decode_sample_step(params: Params, config: LlamaConfig, cache,
                         tokens: jax.Array, pos: jax.Array, key: jax.Array,
                         draw: jax.Array, temperature: float, top_k: int,
-                        top_p: float):
+                        top_p: float, attend: Optional[jax.Array] = None):
     """One continuous-batching step: decode every slot's previous token at
     its own position, sample the next. ONE compile per (config, n_slots,
     token_budget) — slot occupancy, positions, and request boundaries are
     all data, never shapes. `tokens` is the vector the step (or admission)
-    before returned, still on the device; the result is the next call's."""
-    logits, cache = decode_step(params, config, cache, tokens, pos)
+    before returned, still on the device; the result is the next call's.
+    `attend` is how many cached rows each slot attends to: its position,
+    or 0 for a slot that does not ride, whose token nobody reads (absent:
+    every slot's position)."""
+    logits, cache = decode_step(params, config, cache, tokens, pos, attend)
     nxt = _sample(logits, temperature, top_k,
                   _draw_key(key, draw, temperature), top_p)
     return nxt, cache
@@ -487,6 +504,11 @@ class ContinuousBatchingEngine:
                 "a slot's cache is not a function of its K/V rows alone")
         self._sparse_reads = getattr(config, "sparse_read_blocks", None)
         self._cache = self._empty_cache()
+        # rows a chunk of the decode step's cache read holds (0: the whole
+        # budget is read); None for a cache by layer kind, read by its
+        # model's own rule
+        self._read_chunk = None if cache_by_kind(config) else \
+            read_chunk_rows(token_budget, self._cache["k"].dtype)
         # paged prefix-shared KV pool (serve/kvcache.py); None = sharing
         # OFF, which keeps the admission path byte-identical to the
         # pre-paging engine
@@ -840,16 +862,21 @@ class ContinuousBatchingEngine:
             if riders:
                 ph.enter("tony.engine.decode.prepare")
                 # every slot is stepped; one that does not ride stays where
-                # it is (a freed one at its parked row, `_finish_slot`). A
-                # fresh array a step: the call may still be reading the
-                # one before
+                # it is (a freed one at its parked row, `_finish_slot`) and
+                # attends to nothing, so the step reads none of its rows:
+                # its token is thrown away. Fresh arrays a step: the call
+                # may still be reading the ones before
                 pos = np.fromiter((s.pos for s in self._slots), np.int32,
                                   self.n_slots)
+                attend = np.fromiter(
+                    (s.pos if s.rides else 0 for s in self._slots),
+                    np.int32, self.n_slots)
+                read = self._rows_read(attend)
                 ph.enter("tony.engine.decode.dispatch")
                 self._tokens, self._cache = _decode_sample_step(
                     self.params, self.config, self._cache, self._tokens,
                     pos, self._key, self._next_draw(), self.temperature,
-                    self.top_k, self.top_p)
+                    self.top_k, self.top_p, attend=attend)
                 flight = _Flight(self._tokens,
                                  [(s, s.handle, s.pos) for s in riders])
                 for slot in riders:
@@ -858,6 +885,10 @@ class ContinuousBatchingEngine:
                 with self._lock:
                     self.stats.decode_steps_total += 1
                     self.stats.decode_steps_overlapped_total += bool(landing)
+                    if read is not None:
+                        self.stats.cache_rows_read_total += read
+                        self.stats.cache_rows_budget_total += (
+                            self.n_slots * self.token_budget)
                 if land:
                     landing.append(flight)
                 else:
@@ -874,6 +905,17 @@ class ContinuousBatchingEngine:
             # span
             del landing, flight
             return True
+
+    def _rows_read(self, attend: np.ndarray) -> Optional[int]:
+        """Cache rows (a layer) a decode step with these attend lengths
+        reads: each slot's length rounded up to the kernel's chunk (None
+        for a cache by layer kind, which keeps no such count)."""
+        chunk = self._read_chunk
+        if chunk is None:
+            return None
+        if chunk == 0:
+            return self.n_slots * self.token_budget
+        return int(((attend + (chunk - 1)) // chunk).sum()) * chunk
 
     def _next_draw(self) -> np.int32:
         """The number of the next sampling draw (`_draw_key`)."""
@@ -1270,6 +1312,10 @@ class ContinuousBatchingEngine:
                 for name in ("sparse_blocks_attended_total",
                              "sparse_context_blocks_total",
                              "dense_path_admissions_total"):
+                    snap[name] = getattr(self.stats, name)
+            if self._read_chunk is not None:
+                for name in ("cache_rows_read_total",
+                             "cache_rows_budget_total"):
                     snap[name] = getattr(self.stats, name)
             itl = _percentile(self.stats.itl_s, 0.50)
             if itl is not None:
