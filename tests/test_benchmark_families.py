@@ -20,7 +20,8 @@ sys.path.insert(0, BENCH)
 from lib import spec  # noqa: E402
 
 FAMILY_OF = {"mistral-7b-train": "llama", "mistral-7b-serve": "llama",
-             "minicpm-sala-serve": "minicpm_sala"}
+             "minicpm-sala-serve": "minicpm_sala",
+             "lfm2-24b-a2b-serve": "lfm2_moe"}
 FUNCTIONS = {
     "program": ("serving", "training"),
     "reference": ("init_on_device", "served_logits", "follow_training"),
@@ -96,7 +97,8 @@ def test_a_family_has_its_three_modules_and_their_functions(family):
 def test_nothing_outside_the_families_names_an_architecture():
     """lib/, launch/, metrics/ and run.py find the program's model, the
     reference and the counts through the family alone."""
-    named = re.compile(r"models\.(llama|sala)|models import (llama|sala)|"
+    named = re.compile(r"models\.(llama|sala|lfm2)|"
+                       r"models import (llama|sala|lfm2)|Lfm2Config|"
                        r"LlamaConfig|SalaConfig|llama_init|sala_init|"
                        r"llama_loss|"
                        r"from lib import [^\n]*\b(reference|counts)\b|"
@@ -121,3 +123,35 @@ def test_a_configuration_of_the_new_family_states_what_it_assumed():
                if isinstance(v, dict))
     assert cfg["mixer_types"] == ["minicpm4"] + ["lightning-attn"] * 3 \
         + cfg["mixer_types"][4:] and len(cfg["mixer_types"]) == 16
+
+
+def test_the_expert_configuration_is_the_published_one_cut_in_depth_alone():
+    """lfm2-24b-a2b-serve: every number of the source's config under the
+    source's key, the depth and the layer list (a prefix) alone reduced,
+    each assumed size with its origin, and the bytes the file states are
+    the family's counts."""
+    path, cfg = _config("lfm2-24b-a2b-serve")
+    assert cfg["reduced"] == ["num_hidden_layers", "layer_types"]
+    assert set(cfg["reduced"]) <= set(cfg["changed"])
+    published = ["conv", "conv"] + ["full_attention", "conv", "conv",
+                                    "conv"] * 2
+    assert cfg["layer_types"] == published and cfg["num_hidden_layers"] == 10
+    assert (cfg["hidden_size"], cfg["intermediate_size"],
+            cfg["moe_intermediate_size"], cfg["num_experts"],
+            cfg["num_experts_per_tok"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["vocab_size"],
+            cfg["conv_L_cache"], cfg["num_dense_layers"]) == (
+        2048, 11776, 1536, 64, 4, 32, 8, 65536, 3, 2)
+    assert all(v["origin"] for v in cfg["assumed"].values()
+               if isinstance(v, dict))
+    counts = spec.load_family(path, cfg).counts
+    assert counts.total_params(cfg) == 5_267_090_176
+    run = cfg["run"]
+    assert counts.cache_bytes(cfg, run["slots"], run["token_budget"]) \
+        == 64 * 8192 * 4096 + 64 * 8 * 3 * 2048 * 4
+    # a step that hits 57 of 64 experts a layer reads 8.6 of its 9.6 GB
+    # from the experts; the least a caller may be told is top-4's
+    full = counts.decode_step_bytes(cfg, [50_000], experts_hit=57,
+                                    riders=34)
+    assert 9.5e9 < full < 9.8e9
+    assert counts.decode_step_bytes(cfg, [50_000]) < 0.2 * full

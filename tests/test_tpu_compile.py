@@ -412,3 +412,84 @@ def test_sala_admission_of_32768_tokens_fits_beside_weights_and_cache(
     text = compiled.as_text()
     for kernel in ("tony_sparse_attn", "tony_lightning_chunk"):
         assert kernel in text, kernel
+
+
+# -- conv + attention layers with experts: the lfm2-longgen cell's programs --
+
+def _lfm2_cell(one_chip):
+    """benchmark/configs/lfm2-24b-a2b-serve.json as the program has it:
+    LFM2-24B-A2B widths (heads of 64, 64 experts of 1536, top-4), the
+    first 10 published layers, 64 slots x 8192 tokens; abstract weights
+    and cache."""
+    from tony_tpu.models import lfm2
+
+    config = lfm2.Lfm2Config(n_layers=10, max_seq=8192,
+                             layer_types=lfm2.Lfm2Config.layer_types[:10])
+    slots, budget = 64, 8192
+    params = jax.tree.map(
+        lambda l: _sds(l.shape, l.dtype, one_chip),
+        jax.eval_shape(partial(lfm2.lfm2_init, config),
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(
+        lambda l: _sds(l.shape, l.dtype, one_chip),
+        jax.eval_shape(lambda: lfm2.empty_cache(config, slots, budget)))
+
+    def nbytes(tree):
+        return sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                   for c in jax.tree.leaves(tree))
+    return config, params, cache, nbytes(params), nbytes(cache), slots
+
+
+def test_lfm2_decode_step_reads_experts_in_place_at_heads_of_64(one_chip):
+    """The 64-slot decode step at the cell's shapes: weights 10.53 GB and
+    cache 2.15 GB + 13 MB of float32 conv states are arguments, the cache aliased in
+    and out; `tony_expert_matmul` takes the expert stacks where they lie
+    (no layer's 0.6 GB of experts is sliced out: the temporaries stay
+    under 64 MB) and `tony_decode_read` compiles at heads of 64; each kind
+    of layer body is held once; the step returns its counts."""
+    from tony_tpu.serve.engine import _decode_sample_step
+
+    config, params, cache, weights, cache_bytes, slots = _lfm2_cell(one_chip)
+    assert abs(weights - 10.534e9) < 0.01e9 and abs(
+        cache_bytes - 2.1601e9) < 0.001e9
+    per_slot = _sds((slots,), jnp.int32, one_chip)
+    lowered = _decode_sample_step.lower(
+        params, config, cache, per_slot, per_slot,
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
+        0.0, 0, 1.0, attend=per_slot)
+    assert [o.shape for o in jax.tree.leaves(lowered.out_info)][-1] == (2,)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    text = compiled.as_text()
+    for kernel in ("tony_expert_matmul", "tony_decode_read"):
+        assert kernel in text, kernel
+    # the attention body's pair and the conv body's pair, not 2 x 8 layers
+    assert len(re.findall(
+        r"%tony_expert_matmul[.\d]* = [^=]*? custom-call\(", text)) == 4
+
+
+@pytest.mark.parametrize("prompt,temp_limit", [(256, 0.1e9), (4096, 1.5e9)])
+def test_lfm2_admission_fits_beside_weights_and_cache(one_chip, prompt,
+                                                      temp_limit):
+    """The shortest and the longest admission of the cell (batch 1): flash
+    attention at heads of 64 and the grouped matmul at the admission's
+    tile (16 rows for 256 tokens, 256 for 4096) lower, and weights, cache
+    and temporaries fit the chip together: slots stayed 64."""
+    from tony_tpu.serve.engine import _admit_step
+
+    config, params, cache, weights, cache_bytes, slots = _lfm2_cell(one_chip)
+    compiled = _admit_step.lower(
+        params, config, cache, _sds((slots,), jnp.int32, one_chip),
+        _sds((prompt,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
+        0.0, 0, 1.0, False, _sds((), jnp.int32, one_chip), False).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_limit, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
+    text = compiled.as_text()
+    for kernel in ("tony_expert_matmul", "tony_flash_fwd"):
+        assert kernel in text, kernel

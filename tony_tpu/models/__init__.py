@@ -24,8 +24,24 @@ from tony_tpu.models.vit import (
     ViTConfig, vit_forward, vit_init, vit_loss, vit_param_axes,
 )
 
+# the models whose layers are of several kinds and whose cache is by layer
+# kind: each module has PRESETS, `get_config(name)`, `init(config, key)`
+# and what models/generate.py `kind_module` asks of it. Imported when a
+# preset is looked for, not with the package.
+BY_KIND = ("tony_tpu.models.sala", "tony_tpu.models.lfm2")
+
+
+def by_kind_preset(name: str):
+    """The module of BY_KIND whose PRESETS hold `name`, or None."""
+    import importlib
+    for module in map(importlib.import_module, BY_KIND):
+        if name in module.PRESETS:
+            return module
+    return None
+
+
 __all__ = [
-    "generate", "generate_text",
+    "generate", "generate_text", "BY_KIND", "by_kind_preset",
     "LlamaConfig", "llama_forward", "llama_init", "llama_loss",
     "llama_param_axes", "mnist_forward", "mnist_init", "mnist_loss",
     "linreg_forward", "linreg_init", "linreg_loss",

@@ -27,6 +27,7 @@ re-running the full training forward on the growing sequence.
 
 from __future__ import annotations
 
+import importlib
 from functools import partial
 from typing import Any, Optional
 
@@ -78,8 +79,9 @@ def _warn_moe_below_capacity(config: LlamaConfig, who: str = "decode"
     silently serves tokens training dropped (ADVICE r5). Mirrors the
     ValueError in speculative_generate, softened to a warning here
     because plain sampling has no exactness contract to break."""
-    if not getattr(config, "n_experts", 0):
-        return
+    if not getattr(config, "n_experts", 0) \
+            or not hasattr(config, "capacity_factor"):
+        return      # no experts, or experts routed without a capacity
     from tony_tpu.models.moe import no_drop_capacity_floor
     floor = no_drop_capacity_floor(config)
     if config.capacity_factor < floor:
@@ -94,11 +96,41 @@ def _warn_moe_below_capacity(config: LlamaConfig, who: str = "decode"
 
 def cache_by_kind(config) -> bool:
     """True for a model whose layers are of several kinds and whose cache
-    therefore has other leaves than K/V rows (models/sala.py): `prefill`,
-    `decode_step` and `empty_cache` hand such a config to its own module.
-    A Python test at trace time: a LlamaConfig's programs hold no trace of
-    it."""
+    therefore has other leaves than K/V rows: `prefill`, `decode_step` and
+    `empty_cache` hand such a config to the module it names
+    (`kind_module`). A Python test at trace time: a LlamaConfig's programs
+    hold no trace of it."""
     return getattr(config, "has_recurrent_state", False)
+
+
+def kind_module(config, quant_cache: bool = False):
+    """The module a config whose cache is by layer kind names under
+    `cache_module` — it implements `empty_cache(config, slots, budget)`,
+    `prefill(params, tokens, config, cache_len)` and `decode_step(params,
+    config, cache, token, pos, attend)`, and takes `quant_cache=True` where
+    it declares `INT8_CACHE` — or None for a config whose cache is K/V
+    rows alone. Trace-time Python only."""
+    if not cache_by_kind(config):
+        return None
+    module = importlib.import_module(config.cache_module)
+    if quant_cache and not getattr(module, "INT8_CACHE", False):
+        raise ValueError(
+            "quant_cache: this model's cache is by layer kind "
+            "(compressed keys, recurrent state); it has no int8 form")
+    return module
+
+
+def kv_leaves(shape: tuple, dtype, quant_cache: bool
+              ) -> dict[str, jax.Array]:
+    """Zero K/V rows of `shape` (L, B, Hkv, S, hd): int8 rows with one
+    float32 scale a row if `quant_cache`, else `dtype`."""
+    if quant_cache:
+        scale = shape[:-1] + (1,)
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
+                "k_scale": jnp.zeros(scale, jnp.float32),
+                "v_scale": jnp.zeros(scale, jnp.float32)}
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def empty_cache(config, n_slots: int, token_budget: int,
@@ -107,23 +139,14 @@ def empty_cache(config, n_slots: int, token_budget: int,
     in exactly the tree `prefill` writes (int8 layout included, which
     `window_logits` detects by structure). Every leaf has the slot on
     axis 1: that is all an admission needs to know to write one."""
-    if cache_by_kind(config):
-        if quant_cache:
-            raise ValueError(
-                "quant_cache: this model's cache is by layer kind "
-                "(compressed keys, recurrent state); it has no int8 form")
-        from tony_tpu.models import sala
-        return sala.empty_cache(config, n_slots, token_budget)
-    shape = (config.n_layers, n_slots, config.n_kv_heads, token_budget,
-             config.head_dim)
-    if quant_cache:
-        scale = shape[:-1] + (1,)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(scale, jnp.float32),
-                "v_scale": jnp.zeros(scale, jnp.float32)}
-    return {"k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype)}
+    module = kind_module(config, quant_cache)
+    if module is not None:
+        return module.empty_cache(config, n_slots, token_budget,
+                                  **({"quant_cache": True} if quant_cache
+                                     else {}))
+    return kv_leaves((config.n_layers, n_slots, config.n_kv_heads,
+                      token_budget, config.head_dim), config.dtype,
+                     quant_cache)
 
 
 def new_cache_rows(k, v, dtype, quant: bool):
@@ -155,8 +178,8 @@ def write_cache_rows(cache, rows, offsets):
     loop) each is an in-place write of L x Hkv x W rows, and nothing
     cache-sized is sliced, copied or stacked. A scatter would do it in one
     op, but forces a cache layout that brings slab copies back. `offsets`
-    may also be {name: (B,)}: leaves that advance at different paces
-    (models/sala.py) are still written by the one loop."""
+    may also be {name: (B,)}: leaves that advance at different paces (a
+    cache by layer kind) are still written by the one loop."""
     per_leaf = offsets if isinstance(offsets, dict) else dict.fromkeys(
         cache, offsets)
 
@@ -181,9 +204,11 @@ def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
     decode bandwidth is cache-read-bound, so halving cache bytes is the
     long-context serving lever the way weight int8 is the short-context
     one."""
-    if cache_by_kind(config):
-        from tony_tpu.models import sala
-        return sala.prefill(params, tokens, config, cache_len)
+    module = kind_module(config, quant_cache)
+    if module is not None:
+        return module.prefill(params, tokens, config, cache_len,
+                              **({"quant_cache": True} if quant_cache
+                                 else {}))
     b, p = tokens.shape
     nkv, hd = config.n_kv_heads, config.head_dim
     cos, sin = rope_tables(config, cache_len)
@@ -288,6 +313,26 @@ def window_logits(params: Params, config: LlamaConfig,
     return logits, cache
 
 
+def decode_step_counted(params: Params, config: LlamaConfig,
+                        cache: dict[str, jax.Array], token: jax.Array,
+                        pos: jax.Array, attend: Optional[jax.Array] = None):
+    """`decode_step`, and what the model counted on the device while it
+    ran: an int32 vector named entry by entry by the module's
+    `STEP_COUNTS` (experts that got rows, say), or None for a
+    model that counts nothing — no result of the compiled step."""
+    pos = jnp.broadcast_to(pos, token.shape)
+    module = kind_module(config)
+    if module is not None:
+        counted = getattr(module, "decode_step_counted", None)
+        if counted is not None:
+            return counted(params, config, cache, token, pos, attend)
+        return (*module.decode_step(params, config, cache, token, pos,
+                                    attend), None)
+    logits, cache = window_logits(params, config, cache, token[:, None],
+                                  pos, attend)
+    return logits[:, 0], cache, None
+
+
 def decode_step(params: Params, config: LlamaConfig,
                 cache: dict[str, jax.Array], token: jax.Array,
                 pos: jax.Array, attend: Optional[jax.Array] = None
@@ -297,15 +342,10 @@ def decode_step(params: Params, config: LlamaConfig,
     the continuous-batching shape (serve/engine.py), where every batch
     row is an independent request slot at its own sequence position.
     `attend` as in `window_logits`; a model whose cache is by layer kind
-    reads by its own rule and takes no notice of it.
+    is handed it too, and reads by its own rule.
     Returns (logits (B, V), updated cache)."""
-    pos = jnp.broadcast_to(pos, token.shape)
-    if cache_by_kind(config):
-        from tony_tpu.models import sala
-        return sala.decode_step(params, config, cache, token, pos)
-    logits, cache = window_logits(params, config, cache, token[:, None],
-                                  pos, attend)
-    return logits[:, 0], cache
+    return decode_step_counted(params, config, cache, token, pos,
+                               attend)[:2]
 
 
 def _sample(logits: jax.Array, temperature: float, top_k: int,

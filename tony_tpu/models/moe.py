@@ -29,6 +29,19 @@ makes the mesh's `ep` axis real. Design:
 
 The MoE block replaces the dense SwiGLU MLP in the Llama block; attention,
 RoPE, norms are shared with models/llama.py.
+
+**Two paths, two callers.** Everything above (`MoEConfig`, `moe_mlp`: a
+padded queue an expert, overflow dropped) is the TRAINING path: the
+trainer's `moe_loss`, and `generate()` / the engine for a `MoEConfig`
+preset, which route through `models/generate._mlp`. The SERVING path of a
+model whose experts are published with no capacity (models/lfm2.py) is
+the last section, `grouped_expert_mlp`: sigmoid scores and top-k in
+float32 (`route_topk`), every chosen (token, expert) pair laid out group by
+group (`group_rows`) and multiplied by ONE grouped matmul
+(ops/expert_matmul.py `tony_expert_matmul`) that reads an expert's weights
+only if it has rows. No token is dropped at any imbalance; a row the
+caller marks as not riding (the serving engine's parked slots) reaches no
+expert. It has no training path and no aux loss.
 """
 
 from __future__ import annotations
@@ -41,6 +54,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from tony_tpu.models.llama import LlamaConfig, llama_init, llama_param_axes
+from tony_tpu.ops.expert_matmul import (
+    expert_matmul, padded_rows, tile_rows_for,
+)
 from tony_tpu.ops.rmsnorm import rms_norm
 from tony_tpu.parallel.sharding import constrain
 
@@ -334,3 +350,113 @@ def moe_loss(params: Params, batch: dict[str, jax.Array],
     x, aux = moe_hidden(params, inputs, config)
     return (_head_loss(x, params, targets, config)
             + config.aux_loss_weight * aux)
+
+
+# ---------------------------------------------------------------------------
+# serving: routing without dropped tokens, one grouped matmul
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class RouterSpec:
+    """How a layer chooses experts: `top_k` of `n_experts` by sigmoid
+    score plus a per-expert bias; the weights are the chosen scores
+    themselves (without the bias), divided by their sum + `norm_eps` if
+    `norm_topk`, times `scale`."""
+    n_experts: int
+    top_k: int
+    norm_topk: bool = True
+    scale: float = 1.0
+    norm_eps: float = 1e-6
+
+
+def route_topk(u: jax.Array, router: jax.Array, bias: jax.Array,
+               spec: RouterSpec) -> tuple[jax.Array, jax.Array]:
+    """u (T, D) -> (chosen experts (T, k) int32, their weights (T, k)
+    float32). The scores are computed in float32 whatever the weights'
+    type (`highest`: one bf16 pass would move a score by 1e-2, more than
+    the gap between a token's 4th and 5th expert one time in ten); ties go
+    to the lower expert index."""
+    z = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(z)
+    _, chosen = lax.top_k(scores + bias.astype(jnp.float32), spec.top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if spec.norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + spec.norm_eps)
+    return chosen.astype(jnp.int32), weights * spec.scale
+
+
+def group_rows(chosen: jax.Array, valid: jax.Array | None, n_experts: int,
+               tile_rows: int) -> dict[str, jax.Array]:
+    """Lay the (token, choice) pairs out group by group, each expert's
+    group starting at a multiple of `tile_rows` (ops/expert_matmul.py).
+    chosen (T, k); valid (T,) bool or None: a token that is not valid
+    reaches no expert. Returns `dest` (T, k) the row of each pair (the
+    layout's length for a pair that was dropped), `row_token` (rows,) the
+    token whose input a row holds (T for a padding row), `tile_expert`
+    (rows / tile_rows,), `tiles_used` (1,) and `counts` (E,) the rows each
+    expert got. No sort: a pair's rank in its group is a running count."""
+    t, k = chosen.shape
+    rows = padded_rows(t * k, n_experts, tile_rows)
+    flat = chosen.reshape(-1)
+    onehot = flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None]
+    if valid is not None:
+        onehot &= jnp.repeat(valid, k)[:, None]
+    running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    counts = running[-1]
+    rank = jnp.take_along_axis(running, flat[:, None], axis=1)[:, 0] - 1
+    ends = jnp.cumsum(-(-counts // tile_rows) * tile_rows)
+    starts = ends - -(-counts // tile_rows) * tile_rows
+    kept = jnp.any(onehot, axis=1)
+    dest = jnp.where(kept, starts[flat] + rank, rows)
+    token = jnp.arange(t * k, dtype=jnp.int32) // k
+    row_token = jnp.full((rows,), t, jnp.int32).at[dest].set(
+        token, mode="drop")
+    first_row = jnp.arange(rows // tile_rows, dtype=jnp.int32) * tile_rows
+    tile_expert = jnp.minimum(
+        jnp.sum(ends[None, :] <= first_row[:, None], axis=1),
+        n_experts - 1).astype(jnp.int32)
+    return {"dest": dest.reshape(t, k), "row_token": row_token,
+            "tile_expert": tile_expert,
+            "tiles_used": (ends[-1:] // tile_rows).astype(jnp.int32),
+            "counts": counts}
+
+
+def grouped_expert_mlp(u: jax.Array, layer: jax.Array, experts: Params,
+                       spec: RouterSpec, valid: jax.Array | None = None
+                       ) -> tuple[jax.Array, jax.Array]:
+    """The expert MLP of one layer over rows u (T, D): out (T, D) float32
+    = sum over a token's k experts of w_e * W2_e(silu(W1_e u) * W3_e u),
+    and the rows each expert got (E,) int32. `experts` holds the WHOLE
+    stacks `router` (layers, D, E), `expert_bias` (layers, E), `w1`, `w3`
+    (layers, E, D, F), `w2` (layers, E, F, D), of which `layer` (scalar
+    int32) is read. Rows in float32 are scored as they are and multiplied
+    as two halves of the weights' type (ops/expert_matmul.py
+    `split_rows`); the gated product between the two matmuls stays
+    float32. `valid` (T,) marks the rows that ride: the others reach no
+    expert and come out zero."""
+    t, d = u.shape
+    tile = tile_rows_for(t * spec.top_k, spec.n_experts)
+    layer1 = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    with jax.named_scope("tony_moe_route"):
+        router = lax.dynamic_index_in_dim(experts["router"], layer, 0, False)
+        bias = lax.dynamic_index_in_dim(experts["expert_bias"], layer, 0,
+                                        False)
+        chosen, weights = route_topk(u, router, bias, spec)
+        groups = group_rows(chosen, valid, spec.n_experts, tile)
+        x = jnp.take(jnp.concatenate([u, jnp.zeros((1, d), u.dtype)]),
+                     groups["row_token"], axis=0)
+    tiles = (layer1, groups["tile_expert"], groups["tiles_used"])
+    h = expert_matmul(*tiles, x, experts["w1"], experts["w3"],
+                      tile_rows=tile, out_dtype=jnp.float32)
+    y = expert_matmul(*tiles, h, experts["w2"], tile_rows=tile,
+                      out_dtype=jnp.float32)
+    with jax.named_scope("tony_moe_route"):
+        dest = groups["dest"]
+        got = jnp.take(y, jnp.minimum(dest, y.shape[0] - 1), axis=0)
+        # a dropped pair's row is not its own (and a tile nobody used was
+        # never written): masked, not multiplied by zero
+        out = jnp.sum(jnp.where((dest < y.shape[0])[..., None],
+                                weights[..., None] * got, 0.0), axis=1)
+    return out, groups["counts"]

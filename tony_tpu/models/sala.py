@@ -143,6 +143,12 @@ class SalaConfig:
         """Whether a sparse layer attends to all of such a context."""
         return context <= self.sparse.dense_len
 
+    @property
+    def cache_module(self) -> str:
+        """The module that implements this config's `empty_cache`,
+        `prefill` and `decode_step` (models/generate.py `kind_module`)."""
+        return __name__
+
 
 PRESETS = {
     # test size: two periods, blocks of 8 tokens, dense up to 64
@@ -164,6 +170,15 @@ def is_sala_preset(name: str) -> bool:
 
 def get_sala_config(name: str, **overrides) -> SalaConfig:
     return replace(PRESETS[name], **overrides)
+
+
+get_config = get_sala_config
+
+
+def init(config: SalaConfig, key: jax.Array) -> Params:
+    """What `python -m tony_tpu.serve` draws a preset's weights with:
+    `sala_init`, looked up when called."""
+    return sala_init(config, key)
 
 
 def lightning_slopes(config: SalaConfig) -> np.ndarray:
@@ -531,9 +546,11 @@ def _lightning_decode(x, state, layer: Params, decay, index, cos, sin, pos,
 
 def decode_step(params: Params, config: SalaConfig,
                 cache: dict[str, jax.Array], token: jax.Array,
-                pos: jax.Array) -> tuple[jax.Array, dict[str, jax.Array]]:
+                pos: jax.Array, attend=None
+                ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """One token a slot. token (B,) int32 at positions pos (B,) (the rows
-    each slot's cache holds). Returns (logits (B, V), the cache with the
+    each slot's cache holds); `attend` is the engine's riding mask, which
+    these layers take no notice of (they read by their own rule). Returns (logits (B, V), the cache with the
     token's K/V rows, any compressed key it completed and the advanced
     states written)."""
     from tony_tpu.models.generate import write_cache_rows

@@ -59,7 +59,7 @@ def read_chunk_rows(budget: int, dtype) -> int:
 
 
 def _attend_jnp(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
-                window: int):
+                window: int, sm_scale: float | None = None, out_dtype=None):
     """The plain body: every row of the layer is read and the rows at or
     past lens[b] are masked. One softmax over the cached and the window's
     own scores: every position attends to exactly its rows 0..position."""
@@ -73,7 +73,8 @@ def _attend_jnp(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
         vc = vc * of_layer(scales[1])[..., None]
     b, g, r, d = q.shape
     s, w = kc.shape[2], window
-    qg = q.reshape(b, g, r // w, w, d).astype(jnp.float32) * d ** -0.5
+    qg = q.reshape(b, g, r // w, w, d).astype(jnp.float32) * (
+        d ** -0.5 if sm_scale is None else sm_scale)
     old = jnp.einsum("bgrwd,bgsd->bgrws", qg, kc)       # (B,G,rep,W,S)
     col = lax.broadcasted_iota(jnp.int32, old.shape, 4)
     old = jnp.where(col < lens[:, None, None, None, None], old, NEG_INF)
@@ -86,7 +87,7 @@ def _attend_jnp(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
     out = (jnp.einsum("bgrws,bgsd->bgrwd", probs[..., :s], vc)
            + jnp.einsum("bgrwu,bgud->bgrwd", probs[..., s:],
                         v_new.astype(jnp.float32)))     # (B,G,rep,W,hd)
-    return out.reshape(b, g, r, d).astype(q.dtype)
+    return out.reshape(b, g, r, d).astype(out_dtype or q.dtype)
 
 
 def _decode_read_kernel(layer_ref, len_ref, q_ref, kn_ref, vn_ref, *refs,
@@ -173,14 +174,17 @@ def _decode_read_kernel(layer_ref, len_ref, q_ref, kn_ref, vn_ref, *refs,
 
 
 def _attend_pallas(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
-                   window: int, chunk: int, interpret: bool = False):
+                   window: int, chunk: int, sm_scale: float | None = None,
+                   out_dtype=None, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, g, r, d = q.shape
     leaves = (k_cache, v_cache) + scales
     kernel = functools.partial(_decode_read_kernel, chunk=chunk,
-                               window=window, sm_scale=d ** -0.5,
+                               window=window,
+                               sm_scale=d ** -0.5 if sm_scale is None
+                               else sm_scale,
                                quant=bool(scales))
 
     def per_slot(rows):
@@ -199,7 +203,7 @@ def _attend_pallas(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
                 for leaf in leaves
             ] + [pltpu.SemaphoreType.DMA((len(leaves), 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, g, r, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, r, d), out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -209,7 +213,9 @@ def _attend_pallas(layer, lens, q, k_new, v_new, k_cache, v_cache, *scales,
 
 def cache_attention(layer: jax.Array, lens: jax.Array, q: jax.Array,
                     k_new: jax.Array, v_new: jax.Array,
-                    cache: dict[str, jax.Array]) -> jax.Array:
+                    cache: dict[str, jax.Array],
+                    sm_scale: float | None = None,
+                    out_dtype=None) -> jax.Array:
     """Attention of W new positions a slot against the cache AND
     themselves.
 
@@ -223,7 +229,9 @@ def cache_attention(layer: jax.Array, lens: jax.Array, q: jax.Array,
     v_new (B, Hkv, W, hd) are the window's own rows as a read back from
     the cache would give them, attended from registers under the
     within-window causal mask. GQA by head group: K/V are never repeated.
-    Returns (B, H, W, hd)."""
+    The scores are scaled by `sm_scale` (absent: hd ** -0.5; a caller that
+    lays several narrow heads side by side in one row gives its own).
+    Returns (B, H, W, hd) in `out_dtype` (absent: q's)."""
     b, nh, w, hd = q.shape
     g = k_new.shape[1]
     budget = cache["k"].shape[3]
@@ -232,8 +240,10 @@ def cache_attention(layer: jax.Array, lens: jax.Array, q: jax.Array,
     args = (layer, lens, q.reshape(b, g, nh // g * w, hd), k_new, v_new,
             cache["k"], cache["v"]) + scales
     chunk = read_chunk_rows(budget, cache["k"].dtype)
-    plain = functools.partial(_attend_jnp, window=w)
-    kernel = functools.partial(_attend_pallas, window=w, chunk=chunk)
+    plain = functools.partial(_attend_jnp, window=w, sm_scale=sm_scale,
+                              out_dtype=out_dtype)
+    kernel = functools.partial(_attend_pallas, window=w, chunk=chunk,
+                               sm_scale=sm_scale, out_dtype=out_dtype)
     if not chunk:
         out = plain(*args)
     elif _INTERPRET:

@@ -37,8 +37,9 @@ LOG = logging.getLogger(__name__)
 def build_arg_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog="tony_tpu.serve")
     p.add_argument("--config", default="tiny",
-                   help="model preset (models/llama.py PRESETS / MoE / "
-                        "models/sala.py)")
+                   help="model preset (models/llama.py PRESETS / MoE / a "
+                        "model of several layer kinds: models/__init__.py "
+                        "BY_KIND)")
     p.add_argument("--checkpoint-dir", default="",
                    help="restore params from the latest checkpoint here "
                         "(the examples/llama-pretrain format)")
@@ -155,14 +156,15 @@ def _load_model(args):
     import jax
     import jax.numpy as jnp
 
+    from tony_tpu.models import by_kind_preset
     from tony_tpu.models.moe import is_moe_preset
-    from tony_tpu.models.sala import is_sala_preset
 
-    if is_sala_preset(args.config):
-        # layers of several kinds; the engine asks the model for its cache
-        from tony_tpu.models import sala
-        config = sala.get_sala_config(args.config)
-        params = sala.sala_init(config, jax.random.PRNGKey(0))
+    kind = by_kind_preset(args.config)
+    if kind is not None:
+        # layers of several kinds: the preset's own module makes the
+        # config and the weights, and the engine asks it for its cache
+        config = kind.get_config(args.config)
+        params = kind.init(config, jax.random.PRNGKey(0))
     elif is_moe_preset(args.config):
         from tony_tpu.models.moe import get_moe_config, moe_init
         base = get_moe_config(args.config)

@@ -82,8 +82,8 @@ from jax import lax
 
 from tony_tpu import constants as C
 from tony_tpu.models.generate import (
-    _sample, _warn_moe_below_capacity, cache_by_kind, decode_step,
-    empty_cache, prefill,
+    _sample, _warn_moe_below_capacity, cache_by_kind, decode_step_counted,
+    empty_cache, kind_module, prefill,
 )
 from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.observability.spans import Phases, span
@@ -252,6 +252,9 @@ class _Slot:
 class _Flight:
     """A dispatched decode step whose tokens the host has not read."""
     tokens: jax.Array     # the step's sampled tokens, (n_slots,), on device
+    # what the model counted on the device during the step (the module's
+    # STEP_COUNTS), read back with the tokens; None for most models
+    counts: Optional[jax.Array]
     # who held which slot when it was dispatched, and the row it wrote:
     # a token is booked only for a handle that still holds its slot
     riders: list[tuple[_Slot, RequestHandle, int]]
@@ -317,6 +320,16 @@ class EngineStats:
     # still reads
     cache_rows_read_total: int = 0
     cache_rows_budget_total: int = 0
+    # a model with expert layers that counts on the device
+    # (models/lfm2.py STEP_COUNTS): expert layers run summed over the
+    # decode steps read (the layers a step has, reckoned here), and, READ
+    # BACK from each step with its tokens, the experts that got at least
+    # one row and the rows they got, summed over those layers. Their
+    # ratios: the share of a layer's experts a step reads the weights of,
+    # and the rows an expert hit serves
+    moe_layer_steps_total: int = 0
+    moe_experts_hit_total: int = 0
+    moe_rows_total: int = 0
     # per decode iteration, the loop thread's time outside its wait on
     # the device: from the previous read of a step's tokens returning to
     # the next one starting (booking, release, reap, prepare, dispatch,
@@ -379,11 +392,14 @@ def _decode_sample_step(params: Params, config: LlamaConfig, cache,
     before returned, still on the device; the result is the next call's.
     `attend` is how many cached rows each slot attends to: its position,
     or 0 for a slot that does not ride, whose token nobody reads (absent:
-    every slot's position)."""
-    logits, cache = decode_step(params, config, cache, tokens, pos, attend)
+    every slot's position). Returns (tokens, cache, what the model counted
+    on the device during the step: None, no result at all, for a model
+    that counts nothing)."""
+    logits, cache, counts = decode_step_counted(params, config, cache,
+                                                tokens, pos, attend)
     nxt = _sample(logits, temperature, top_k,
                   _draw_key(key, draw, temperature), top_p)
-    return nxt, cache
+    return nxt, cache, counts
 
 
 @partial(jax.jit, static_argnames=("config", "temperature", "top_k",
@@ -507,8 +523,15 @@ class ContinuousBatchingEngine:
         # rows a chunk of the decode step's cache read holds (0: the whole
         # budget is read); None for a cache by layer kind, read by its
         # model's own rule
-        self._read_chunk = None if cache_by_kind(config) else \
+        self._read_chunk = None if (
+            cache_by_kind(config)
+            and not getattr(config, "reads_cache_by_attend", False)) else \
             read_chunk_rows(token_budget, self._cache["k"].dtype)
+        # what the model's decode step counts on the device, by name, and
+        # the expert layers a step runs
+        self._step_counts = getattr(kind_module(config), "STEP_COUNTS", ())
+        self._expert_layers = getattr(config, "n_expert_layers", 0) \
+            if self._step_counts else 0
         # paged prefix-shared KV pool (serve/kvcache.py); None = sharing
         # OFF, which keeps the admission path byte-identical to the
         # pre-paging engine
@@ -873,11 +896,11 @@ class ContinuousBatchingEngine:
                     np.int32, self.n_slots)
                 read = self._rows_read(attend)
                 ph.enter("tony.engine.decode.dispatch")
-                self._tokens, self._cache = _decode_sample_step(
+                self._tokens, self._cache, counts = _decode_sample_step(
                     self.params, self.config, self._cache, self._tokens,
                     pos, self._key, self._next_draw(), self.temperature,
                     self.top_k, self.top_p, attend=attend)
-                flight = _Flight(self._tokens,
+                flight = _Flight(self._tokens, counts,
                                  [(s, s.handle, s.pos) for s in riders])
                 for slot in riders:
                     slot.pos += 1
@@ -933,6 +956,8 @@ class ContinuousBatchingEngine:
                 self.stats.step_host_s.append(
                     started - self._read_ended_at - self._admit_s)
         nxt_np = np.asarray(jax.device_get(flight.tokens))
+        counted = None if flight.counts is None else \
+            jax.device_get(flight.counts)
         if self._test_decode_delay_s > 0:
             # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
             # decode by a fixed per-step delay — the slow-hop-attribution
@@ -963,6 +988,12 @@ class ContinuousBatchingEngine:
                 len(flight.riders) - len(gaps))
             self.stats.sparse_blocks_attended_total += attended
             self.stats.sparse_context_blocks_total += context
+            if counted is not None:
+                self.stats.moe_layer_steps_total += self._expert_layers
+                for name, n in zip(self._step_counts, counted):
+                    name += "_total"
+                    setattr(self.stats, name, getattr(self.stats, name)
+                            + int(n))
 
     def _admit_pending(self) -> bool:
         admitted = False
@@ -1316,6 +1347,10 @@ class ContinuousBatchingEngine:
             if self._read_chunk is not None:
                 for name in ("cache_rows_read_total",
                              "cache_rows_budget_total"):
+                    snap[name] = getattr(self.stats, name)
+            if self._step_counts:
+                for name in ("moe_layer_steps_total",
+                             *(n + "_total" for n in self._step_counts)):
                     snap[name] = getattr(self.stats, name)
             itl = _percentile(self.stats.itl_s, 0.50)
             if itl is not None:
