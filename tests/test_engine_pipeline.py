@@ -439,3 +439,54 @@ def test_a_stream_keeps_its_greedy_tokens_with_slots_freed_and_refilled_around_i
     assert 0 < stats.cache_rows_read_total <= stats.cache_rows_budget_total
     assert stats.cache_rows_read_total == sum(
         int(-(-n // 16) * 16) for _, attend in seen for n in attend)
+
+
+# -- a slot that does not ride moves no recurrent state (PR 38) --------------
+
+@pytest.mark.parametrize("stepper", ["_step", "step"])
+def test_a_slot_admitted_again_after_a_pause_serves_what_it_would_alone(
+        model, monkeypatch, stepper):
+    """A ends early and its slot sits parked for some steps beside B's
+    stream (for the model with lightning layers: its state is dead and no
+    step moves it any more); C is then admitted into the SAME slot. C's
+    tokens and B's are those of each alone in a fresh engine and of
+    offline `generate`: the pause changes nothing. The state counters,
+    which only a model whose step moves the riders' state alone keeps:
+    every rider of every dispatched step moved its state, and no other
+    slot did."""
+    seen = _recorded_dispatches(monkeypatch)
+    budget, (pa, pb, pc) = model["budget"], model["prompts"]
+    requests = [(_prompt(model, pa, 90), 3), (_prompt(model, pb, 91), 16),
+                (_prompt(model, pc, 92), 8)]
+    engine = _engine(model)
+    step = getattr(engine, stepper)
+    a, b = (engine.submit(p, n) for p, n in requests[:2])
+    while not a.done.is_set():
+        assert step()
+    for _ in range(4):
+        assert step()
+    paused = [(pos, attend) for pos, attend in seen if pos[0] == budget - 1]
+    assert len(paused) >= 4 and not any(att[0] for _, att in paused)
+    c = engine.submit(*requests[2])
+    step()
+    assert engine._slots[0].handle is c and engine._slots[1].handle is b
+    _run(engine, stepper)
+    seen = list(seen)       # the engines below are recorded too
+    alone = _by_hand(model, requests)
+    assert [h.tokens for h in (a, b, c)] == [h.tokens for h in alone]
+    for (prompt, new), h in zip(requests[1:], (b, c)):
+        assert h.tokens == [int(t) for t in gen.generate(
+            model["params"], model["cfg"],
+            jnp.asarray([prompt], jnp.int32), new)[0]]
+    snap = engine.snapshot()
+    if not getattr(model["cfg"], "moves_state_by_riding", False):
+        assert "state_slots_moved_total" not in snap
+        assert "state_slots_total" not in snap
+        return
+    assert snap["state_slots_moved_total"] == (
+        snap["decode_slot_steps_total"]
+        + snap["decode_slot_steps_discarded_total"]) \
+        == sum(int(np.count_nonzero(att)) for _, att in seen) > 0
+    assert snap["state_slots_total"] == 2 * snap["decode_steps_total"] \
+        == 2 * len(seen)
+    assert snap["state_slots_moved_total"] < snap["state_slots_total"]

@@ -491,3 +491,88 @@ def test_decode_read_kernel_matches_the_jnp_body_and_reads_no_row_past_a_length(
     np.testing.assert_allclose(np.asarray(again, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+RIDING = {"all-ride": [1, 1, 1, 1, 1], "one-rides": [0, 0, 1, 0, 0],
+          "first-parked": [0, 1, 1, 1, 1], "last-parked": [1, 1, 1, 1, 0],
+          "alternating": [1, 0, 1, 0, 1], "none-rides": [0, 0, 0, 0, 0]}
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32-state", "bf16-state"])
+@pytest.mark.parametrize("mask", list(RIDING))
+def test_lightning_step_moves_the_state_of_riding_slots_only(mask,
+                                                             state_dtype):
+    """`tony_lightning_step` (interpret mode) against the jnp body under a
+    riding mask: the riders' output rows and state slabs are the body's,
+    a slot that does not ride keeps its slabs BIT-equal (noise, and huge
+    values that a decay would have moved) and gets a row of zeros, and no
+    other layer's slice is touched."""
+    from tony_tpu.ops import lightning as L
+
+    b, h, d, n_layers = 5, 4, 16, 3
+    riding = np.asarray(RIDING[mask], bool)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v = (jax.random.normal(key, (b, h, d), jnp.float32)
+               for key in ks[:3])
+    state = jax.random.normal(ks[3], (n_layers, b, h, d, d), jnp.float32)
+    state = state.at[:, 1].multiply(1e30).astype(state_dtype)
+    decay = jnp.asarray([0.9, 0.5, 0.99, 0.3], jnp.float32)
+    layer = jnp.asarray([1], jnp.int32)
+    args = (layer, *L.compact_riders(jnp.asarray(riding)), decay, q, k, v,
+            state)
+    o, new = L._step_pallas(*args, scale=0.25, interpret=True)
+    want_o, want = L._step_jnp(*args, scale=0.25)
+    assert new.dtype == state.dtype and o.dtype == jnp.float32
+    assert bool(jnp.all(jnp.isfinite(o[~riding]))) and bool(
+        jnp.all(o[~riding] == 0))
+    assert bool(jnp.all(new[:, ~riding] == state[:, ~riding]))
+    assert bool(jnp.all(new[(0, 2), :] == state[(0, 2), :]))
+    assert bool(jnp.all(want[:, ~riding] == state[:, ~riding]))
+    calm = riding & (np.arange(b) != 1)         # slot 1 holds 1e30s
+    np.testing.assert_allclose(o[calm], want_o[calm], atol=1e-5, rtol=1e-5)
+    tol = 1e-5 if state_dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(new[1, calm], np.float32),
+                               np.asarray(want[1, calm], np.float32),
+                               atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(new[1, 1], np.float32),
+                               np.asarray(want[1, 1], np.float32),
+                               atol=1e20, rtol=tol)
+    if riding.any():
+        assert not bool(jnp.all(new[1, riding] == state[1, riding]))
+    # the mask absent is every slot riding
+    o_all, new_all = L.lightning_step(layer, decay, q, k, v, state, 0.25)
+    full = L._step_jnp(layer, *L.compact_riders(jnp.ones((b,), bool)),
+                       *args[3:], scale=0.25)
+    assert bool(jnp.all(o_all == full[0])) and bool(
+        jnp.all(new_all == full[1]))
+
+
+def test_sparse_read_kernel_dereferences_no_block_of_a_slot_that_reads_none():
+    """`tony_sparse_read` (interpret mode) with a row of `counts` 0, as
+    models/sala.py hands it a slot that does not ride: that slot's token
+    attends to its own row alone (its output is its V row, finite) though
+    its block ids point anywhere and its cache rows are NaN; the other
+    slot's result is the jnp body's."""
+    from tony_tpu.ops import sparse_attention as sa
+
+    b, g, r, d, block, nb, sm, n_layers = 2, 2, 2, 16, 8, 16, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    q = jax.random.normal(ks[0], (b, g, r, d), jnp.float32)
+    k_new = jax.random.normal(ks[1], (b, g, d), jnp.float32)
+    v_new = jax.random.normal(ks[2], (b, g, d), jnp.float32)
+    shape = (n_layers, b, g, nb * block, d)
+    k_cache = jax.random.normal(ks[3], shape).at[:, 1].set(jnp.nan)
+    v_cache = jax.random.normal(ks[4], shape).at[:, 1].set(jnp.nan)
+    ids = jnp.stack([jnp.broadcast_to(jnp.arange(sm) * 2, (g, sm)),
+                     jnp.full((g, sm), nb - 1)]).astype(jnp.int32)
+    counts = jnp.asarray([[5, 3], [0, 0]], jnp.int32)
+    lens = jnp.asarray([75, nb * block - 1], jnp.int32)
+    args = (jnp.asarray([1], jnp.int32), ids, counts, lens, q, k_new, v_new,
+            k_cache, v_cache)
+    got = sa._decode_attend_pallas(*args, block=block, interpret=True)
+    want = sa._decode_attend_jnp(*args, block=block)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        got[1], jnp.broadcast_to(v_new[1][:, None, :], (g, r, d)), atol=1e-6)

@@ -191,18 +191,24 @@ def test_compressed_keys_after_decode_are_those_of_the_rows(params, prompts,
                                 == empty[name][:, slot, :, :rows])), name
 
 
+def _two_filled_slots(params, prompt):
+    """A cache of two slots, both holding `prompt` as its prefill left it."""
+    _, pc = jax.jit(lambda t: sala.prefill(params, t, CONFIG, BUDGET))(
+        jnp.asarray([prompt], jnp.int32))
+    cache = sala.empty_cache(CONFIG, 2, BUDGET)
+    for slot in (0, 1):
+        cache = {k: jax.lax.dynamic_update_slice_in_dim(
+            cache[k], pc[k], slot, axis=1) for k in cache}
+    return cache
+
+
 def test_untouched_slots_stay_bit_equal(params, prompts):
     """A decode step changes only what it must of the slots it serves:
     every K/V row but the new one, every compressed key but a completed
     one, bit-equal before and after."""
     prompt = prompts["above"]
     with jax.default_matmul_precision("highest"):
-        _, pc = jax.jit(lambda t: sala.prefill(params, t, CONFIG, BUDGET))(
-            jnp.asarray([prompt], jnp.int32))
-        cache = sala.empty_cache(CONFIG, 2, BUDGET)
-        for slot in (0, 1):
-            cache = {k: jax.lax.dynamic_update_slice_in_dim(
-                cache[k], pc[k], slot, axis=1) for k in cache}
+        cache = _two_filled_slots(params, prompt)
         n = len(prompt)
         _, after = jax.jit(lambda c: sala.decode_step(
             params, CONFIG, c, jnp.asarray([7, 9], jnp.int32),
@@ -218,6 +224,46 @@ def test_untouched_slots_stay_bit_equal(params, prompts):
     assert bool(jnp.all(after["ck"][:, :, :, keep]
                         == cache["ck"][:, :, :, keep]))
     assert not bool(jnp.all(after["state"] == cache["state"]))
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernels"])
+@pytest.mark.parametrize("parked", [0, 1])
+def test_a_slot_that_does_not_ride_moves_no_state(params, prompts,
+                                                  monkeypatch, path, parked):
+    """Two filled slots, one of which the engine's `attend` array says
+    does not ride: the rider's logits, new rows, compressed key and state
+    are those of the step in which both ride, bit for bit; the other's
+    lightning state is bit-equal before and after (and its logits finite:
+    it attended to its own row). No `attend` is every slot riding. The
+    plain bodies, and the kernels interpreted."""
+    if path == "kernels":
+        monkeypatch.setattr(lightning, "_INTERPRET", True)
+        monkeypatch.setattr(sa, "_INTERPRET", True)
+    prompt = prompts["above"]
+    n, rider = len(prompt), 1 - parked
+    with jax.default_matmul_precision("highest"):
+        cache = _two_filled_slots(params, prompt)
+        token = jnp.asarray([7, 9], jnp.int32)
+        pos = jnp.asarray([n, n], jnp.int32)
+        step = jax.jit(lambda c, attend: sala.decode_step(
+            params, CONFIG, c, token, pos, attend))
+        both, full = step(dict(cache), None)
+        again, same = step(dict(cache), pos)
+        attend = pos.at[parked].set(0)
+        logits, after = step(dict(cache), attend)
+    assert bool(jnp.all(both == again))
+    assert all(bool(jnp.all(full[k] == same[k])) for k in full)
+    assert bool(jnp.all(logits[rider] == both[rider]))
+    for name in after:
+        assert bool(jnp.all(after[name][:, rider] == full[name][:, rider])), \
+            name
+    assert bool(jnp.all(after["state"][:, parked]
+                        == cache["state"][:, parked]))
+    assert not bool(jnp.all(full["state"][:, parked]
+                            == cache["state"][:, parked]))
+    assert not bool(jnp.all(after["state"][:, rider]
+                            == cache["state"][:, rider]))
+    assert bool(jnp.all(jnp.isfinite(logits)))
 
 
 @pytest.mark.parametrize("n", [5, 64, 100, 257])

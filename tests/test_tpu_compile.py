@@ -358,8 +358,11 @@ def _sala_cell(one_chip):
     return config, params, cache, cache_bytes, slots, budget
 
 
-def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip):
-    """One decode step of the hybrid model at the cell's shapes: the K/V
+@pytest.mark.parametrize("mask", ["engine-mask", "every-slot-rides"])
+def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip, mask):
+    """One decode step of the hybrid model at the cell's shapes, with the
+    engine's riding mask (`attend`, which tells `tony_lightning_step`
+    whose state to move) and without it (offline `generate`): the K/V
     rows, the compressed keys and the lightning states (2.9 GB together)
     are aliased in and out, nothing cache-sized is copied (no K/V slab,
     no copy of the 0.4 GB of states, no transposed stack of weights: each
@@ -375,7 +378,8 @@ def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip):
         params, config, cache, _sds((slots,), jnp.int32, one_chip),
         _sds((slots,), jnp.int32, one_chip),
         _sds((2,), jnp.uint32, one_chip), _sds((), jnp.int32, one_chip),
-        0.0, 0, 1.0).compile()
+        0.0, 0, 1.0, attend=_sds((slots,), jnp.int32, one_chip)
+        if mask == "engine-mask" else None).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= cache_bytes

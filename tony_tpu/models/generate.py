@@ -342,7 +342,7 @@ def decode_step(params: Params, config: LlamaConfig,
     the continuous-batching shape (serve/engine.py), where every batch
     row is an independent request slot at its own sequence position.
     `attend` as in `window_logits`; a model whose cache is by layer kind
-    is handed it too, and reads by its own rule.
+    is handed it too, as its riding mask (0: the slot does not ride).
     Returns (logits (B, V), updated cache)."""
     return decode_step_counted(params, config, cache, token, pos,
                                attend)[:2]

@@ -27,10 +27,10 @@ compiled step holds each kind's block once whatever the depth.
 keys for the sparse layers, a state for the lightning layers. Every leaf
 has the slot on axis 1, which is all the engine needs to know to admit
 into a slot. `prefill` gives the leaves one prompt writes, `decode_step`
-advances every slot by one token: the sparse layers read the cache in
-place and hand their new rows out to be written after the layer loop
-(models/generate.py `write_cache_rows`), the lightning layers update
-their slice of the state in place.
+advances every slot that rides by one token: the sparse layers read the
+cache in place and hand their new rows out to be written after the layer
+loop (models/generate.py `write_cache_rows`), the lightning layers update
+the riders' slabs of their slice of the state in place.
 
 There is no training path: the model is served, not trained.
 """
@@ -47,7 +47,9 @@ from jax import lax
 
 from tony_tpu.models.llama import swiglu_mlp
 from tony_tpu.ops.attention import flash_attention
-from tony_tpu.ops.lightning import lightning_chunk, lightning_step
+from tony_tpu.ops.lightning import (
+    compact_riders, lightning_chunk, lightning_step,
+)
 from tony_tpu.ops.rmsnorm import rms_norm
 from tony_tpu.ops.rope import rope_frequencies
 from tony_tpu.ops.sparse_attention import (
@@ -127,6 +129,13 @@ class SalaConfig:
     def has_recurrent_state(self) -> bool:
         """A slot's cache is not a function of its rows alone: a prefix's
         state cannot be shared page by page, nor its rows migrated."""
+        return True
+
+    @property
+    def moves_state_by_riding(self) -> bool:
+        """A decode step reads and rewrites the lightning state of the
+        slots that ride and of no other (the engine's
+        `state_slots_moved_total`)."""
         return True
 
     @property
@@ -486,7 +495,8 @@ def _completed_window(tail, k_new, pos, sp: SparseSpec):
             mean.astype(k_new.dtype))
 
 
-def _sparse_decode(x, layer: Params, p, cache, pos, config: SalaConfig):
+def _sparse_decode(x, layer: Params, p, cache, pos, riding,
+                   config: SalaConfig):
     b = x.shape[0]
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     sp = config.sparse
@@ -504,6 +514,9 @@ def _sparse_decode(x, layer: Params, p, cache, pos, config: SalaConfig):
         new = _completed_window(tail, k, pos, sp)
         ck = lax.dynamic_index_in_dim(cache["ck"], p, 0, keepdims=False)
         ids, counts = select_decode(qg, ck, pos, sp, new)
+        # a slot that does not ride reads none of the blocks selected for
+        # it: its token attends to its own row alone
+        counts = jnp.where(riding[:, None], counts, 0)
     attn = sparse_decode_attention(jnp.reshape(p, (1,)), ids, counts, pos,
                                    qg, k, v, cache["k"], cache["v"], sp)
     attn = attn.reshape(b, nh * hd) * jax.nn.sigmoid(h @ layer["w_og"])
@@ -513,7 +526,7 @@ def _sparse_decode(x, layer: Params, p, cache, pos, config: SalaConfig):
 
 
 def _lightning_decode(x, state, layer: Params, decay, index, cos, sin, pos,
-                      config: SalaConfig):
+                      riders, config: SalaConfig):
     b = x.shape[0]
     lh, hd = config.lightning_heads, config.head_dim
     h = _norm(x, layer["attn_norm"], config)
@@ -538,7 +551,7 @@ def _lightning_decode(x, state, layer: Params, decay, index, cos, sin, pos,
     # token's state is the same whichever path made it
     o, state = lightning_step(jnp.reshape(index, (1,)), decay,
                               q.astype(config.dtype), k.astype(config.dtype),
-                              v, state, hd ** -0.5)
+                              v, state, hd ** -0.5, riders)
     o = _head_norm(o, layer["o_norm"], config.norm_eps).astype(config.dtype)
     o = o.reshape(b, lh * hd) * jax.nn.sigmoid(h @ layer["w_og"])
     return _finish_layer(x, o @ layer["wo"], layer, config), state
@@ -549,10 +562,15 @@ def decode_step(params: Params, config: SalaConfig,
                 pos: jax.Array, attend=None
                 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """One token a slot. token (B,) int32 at positions pos (B,) (the rows
-    each slot's cache holds); `attend` is the engine's riding mask, which
-    these layers take no notice of (they read by their own rule). Returns (logits (B, V), the cache with the
-    token's K/V rows, any compressed key it completed and the advanced
-    states written)."""
+    each slot's cache holds); `attend` (B,) is the engine's riding mask: 0
+    for a slot that does not ride (absent: every slot rides). Such a slot
+    moves no lightning state (its slabs stay bit-equal) and reads no block
+    of the sparse layers' cache; it still writes its token's K/V, `ck` and
+    `tail` rows where it is parked, and its logits are thrown away. What
+    a riding slot attends to is these layers' own rule, not `attend`'s
+    length. Returns (logits (B, V), the cache with the token's K/V rows,
+    any compressed key it completed and the riders' advanced states
+    written)."""
     from tony_tpu.models.generate import write_cache_rows
 
     sp = config.sparse
@@ -561,6 +579,8 @@ def decode_step(params: Params, config: SalaConfig,
     cos, sin = _rope_tables(config, budget)
     x = jnp.take(params["embed"], token, axis=0).astype(STREAM) \
         * config.scale_emb
+    riding = jnp.ones(token.shape, bool) if attend is None else attend > 0
+    riders = compact_riders(riding)     # once, not a lightning layer
     decays = jnp.exp(-jnp.asarray(lightning_slopes(config)))
 
     # a period's lightning layers are indexed out of the whole stack by
@@ -573,7 +593,7 @@ def decode_step(params: Params, config: SalaConfig,
     def period(carry, xs):
         x, state = carry
         sparse, p = xs
-        x, rows = _sparse_decode(x, sparse, p, cache, pos, config)
+        x, rows = _sparse_decode(x, sparse, p, cache, pos, riding, config)
 
         def one(carry, i):
             x, state = carry
@@ -583,7 +603,7 @@ def decode_step(params: Params, config: SalaConfig,
             x, state = _lightning_decode(
                 x, state, layer,
                 lax.dynamic_index_in_dim(decays, index, 0, False), index,
-                cos, sin, pos, config)
+                cos, sin, pos, riders, config)
             return (x, state), None
 
         (x, state), _ = lax.scan(one, (x, state),

@@ -19,7 +19,11 @@ recurrence:
 - `lightning_step` (decode, one token a slot): the recurrence itself. The
   kernel `tony_lightning_step` updates one layer's slice of the whole
   state array in place (aliased in and out), so a decode step carries the
-  array through its layer loop without ever copying it.
+  array through its layer loop without ever copying it; of that slice it
+  reads and rewrites the slabs of the slots that ride, and no other: a
+  slot no stream holds costs an empty grid step (2 MB each way a layer
+  at 32 heads of 128, of a replica with 2-5 riders of 16 slots: PERF.md,
+  PR 38).
 
 Dispatch is by platform at lowering time, as in ops/attention.py.
 """
@@ -35,7 +39,6 @@ from jax import lax
 from tony_tpu.ops.attention import _INTERPRET
 
 CHUNK = 256         # tokens a chunk: two MXU tiles of quadratic work
-STEP_HEADS = 8      # heads one program of tony_lightning_step updates
 
 
 # ---------------------------------------------------------------------------
@@ -167,91 +170,136 @@ def lightning_chunk(q: jax.Array, k: jax.Array, v: jax.Array,
 # decode: one step of the recurrence, the state updated in place
 # ---------------------------------------------------------------------------
 
-def _step_jnp(layer, decay, q, k, v, state, *, scale: float):
-    s = lax.dynamic_index_in_dim(state, layer[0], 0, keepdims=False)
-    s = decay[None, :, None, None] * s.astype(jnp.float32) \
+def compact_riders(riding: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(slots (B,) int32, count (1,) int32) of a riding mask (B,): the
+    slots that ride in order, then the last of them again and again (slot
+    B - 1 where none rides), so that a program of `tony_lightning_step`
+    past the last rider names the block the one before it named and moves
+    nothing. Made once a decode step, outside its layer loop."""
+    b = riding.shape[0]
+    seen = jnp.cumsum(riding.astype(jnp.int32))
+    count = seen[-1]
+    i = jnp.arange(b, dtype=jnp.int32)
+    # the (i + 1)-th rider is the slot before which as many slots have
+    # seen at most i riders
+    slots = jnp.sum(seen[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
+    last = jnp.take(slots, jnp.maximum(count - 1, 0))
+    slots = jnp.minimum(jnp.where(i < count, slots, last), b - 1)
+    return slots, jnp.reshape(count, (1,))
+
+
+def _step_jnp(layer, slots, count, decay, q, k, v, state, *, scale: float):
+    riding = jnp.zeros(q.shape[:1], bool).at[slots].set(True) \
+        & (count[0] > 0)
+    old = lax.dynamic_index_in_dim(state, layer[0], 0, keepdims=False)
+    s = decay[None, :, None, None] * old.astype(jnp.float32) \
         + k[..., :, None] * v[..., None, :]
     o = jnp.einsum("bhd,bhde->bhe", q * scale, s,
                    precision=lax.Precision.HIGHEST)
-    return o, lax.dynamic_update_index_in_dim(
-        state, s.astype(state.dtype), layer[0], 0)
+    s = jnp.where(riding[:, None, None, None], s.astype(state.dtype), old)
+    return (jnp.where(riding[:, None, None], o, 0.0),
+            lax.dynamic_update_index_in_dim(state, s, layer[0], 0))
 
 
-def _step_kernel(layer_ref, decay_ref, q_ref, k_ref, v_ref, s_ref, o_ref,
-                 s_out_ref, *, heads: int, scale: float):
+def _step_kernel(layer_ref, slots_ref, count_ref, decay_ref, q_ref, k_ref,
+                 v_ref, s_ref, o_ref, s_out_ref, *, scale: float):
     from jax.experimental import pallas as pl
 
-    hb = pl.program_id(1)
-    d = q_ref.shape[-1]
-    # q and k arrive as rows (the layout their projections leave them in)
-    # and are stood up as columns by products with the identity: exact in
-    # float32, a value being the sum of three bfloat16 terms
-    eye = (lax.broadcasted_iota(jnp.int32, (d, d), 0)
-           == lax.broadcasted_iota(jnp.int32, (d, d), 1)).astype(
-               jnp.bfloat16)
+    i, count = pl.program_id(0), count_ref[0]
+    heads, d = q_ref.shape[1:]
 
-    def columns(x):                                 # (heads, d) -> (d, heads)
-        out = jnp.zeros((d, x.shape[0]), jnp.float32)
-        for _ in range(3):
-            term = x.astype(jnp.bfloat16)
-            out = out + lax.dot_general(
-                eye, term, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            x = x - term.astype(jnp.float32)
-        return out
+    @pl.when(i == 0)
+    def _():        # a slot that does not ride: a finite row, no state read
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    q_cols = columns(q_ref[0]) * scale
-    k_cols = columns(k_ref[0])
-    for i in range(heads):
-        lam = decay_ref[hb * heads + i]
-        s = lam * s_ref[0, 0, i].astype(jnp.float32) \
-            + k_cols[:, i:i + 1] * v_ref[0, i:i + 1, :]
-        s_out_ref[0, 0, i] = s.astype(s_out_ref.dtype)
-        o_ref[0, i:i + 1, :] = jnp.sum(q_cols[:, i:i + 1] * s, axis=0,
-                                       keepdims=True)
+    @pl.when((i == 0) & (count == 0))
+    def _():        # no rider at all: the one block named goes back as it came
+        s_out_ref[...] = s_ref[...]
+
+    @pl.when(i < count)
+    def _():
+        # q and k arrive as rows (the layout their projections leave them
+        # in) and are stood up as columns by products with the identity:
+        # exact in float32, a value being the sum of three bfloat16 terms
+        eye = (lax.broadcasted_iota(jnp.int32, (d, d), 0)
+               == lax.broadcasted_iota(jnp.int32, (d, d), 1)).astype(
+                   jnp.bfloat16)
+
+        def columns(x):                             # (heads, d) -> (d, heads)
+            out = jnp.zeros((d, x.shape[0]), jnp.float32)
+            for _ in range(3):
+                term = x.astype(jnp.bfloat16)
+                out = out + lax.dot_general(
+                    eye, term, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                x = x - term.astype(jnp.float32)
+            return out
+
+        slot = slots_ref[i]
+        q_cols = columns(q_ref[0]) * scale
+        k_cols = columns(k_ref[0])
+        for h in range(heads):
+            s = decay_ref[h] * s_ref[0, 0, h].astype(jnp.float32) \
+                + k_cols[:, h:h + 1] * v_ref[0, h:h + 1, :]
+            s_out_ref[0, 0, h] = s.astype(s_out_ref.dtype)
+            o_ref[slot, h:h + 1, :] = jnp.sum(q_cols[:, h:h + 1] * s, axis=0,
+                                              keepdims=True)
 
 
-def _step_pallas(layer, decay, q, k, v, state, *, scale: float,
-                 interpret: bool = False):
+def _step_pallas(layer, slots, count, decay, q, k, v, state, *,
+                 scale: float, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    heads = STEP_HEADS if h % STEP_HEADS == 0 else h
-    row = pl.BlockSpec((1, heads, d), lambda i, j, *_: (i, j, 0))
-    slab = pl.BlockSpec((1, 1, heads, d, d),
-                        lambda i, j, layer_ref, _: (layer_ref[0], i, j, 0, 0))
+    # one program a slot that rides, all its heads (2 MB of float32 state
+    # at 32 heads of 128, in and out, each double-buffered); the programs
+    # past the last rider are empty grid steps
+    row = pl.BlockSpec((1, h, d), lambda i, _, slots, *__: (slots[i], 0, 0))
+    slab = pl.BlockSpec(
+        (1, 1, h, d, d),
+        lambda i, layer, slots, *_: (layer[0], slots[i], 0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads, scale=scale),
+        functools.partial(_step_kernel, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h // heads),
+            num_scalar_prefetch=4,
+            grid=(b,),
             in_specs=[row, row, row, slab],
-            out_specs=[row, slab],
+            # every slot's output row stays in VMEM for the whole call:
+            # the rows of slots no program visits are written too (zeros)
+            out_specs=[pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0)),
+                       slab],
         ),
         out_shape=[jax.ShapeDtypeStruct((b, h, d), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operand 5 (after the two prefetched scalars and q, k, v) is the
-        # state: the same buffer comes out
-        input_output_aliases={5: 1},
+        # operand 7 (after the four prefetched scalars and q, k, v) is the
+        # state: the same buffer comes out, and a slab no program names is
+        # neither read nor written
+        input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="tony_lightning_step",
-    )(layer, decay, q, k, v, state)
+    )(layer, slots, count, decay, q, k, v, state)
 
 
 def lightning_step(layer: jax.Array, decay: jax.Array, q: jax.Array,
                    k: jax.Array, v: jax.Array, state: jax.Array,
-                   scale: float) -> tuple[jax.Array, jax.Array]:
+                   scale: float, riders=None) -> tuple[jax.Array, jax.Array]:
     """One token a slot through one lightning layer. `state`
     (L, B, H, d, d), float32 or kept rounded to bfloat16, is the WHOLE
     state array of the lightning layers, of which slice `layer` (a (1,)
-    int32) is read and rewritten;
+    int32) is read and rewritten, and of it only the slabs of the slots
+    that ride: `riders` = `compact_riders(mask)` (absent: every slot
+    rides). A slot that does not ride moves no state (its slabs stay
+    bit-equal) and its output row is zeros.
     decay (H,) = exp(-slope); q, k, v (B, H, d); q is multiplied by `scale`.
     Returns (o (B, H, d) float32, the state array)."""
-    args = (layer, decay.astype(jnp.float32), q.astype(jnp.float32),
-            k.astype(jnp.float32), v.astype(jnp.float32), state)
+    if riders is None:
+        riders = compact_riders(jnp.ones(q.shape[:1], bool))
+    args = (layer, *riders, decay.astype(jnp.float32),
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), state)
     if _INTERPRET:
         return _step_pallas(*args, scale=scale, interpret=True)
     return lax.platform_dependent(

@@ -320,6 +320,14 @@ class EngineStats:
     # still reads
     cache_rows_read_total: int = 0
     cache_rows_budget_total: int = 0
+    # a model whose decode step reads and rewrites the recurrent state of
+    # the slots that ride and of no other (config.moves_state_by_riding:
+    # models/sala.py): slots whose state a step moved (those its `attend`
+    # array names, every layer's slabs of each) and slots it was run
+    # over, summed over the decode steps dispatched — their ratio is the
+    # share of the state array a step still moves. Reckoned on the host
+    state_slots_moved_total: int = 0
+    state_slots_total: int = 0
     # a model with expert layers that counts on the device
     # (models/lfm2.py STEP_COUNTS): expert layers run summed over the
     # decode steps read (the layers a step has, reckoned here), and, READ
@@ -527,6 +535,9 @@ class ContinuousBatchingEngine:
             cache_by_kind(config)
             and not getattr(config, "reads_cache_by_attend", False)) else \
             read_chunk_rows(token_budget, self._cache["k"].dtype)
+        # whether the decode step moves only the riders' recurrent state
+        self._state_by_riding = getattr(config, "moves_state_by_riding",
+                                        False)
         # what the model's decode step counts on the device, by name, and
         # the expert layers a step runs
         self._step_counts = getattr(kind_module(config), "STEP_COUNTS", ())
@@ -837,16 +848,19 @@ class ContinuousBatchingEngine:
         occupant until that occupant's own writes cover them. The late
         step wrote one row inside the old stream's budget, as does the
         step after a stream's last by length, in which the slot does not
-        ride and stays at its next row; for a model with recurrent state
-        it also updated the slot's `state`, `tail` and `ck` leaves, as
-        every step does for a parked slot. The slot's next admission is
-        dispatched after it, so device order puts the admission's writes
-        last: `shared=False` replaces the slot's whole row of every leaf,
-        the recurrent state included; `shared=True` (K/V leaves only)
-        gathers pages into rows [0, start) and prefills [start, prompt),
-        which are also all that `_seal_prefix` copies out; rows past the
-        prompt stay masked until the new stream's decode reaches them,
-        each written before it is read.
+        ride and stays at its next row; for a model whose cache is by
+        layer kind it also wrote the slot's `tail` and `ck` rows (or its
+        `conv` state), as every step does for a parked slot, and the late
+        step, in which the slot still rode, advanced its lightning
+        `state` (a step moves no state of a slot that does not ride:
+        models/sala.py). The slot's next admission is dispatched after
+        it, so device order puts the admission's writes last:
+        `shared=False` replaces the slot's whole row of every leaf, the
+        recurrent state included; `shared=True` (K/V leaves only) gathers
+        pages into rows [0, start) and prefills [start, prompt), which
+        are also all that `_seal_prefix` copies out; rows past the prompt
+        stay masked until the new stream's decode reaches them, each
+        written before it is read.
 
         A step's tokens are recorded when they are read and their callers
         woken a little later: before the next admission, else once the
@@ -895,6 +909,8 @@ class ContinuousBatchingEngine:
                     (s.pos if s.rides else 0 for s in self._slots),
                     np.int32, self.n_slots)
                 read = self._rows_read(attend)
+                moved = int(np.count_nonzero(attend)) \
+                    if self._state_by_riding else None
                 ph.enter("tony.engine.decode.dispatch")
                 self._tokens, self._cache, counts = _decode_sample_step(
                     self.params, self.config, self._cache, self._tokens,
@@ -912,6 +928,9 @@ class ContinuousBatchingEngine:
                         self.stats.cache_rows_read_total += read
                         self.stats.cache_rows_budget_total += (
                             self.n_slots * self.token_budget)
+                    if moved is not None:
+                        self.stats.state_slots_moved_total += moved
+                        self.stats.state_slots_total += self.n_slots
                 if land:
                     landing.append(flight)
                 else:
@@ -1347,6 +1366,9 @@ class ContinuousBatchingEngine:
             if self._read_chunk is not None:
                 for name in ("cache_rows_read_total",
                              "cache_rows_budget_total"):
+                    snap[name] = getattr(self.stats, name)
+            if self._state_by_riding:
+                for name in ("state_slots_moved_total", "state_slots_total"):
                     snap[name] = getattr(self.stats, name)
             if self._step_counts:
                 for name in ("moe_layer_steps_total",
