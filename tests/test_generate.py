@@ -351,3 +351,37 @@ def test_decode_step_at_staggered_positions_against_the_forward(kind, family):
         else:
             np.testing.assert_allclose(
                 got, ref, atol=1e-6, rtol=2 ** -7 if kind == "bf16" else 1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("b,w", [(4, 1), (1, 37)], ids=["decode-4x1",
+                                                        "admission-1x37"])
+def test_serving_projection_is_the_trainers_bit_for_bit(b, w, dtype):
+    """`qkv_proj(split_on_result=True)`, what `window_logits`, `prefill` and
+    `kvcache.prefill_suffix` call, against the trainer's `qkv_proj`: the
+    same three einsums with a barrier on their results, so the same q, k
+    and v to the last bit, jitted as the serving programs are — at a decode
+    step's shape and at an admission's. (On a TPU the barrier also rounds
+    the Q and K products to the weights' type before RoPE, as the source
+    says, where the folded matmul kept them in float32: PERF.md, PR 42.)"""
+    from dataclasses import replace
+
+    from tony_tpu.models.llama import qkv_proj
+
+    cfg = get_config("tiny")
+    if dtype == "bf16":
+        cfg = replace(cfg, dtype=jnp.bfloat16)
+    layer0 = jax.tree.map(lambda a: a[0],
+                          llama_init(cfg, jax.random.PRNGKey(0))["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(1), (b, w, cfg.dim),
+                          jnp.float32).astype(cfg.dtype)
+    want = jax.jit(lambda h, l: qkv_proj(h, l, cfg))(h, layer0)
+    got = jax.jit(lambda h, l: qkv_proj(h, l, cfg, split_on_result=True))(
+        h, layer0)
+    shapes = [(b, n, w, cfg.head_dim)
+              for n in (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)]
+    assert [g.shape for g in got] == shapes
+    for g, r in zip(got, want):
+        assert g.dtype == r.dtype == cfg.dtype
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      np.asarray(r.astype(jnp.float32)))

@@ -187,15 +187,10 @@ def test_prefill_and_decode_step_compile_for_1b_proxy(one_chip):
                 < HBM_USABLE)
 
 
-def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
+def _results_outside_fusions(hlo: str, shaped: re.Pattern) -> list:
     """Instructions of the scheduled program, outside any fused
-    computation, whose result has a cache slab's dimensions (in any type,
-    with any leading 1s): each one is a pass over a whole layer of the
-    cache in HBM. Parameters and views (get-tuple-element, bitcast) move
-    nothing and do not count; nor does an int8 cache's slice of a layer's
-    row scales (last dimension 1: 1/32 of the slab's bytes)."""
-    dims = ",".join(str(d) for d in slab)
-    shaped = re.compile(r" = \w+\[(1,)*%s\]" % dims)
+    computation, whose result `shaped` matches. Parameters and views
+    (get-tuple-element, bitcast) move nothing and do not count."""
     view = re.compile(r" = \S+ (parameter|get-tuple-element|bitcast)\(")
     found, fused = [], False
     for line in hlo.splitlines():
@@ -205,6 +200,59 @@ def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
         elif not fused and shaped.search(line) and not view.search(line):
             found.append(line.strip()[:160])
     return found
+
+
+def _slab_results_outside_fusions(hlo: str, slab: tuple) -> list:
+    """Results outside fusions with a cache slab's dimensions (in any
+    type, with any leading 1s): each one is a pass over a whole layer of
+    the cache in HBM. An int8 cache's slice of a layer's row scales (last
+    dimension 1: 1/32 of the slab's bytes) does not count."""
+    dims = ",".join(str(d) for d in slab)
+    return _results_outside_fusions(
+        hlo, re.compile(r" = \w+\[(1,)*%s\]" % dims))
+
+
+def _weight_results_outside_fusions(hlo: str, params) -> list:
+    """Results outside fusions with the dimensions of a layer's weight
+    matrix — the last two of any stacked leaf of `params`, in either order
+    and in any layout, behind any leading dimensions (one layer sliced out,
+    or the whole stack): each one moves a weight before a matmul uses it.
+    A matmul that reads its layer in place has no such result: the slice
+    is inside its own fusion."""
+    matrices = {l.shape[-2:] for l in jax.tree.leaves(params)
+                if len(l.shape) >= 3 and min(l.shape[-2:]) >= 256}
+    dims = sorted({"%d,%d" % d for m in matrices for d in (m, m[::-1])})
+    return _results_outside_fusions(
+        hlo, re.compile(r" = \w+\[(\d+,)*(%s)\]" % "|".join(dims)))
+
+
+def test_the_weight_helper_finds_the_q_projections_slice_and_copy():
+    """The helper on the lines PR 42 took out of the chat-steady decode
+    program (text of the parent's compile, shortened): the slice of `wq`'s
+    layer out of the stack and its re-laid-out copy are found, 0.71 and
+    0.55 ms a step on the chip; the bitcast, the matmul over the copy and
+    a matmul that slices inside its own fusion are not."""
+    hlo = """\
+%fused_computation.7 (param_0: bf16[16,4096,4096], param_1: s32[]) -> bf16[32,4096] {
+  %dynamic-slice.3 = bf16[1,4096,4096]{2,1,0} dynamic-slice(%param_0, %param_1)
+  ROOT %convolution.35 = bf16[32,4096]{1,0} convolution(%p, %dynamic-slice.3)
+}
+%wide.region_0.clone (wide.arg: (s32[], bf16[16,4096,4096])) -> (s32[]) {
+  %get-tuple-element.597 = bf16[16,4096,4096]{2,1,0} get-tuple-element(%wide.arg), index=1
+  %constant_dynamic-slice_fusion.14 = bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.597, %i), kind=kLoop
+  %copy.50 = bf16[1,4096,4096]{1,2,0:T(8,128)(2,1)S(1)} copy(%constant_dynamic-slice_fusion.14)
+  %bitcast.145 = bf16[32,128,4096]{2,1,0} bitcast(%copy.50)
+  %convert_bitcast_fusion.2 = f32[32,32,1,128]{3,0,1,2} fusion(%bitcast.145, %tony_rmsnorm.12), kind=kOutput
+  %copy.53 = bf16[1,1024,4096]{2,1,0} copy(%k)
+  %fusion.99 = bf16[32,1,4096]{2,0,1} fusion(%tony_rmsnorm.12, %get-tuple-element.597, %i), kind=kOutput
+}
+"""
+    params = {"layers": {"wq": _sds((16, 4096, 4096), jnp.bfloat16, None),
+                         "wk": _sds((16, 4096, 1024), jnp.bfloat16, None),
+                         "attn_norm": _sds((16, 4096), jnp.float32, None)}}
+    found = _weight_results_outside_fusions(hlo, params)
+    assert [line.split(" = ")[0] for line in found] == [
+        "%constant_dynamic-slice_fusion.14", "%copy.50", "%copy.53"]
 
 
 def _chat_steady_cell(one_chip, quant):
@@ -290,6 +338,40 @@ def test_decode_step_reads_the_cache_through_the_length_aware_kernel(
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_USABLE
     assert _slab_results_outside_fusions(text, slab) == []
+
+
+@pytest.mark.parametrize("program", ["decode", "admission-384"])
+def test_serving_programs_read_each_layers_weights_in_place(one_chip,
+                                                            program):
+    """The two programs of the chat-steady cell, at its shapes: the decode
+    step as the engine calls it and the admission of the mix's median
+    prompt. No result outside a fusion has a layer's weight shape: every
+    projection is one matmul that slices its layer out of the stack inside
+    its own fusion. Before PR 42 the split into heads was folded into the
+    Q and K matmuls (and V's, in the admission), which then wanted the
+    weight with the contracted dimension minor: each layer of each step
+    sliced `wq`'s 33.5 MB out of the stack and re-laid it out before a
+    0.19 ms matmul (`constant_dynamic-slice_fusion.14`, `copy.50`: 1.45 ms
+    of an 11.4 ms step on the chip against `wo`'s 0.71 for the same bytes;
+    PERF.md, PR 42). `qkv_proj(split_on_result=True)` is the repair."""
+    from tony_tpu.serve.engine import _admit_step, _decode_sample_step
+
+    config, params, cache, _, slots, _ = _chat_steady_cell(one_chip, False)
+    per_slot = _sds((slots,), jnp.int32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    key = _sds((2,), jnp.uint32, one_chip)
+    if program == "decode":
+        lowered = _decode_sample_step.lower(
+            params, config, cache, per_slot, per_slot, key, scalar,
+            0.0, 0, 1.0, attend=per_slot)
+    else:
+        lowered = _admit_step.lower(
+            params, config, cache, per_slot, _sds((384,), jnp.int32,
+                                                  one_chip),
+            scalar, key, scalar, 0.0, 0, 1.0, False, scalar, False)
+    compiled = lowered.compile()
+    assert _weight_results_outside_fusions(compiled.as_text(), params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
 def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
@@ -387,6 +469,7 @@ def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip, mask):
     text = compiled.as_text()
     slab = (slots, config.n_kv_heads, budget, config.head_dim)
     assert _slab_results_outside_fusions(text, slab) == []
+    assert _weight_results_outside_fusions(text, params) == []
     for kernel in ("tony_sparse_read", "tony_lightning_step"):
         assert kernel in text, kernel
     # two kinds of block, each once: not sixteen unrolled layers

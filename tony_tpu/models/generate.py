@@ -219,7 +219,7 @@ def prefill(params: Params, tokens: jax.Array, config: LlamaConfig,
         # the scan body, so XLA fuses the int8 read into each matmul
         layer = dequantize_layer(layer)
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q, k, v = qkv_proj(h, layer, config)
+        q, k, v = qkv_proj(h, layer, config, split_on_result=True)
         q = apply_rope(q, cos[:p], sin[:p])
         k = apply_rope(k, cos[:p], sin[:p])
         attn = flash_attention(q, k, v, True)
@@ -291,7 +291,7 @@ def window_logits(params: Params, config: LlamaConfig,
         # int8-quantized layers dequantize HERE, inside the scan body
         layer = dequantize_layer(layer)
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q, k, v = qkv_proj(h, layer, config)
+        q, k, v = qkv_proj(h, layer, config, split_on_result=True)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
         rows, k_new, v_new = new_cache_rows(k, v, cache["k"].dtype, quant)

@@ -277,16 +277,32 @@ def rope_tables(config: LlamaConfig, seq: int):
         orig_max_seq=config.rope_orig_max_seq or config.max_seq)
 
 
-def qkv_proj(h: jax.Array, layer: Params, config: LlamaConfig
+def qkv_proj(h: jax.Array, layer: Params, config: LlamaConfig,
+             split_on_result: bool = False
              ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(B, S, D) -> q (B,H,S,hd), k/v (B,Hkv,S,hd) — pre-RoPE. Shared by
     the training forward here and the KV-cache decode (models/generate.py)
-    so architecture changes land in one place."""
+    so architecture changes land in one place.
+
+    `split_on_result` is the serving programs' rule (every caller in
+    models/generate.py and serve/kvcache.py passes it; models/sala.py
+    states the same rule for its own projections): a projection's heads
+    are split on its result, never on its weight. A barrier keeps the
+    reshape and the transpose below out of the matmul: folded into it, the
+    compiler batches the matmul over heads and wants the WEIGHT with the
+    contracted dimension minor, so with a few rows of activations (a
+    decode step's 32, an admission's 1536 at most) it slices the layer out
+    of the stacked weights and re-lays-out all of it, every layer of every
+    step, to save transposing the rows. The trainer does not pass it: at
+    8192 rows the activations are the large operand, the copy is lost in
+    the step, and a barrier would only take fusion freedom from it."""
     b, s, _ = h.shape
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     q = jnp.einsum("bsd,dh->bsh", h, layer["wq"])
     k = jnp.einsum("bsd,dh->bsh", h, layer["wk"])
     v = jnp.einsum("bsd,dh->bsh", h, layer["wv"])
+    if split_on_result:
+        q, k, v = (lax.optimization_barrier(x) for x in (q, k, v))
     q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)      # (B,H,S,hd)
     k = k.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)

@@ -177,7 +177,9 @@ def prefill_suffix(params: Params, config: LlamaConfig, cache,
             ksc = vsc = None
         layer = dequantize_layer(layer)
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        q, k, v = qkv_proj(h, layer, config)     # (1,H,W,hd)/(1,Hkv,W,hd)
+        # (1,H,W,hd)/(1,Hkv,W,hd); heads split on the result, as in
+        # window_logits and generate.prefill
+        q, k, v = qkv_proj(h, layer, config, split_on_result=True)
         q = apply_rope(q, cos, sin, positions=positions)
         k = apply_rope(k, cos, sin, positions=positions)
         row_k = lax.dynamic_index_in_dim(kc, slot, axis=0, keepdims=True)
