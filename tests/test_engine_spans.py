@@ -62,13 +62,31 @@ def _generate(port, prompt, n, stream):
     return urllib.request.urlopen(req, timeout=120).read()
 
 
+def _host_lines(path):
+    """The `tony.*` and `test.*` events of each host thread line."""
+    from jax.profiler import ProfileData
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [{"name": e.name, "start": float(e.start_ns),
+                       "end": float(e.start_ns) + float(e.duration_ns),
+                       "stats": dict(e.stats)}
+                      for e in line.events
+                      if e.name.startswith(("tony.", "test."))]
+            if events:
+                lines.append(sorted(events, key=lambda e: (e["start"],
+                                                           -e["end"])))
+    return lines
+
+
 @pytest.fixture(scope="module")
 def traced(model, tmp_path_factory):
     """One profile of a live engine behind its front end: two requests
     (one streamed, one admitted while the other decodes), then an idle
     stretch. Gives the `tony.*` events of each thread line of the host
     plane as dicts, and the two request ids."""
-    from jax.profiler import ProfileData
     from tony_tpu import constants as C
     params, cfg = model
     with pytest.MonkeyPatch.context() as mp:
@@ -103,19 +121,8 @@ def traced(model, tmp_path_factory):
         engine.stop()
     path = sorted(glob.glob(os.path.join(out, "**", "*.xplane.pb"),
                             recursive=True))[-1]
-    lines = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            events = [{"name": e.name, "start": float(e.start_ns),
-                       "end": float(e.start_ns) + float(e.duration_ns),
-                       "stats": dict(e.stats)}
-                      for e in line.events if e.name.startswith("tony.")]
-            if events:
-                lines.append(sorted(events, key=lambda e: (e["start"],
-                                                           -e["end"])))
-    return {"lines": lines, "request_ids": {first_id, first_id + 1}}
+    return {"lines": _host_lines(path),
+            "request_ids": {first_id, first_id + 1}}
 
 
 def _engine_line(traced):
@@ -362,3 +369,223 @@ def test_serve_main_prints_its_startup_phases_before_serving_up(tmp_path):
     if os.path.exists("/proc/self/stat"):
         # the interpreter's start and the imports came before main()
         assert got["process_age_s"] >= got["total_s"] - 0.02
+
+
+# -- a step's counts on its own spans (PR 43) --------------------------------
+
+def _family_models():
+    from tony_tpu.models import lfm2, sala
+    llama = get_config("tiny")
+    sala_tiny = sala.get_sala_config("sala_tiny")
+    lfm2_tiny = lfm2.get_config("lfm2_tiny")
+    key = jax.random.PRNGKey(0)
+    return {"llama": (llama_init(llama, key), llama),
+            "sala": (sala.sala_init(sala_tiny, key), sala_tiny),
+            "lfm2": (lfm2.lfm2_init(lfm2_tiny, key), lfm2_tiny)}
+
+
+FAMILIES = ("llama", "sala", "lfm2")
+# the counters of /v1/metrics a step's span attributes sum to
+COUNTERS = ("decode_steps_total", "decode_slot_steps_total",
+            "decode_slot_steps_discarded_total", "state_slots_moved_total",
+            "moe_experts_hit_total", "moe_rows_total")
+
+
+@pytest.fixture(scope="module")
+def counted(tmp_path_factory):
+    """One profile over three engines stepped by hand at a known
+    occupancy (A: prompt 5, 4 tokens; B: prompt 7, 6 tokens, admitted in
+    the same step: three decode steps carry both, two carry B alone), a
+    llama-shaped, a SALA-shaped and an LFM2-shaped tiny model, each under
+    a `test.run` span of its own; then a loop thread stopped with a step
+    in flight; then a `Phases` of the test's own. Gives per run the spans
+    under its mark and the growth of the counters over it."""
+    from tony_tpu import constants as C
+    from tony_tpu.observability.spans import Phases, span
+    engines = {}
+    for family, (params, cfg) in _family_models().items():
+        engine = ContinuousBatchingEngine(params, cfg, n_slots=3,
+                                          token_budget=128, queue_depth=8)
+        for n in (5, 7):        # every shape compiled before the profile
+            engine.submit(_prompt(cfg, n, n), 3)
+            while engine.step():
+                pass
+        engines[family] = engine
+    params, cfg = _family_models()["llama"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(C.TEST_SERVE_DECODE_DELAY, "5")
+        looped = ContinuousBatchingEngine(params, cfg, n_slots=3,
+                                          token_budget=128, queue_depth=8)
+    out = str(tmp_path_factory.mktemp("counted_profile"))
+    grown = {}
+    jax.profiler.start_trace(out)
+    try:
+        for family, engine in engines.items():
+            before = engine.snapshot()
+            with span("test.run", which=family):
+                engine.submit(_prompt(cfg, 5, 11), 4)
+                engine.submit(_prompt(cfg, 7, 12), 6)
+                while engine.step():
+                    pass
+            after = engine.snapshot()
+            grown[family] = {k: after[k] - before[k] for k in COUNTERS
+                             if k in after}
+        with span("test.run", which="stop"):
+            looped.start()
+            looped.submit(_prompt(cfg, 5, 13), 100)
+            while looped.stats.decode_steps_total < 4:
+                time.sleep(0.002)
+            looped.stop()
+        with span("test.run", which="phases"):
+            with Phases("test.parent", step=3) as ph:
+                ph.enter("test.first", riders=2)
+                ph.enter("test.second")
+                ph.enter("test.third", lands=2)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(out, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    lines = _host_lines(path)
+    marks = {e["stats"]["which"]: e for ln in lines for e in ln
+             if e["name"] == "test.run"}
+    runs = {which: [[e for e in ln if _within(e, mark)
+                     and e["name"] != "test.run"] for ln in lines]
+            for which, mark in marks.items()}
+    return {"runs": runs, "grown": grown}
+
+
+def _named(events, name):
+    return [e for e in events if e["name"] == "tony.engine." + name]
+
+
+def _threads(counted, which):
+    """The spans of a run, thread line by thread line, the busiest last."""
+    return sorted((ln for ln in counted["runs"][which] if ln), key=len)
+
+
+def _own_thread(counted, which):
+    """The spans of a run that was made on one thread."""
+    lines = _threads(counted, which)
+    assert len(lines) == 1
+    return lines[0]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_steps_counts_on_its_spans_sum_to_the_counters_growth(counted,
+                                                                family):
+    events = _own_thread(counted, family)
+    grown = counted["grown"][family]
+    dispatches, emits = _named(events, "decode.dispatch"), _named(events,
+                                                                  "emit")
+
+    def total(spans, key):
+        return sum(e["stats"][key] for e in spans)
+
+    assert len(dispatches) == len(emits) == grown["decode_steps_total"] == 5
+    assert [d["stats"]["riders"] for d in dispatches] == [2, 2, 2, 1, 1]
+    # the K/V rows of the context in flight: A at 5, 6, 7 beside B at
+    # 7, 8, 9, then B alone at 10 and 11
+    assert [d["stats"]["context_rows"] for d in dispatches] \
+        == [12, 14, 16, 10, 11]
+    # every rider's token was kept or thrown away (a stream that ended
+    # with the step in flight)
+    assert grown["decode_slot_steps_total"] == 8
+    assert total(dispatches, "riders") == grown["decode_slot_steps_total"] \
+        + grown["decode_slot_steps_discarded_total"]
+    if family == "sala":    # the riders are the slots whose state moved
+        assert total(dispatches, "riders") \
+            == grown["state_slots_moved_total"]
+    # what the model counted on the device rides on the `emit` that
+    # landed it, under the counts' own names; nothing else rides: the
+    # leaves share `step`, each has its own
+    counts = {"moe_experts_hit", "moe_rows"} if family == "lfm2" else set()
+    for attr in counts:
+        assert total(emits, attr) == grown[attr + "_total"] > 0, attr
+    for d in dispatches:
+        assert set(d["stats"]) == {"step", "riders", "context_rows"}
+    for e in emits:
+        assert set(e["stats"]) == {"step", "lands"} | counts
+    for name in ("reap", "decode.prepare", "release"):
+        assert all(set(e["stats"]) == {"step"} for e in _named(events, name))
+
+
+@pytest.mark.parametrize("how", ["loop", "step", "stop"])
+def test_lands_names_the_step_whose_dispatch_made_the_tokens(traced, counted,
+                                                             how):
+    """Since PR 31 an iteration of the loop reads the step dispatched an
+    iteration earlier; a caller's own `step()` and `stop()` land the one
+    just dispatched."""
+    if how == "loop":
+        events = _engine_line(traced)
+        behind = 1
+    elif how == "step":
+        events = _own_thread(counted, "llama")
+        behind = 0
+    else:
+        # the mark's own thread holds only what `stop()` itself landed;
+        # the loop's dispatches lie on the loop thread's line
+        events, loop = _threads(counted, "stop")
+        assert len(_named(events, "step")) == 1
+        assert not _named(events, "decode.dispatch")
+        behind = 0
+    waits, emits = _named(events, "decode.wait"), _named(events, "emit")
+    assert waits and len(waits) == len(emits)
+    dispatched = {d["stats"]["step"]: d for d in _named(
+        loop if how == "stop" else events, "decode.dispatch")}
+    for wait, emit in zip(waits, emits):
+        assert wait["stats"]["lands"] == emit["stats"]["lands"] \
+            == wait["stats"]["step"] - behind
+        assert set(wait["stats"]) == {"step", "lands"}
+        made = dispatched[wait["stats"]["lands"]]
+        assert made["end"] <= wait["start"] + SLACK_NS
+    if how == "stop":
+        # the loop's own waits ran a step behind, to the last but one
+        assert [w["stats"]["lands"] for w in _named(loop, "decode.wait")] \
+            == sorted(dispatched)[:-1]
+        assert waits[0]["stats"]["lands"] == max(dispatched)
+
+
+def test_a_leafs_own_attributes_stay_on_that_leaf(counted):
+    events = {e["name"]: e["stats"]
+              for e in _own_thread(counted, "phases")}
+    assert events == {"test.parent": {"step": 3},
+                      "test.first": {"step": 3, "riders": 2},
+                      "test.second": {"step": 3},
+                      "test.third": {"step": 3, "lands": 2}}
+
+
+def test_with_no_profile_open_the_attributes_outlive_no_call(model):
+    """The counts go to the annotation's constructor and to nothing else:
+    no field of the engine, of its stats or of the step in flight grows
+    by them, and the spans' share of an iteration stays a few
+    microseconds (PR 26: under 10 us a step)."""
+    from tony_tpu.observability.spans import Phases
+    params, cfg = model
+    engine = ContinuousBatchingEngine(params, cfg, n_slots=2,
+                                      token_budget=32, queue_depth=8)
+    fields = (set(vars(engine)), set(vars(engine.stats)))
+    engine.submit(_prompt(cfg, 5, 14), 6)
+    engine._step()
+    engine._step()
+    flight = engine._in_flight
+    assert set(vars(flight)) == {"tokens", "counts", "riders", "step"}
+    assert flight.step == engine._steps == 2
+    assert (set(vars(engine)), set(vars(engine.stats))) == fields
+    engine.stop()
+    with Phases("test.parent", step=1) as ph:
+        ph.enter("test.leaf", riders=2, context_rows=9)
+        assert ph._attrs == {"step": 1}
+    n = 2000
+    t = time.perf_counter()
+    for i in range(n):
+        with Phases("tony.engine.step", step=i) as ph:
+            ph.enter("tony.engine.reap")
+            ph.enter("tony.engine.decode.prepare")
+            ph.enter("tony.engine.decode.dispatch", riders=3,
+                     context_rows=1000)
+            ph.enter("tony.engine.decode.wait", lands=i)
+            ph.enter("tony.engine.emit", lands=i, moe_experts_hit=400,
+                     moe_rows=96)
+            ph.enter("tony.engine.release")
+    per_step = (time.perf_counter() - t) / n
+    assert per_step < 50e-6, per_step     # ~8 us here; a loaded CI host

@@ -475,8 +475,7 @@ class _FakeEngine:
 
     def snapshot(self):
         return {"tokens_per_sec": 10.0, "slot_occupancy_pct": 50.0,
-                "queue_depth": 1, "queue_depth_max": 3,
-                "ttft_p50_s": None, "token_budget": 32}
+                "queue_depth": 1, "ttft_p50_s": None, "token_budget": 32}
 
 
 def test_serving_frontend_content_negotiation():

@@ -24,6 +24,9 @@ class Phases:
     `leave()`. Attributes given here or by `set()` go on the parent and on
     every leaf opened afterwards (`set` also reaches the leaf that is
     open), so a step's spans share `step` and a request's `request_id`.
+    What belongs to one leaf alone (a decode step's riders on its
+    `decode.dispatch`) is given to `enter()`: it goes on that leaf and is
+    kept nowhere.
     """
 
     def __init__(self, name: str, **attrs):
@@ -39,9 +42,9 @@ class Phases:
         self.leave()
         self._parent.__exit__(*exc)
 
-    def enter(self, name: str) -> None:
+    def enter(self, name: str, **attrs) -> None:
         self.leave()
-        self._leaf = span(name, **self._attrs)
+        self._leaf = span(name, **self._attrs, **attrs)
         self._leaf.__enter__()
 
     def leave(self) -> None:

@@ -258,6 +258,10 @@ class _Flight:
     # who held which slot when it was dispatched, and the row it wrote:
     # a token is booked only for a handle that still holds its slot
     riders: list[tuple[_Slot, RequestHandle, int]]
+    # the iteration that dispatched it (`tony.engine.decode.dispatch`'s
+    # `step`): what the `decode.wait` and `emit` spans that land it carry
+    # as `lands`, an iteration later in the loop
+    step: int
 
 
 @dataclass
@@ -266,7 +270,6 @@ class EngineStats:
     sources are bounded deques — a gauge window, not an unbounded log."""
     tokens_emitted: int = 0
     requests_finished: int = 0
-    queue_depth_max: int = 0
     # admission accounting: queue-eligible submissions that were accepted
     # vs shed with QueueFullError (the frontend's 429) — the first-class
     # SLI behind the reject-rate burn-rate alert rule. Cumulative
@@ -662,8 +665,6 @@ class ContinuousBatchingEngine:
             handle.migrate_out = bool(migrate_out)
             self._pending.append(handle)
             self._pending_tokens += need
-            self.stats.queue_depth_max = max(self.stats.queue_depth_max,
-                                             len(self._pending))
         self._work.set()
         return handle
 
@@ -729,8 +730,6 @@ class ContinuousBatchingEngine:
                               "leaves": leaves}
             self._pending.append(handle)
             self._pending_tokens += need
-            self.stats.queue_depth_max = max(self.stats.queue_depth_max,
-                                             len(self._pending))
         self._work.set()
         return handle
 
@@ -911,13 +910,17 @@ class ContinuousBatchingEngine:
                 read = self._rows_read(attend)
                 moved = int(np.count_nonzero(attend)) \
                     if self._state_by_riding else None
-                ph.enter("tony.engine.decode.dispatch")
+                # the step's own occupancy, on the span the profile pairs
+                # with its device program
+                ph.enter("tony.engine.decode.dispatch", riders=len(riders),
+                         context_rows=int(attend.sum()))
                 self._tokens, self._cache, counts = _decode_sample_step(
                     self.params, self.config, self._cache, self._tokens,
                     pos, self._key, self._next_draw(), self.temperature,
                     self.top_k, self.top_p, attend=attend)
                 flight = _Flight(self._tokens, counts,
-                                 [(s, s.handle, s.pos) for s in riders])
+                                 [(s, s.handle, s.pos) for s in riders],
+                                 self._steps)
                 for slot in riders:
                     slot.pos += 1
                     slot.dispatched += 1
@@ -966,8 +969,11 @@ class ContinuousBatchingEngine:
 
     def _land(self, flight: _Flight, ph: Phases) -> None:
         """Wait for a dispatched step's tokens (`decode.wait`, after the
-        wake-ups the loop held back) and book them (`emit`)."""
-        ph.enter("tony.engine.decode.wait")
+        wake-ups the loop held back) and book them (`emit`). Both spans
+        say which step they land (`lands`: the dispatching iteration's
+        `step`), and `emit` what the model counted in it on the device
+        (its STEP_COUNTS, under their own names)."""
+        ph.enter("tony.engine.decode.wait", lands=flight.step)
         self._deliver()
         started = time.monotonic()
         if self._read_ended_at is not None:
@@ -975,8 +981,8 @@ class ContinuousBatchingEngine:
                 self.stats.step_host_s.append(
                     started - self._read_ended_at - self._admit_s)
         nxt_np = np.asarray(jax.device_get(flight.tokens))
-        counted = None if flight.counts is None else \
-            jax.device_get(flight.counts)
+        counted = {} if flight.counts is None else dict(zip(
+            self._step_counts, map(int, jax.device_get(flight.counts))))
         if self._test_decode_delay_s > 0:
             # chaos seam: TEST_SERVE_DECODE_DELAY slows this replica's
             # decode by a fixed per-step delay — the slow-hop-attribution
@@ -984,7 +990,7 @@ class ContinuousBatchingEngine:
             time.sleep(self._test_decode_delay_s)
         now = self._read_ended_at = time.monotonic()
         self._admit_s = 0.0
-        ph.enter("tony.engine.emit")
+        ph.enter("tony.engine.emit", lands=flight.step, **counted)
         gaps, attended, context = [], 0, 0
         for slot, handle, pos in flight.riders:
             if slot.handle is not handle:
@@ -1007,12 +1013,11 @@ class ContinuousBatchingEngine:
                 len(flight.riders) - len(gaps))
             self.stats.sparse_blocks_attended_total += attended
             self.stats.sparse_context_blocks_total += context
-            if counted is not None:
+            if counted:
                 self.stats.moe_layer_steps_total += self._expert_layers
-                for name, n in zip(self._step_counts, counted):
+                for name, n in counted.items():
                     name += "_total"
-                    setattr(self.stats, name, getattr(self.stats, name)
-                            + int(n))
+                    setattr(self.stats, name, getattr(self.stats, name) + n)
 
     def _admit_pending(self) -> bool:
         admitted = False
@@ -1334,7 +1339,6 @@ class ContinuousBatchingEngine:
                 "requests_rejected": self.stats.requests_rejected,
                 "tokens_per_sec": self.stats.tokens_emitted / elapsed,
                 "queue_depth": depth,
-                "queue_depth_max": self.stats.queue_depth_max,
                 "active_slots": active,
                 "n_slots": self.n_slots,
                 "slot_occupancy_pct": 100.0 * active / self.n_slots,
