@@ -576,3 +576,147 @@ def test_sparse_read_kernel_dereferences_no_block_of_a_slot_that_reads_none():
     np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(
         got[1], jnp.broadcast_to(v_new[1][:, None, :], (g, r, d)), atol=1e-6)
+
+
+# -- tony_sparse_select: the decode step's block selection, sorting nothing --
+
+# the sala-longdoc cell's selection rule and budget (36 864 tokens: 2 304
+# compressed keys, 576 blocks, 64 of them read), at narrow heads
+SELECT_BUDGET = 36864
+
+
+def _select_args(lens, riding, seed=0, q=None, ck=None, heads=2, d=32,
+                 n_layers=2, layer=1):
+    """The arguments of one selection call of every slot: random queries,
+    compressed keys and completed rows unless given."""
+    from tony_tpu.ops import lightning as L
+    from tony_tpu.ops import sparse_attention as sa
+
+    spec = sa.SparseSpec()
+    b, g, nc = len(lens), 2, SELECT_BUDGET // spec.kernel_stride
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    if q is None:
+        q = jax.random.normal(ks[0], (b, g, heads, d)) * 2
+    if ck is None:
+        ck = jax.random.normal(ks[1], (n_layers, b, g, nc, d))
+    row = jax.random.normal(ks[2], (b, g, d)).astype(jnp.bfloat16)
+    lens = jnp.asarray(lens, jnp.int32)
+    at = lens + 1 - spec.kernel_size
+    flag = (at >= 0) & (at % spec.kernel_stride == 0)
+    return spec, (jnp.asarray([layer], jnp.int32),
+                  *L.compact_riders(jnp.asarray(riding, bool)), lens,
+                  jnp.maximum(at, 0) // spec.kernel_stride,
+                  flag.astype(jnp.int32), q.astype(jnp.bfloat16), row,
+                  ck.astype(jnp.bfloat16))
+
+
+def _select_both(spec, args):
+    from tony_tpu.ops import sparse_attention as sa
+
+    want = jax.jit(partial(sa._select_decode_jnp, spec=spec))(*args)
+    got = jax.jit(partial(sa._select_decode_pallas, spec=spec,
+                          interpret=True))(*args)
+    assert got[0].dtype == got[1].dtype == jnp.int32
+    return tuple(map(np.asarray, got)), tuple(map(np.asarray, want))
+
+
+SELECT_CONTEXTS = {
+    "one-block-short-of-the-budget": 36863, "long": 30001,
+    "first-past-dense-len": 8192, "last-dense": 8191,
+    "dense-under-64-blocks": 4000, "dense-no-complete-window": 20,
+    "empty-slot": 0, "completes-a-window": 12303, "completes-none": 12304,
+    "window-covers-the-whole-context": 9000}
+
+
+@pytest.mark.parametrize("context", list(SELECT_CONTEXTS))
+def test_sparse_select_kernel_picks_the_jnp_bodys_blocks(context):
+    """`tony_sparse_select` (interpret mode) against the jnp body — the
+    one that sorts twice — at the cell's rule and budget: the ids and the
+    counts are the same integers, for contexts past `dense_len` up to one
+    block short of the budget (the 64 best of up to 576 blocks, ascending),
+    for contexts the dense branch reads whole, and whether or not the
+    token completes a compressed key of its own."""
+    n = SELECT_CONTEXTS[context]
+    spec, args = _select_args([n, max(n - 1, 0)], [1, 1],
+                              seed=len(context))
+    if "completes" in context:
+        assert bool(args[5][0]) == (context == "completes-a-window")
+    (ids, counts), (want_ids, want) = _select_both(spec, args)
+    assert (counts == want).all() and (ids == want_ids).all()
+    attended, _ = spec.read_blocks(n + 1)
+    assert counts[0].tolist() == [min(attended, -(-n // 64))] * 2
+    for row, c in zip(ids[0], counts[0]):
+        assert (np.diff(row[:c]) > 0).all() and (row[c:] == 0).all()
+
+
+@pytest.mark.parametrize("mask", list(RIDING))
+def test_sparse_select_kernel_selects_for_riding_slots_only(mask):
+    """Under a riding mask: a slot that does not ride gets count 0 and ids
+    0 from the kernel and from the jnp body, and a rider's row is what it
+    is when every slot rides, whoever else rides."""
+    riding = np.asarray(RIDING[mask], bool)
+    lens = [36000, 8191, 20015, 12303, 500]
+    spec, args = _select_args(lens, riding, seed=11)
+    (ids, counts), (want_ids, want) = _select_both(spec, args)
+    assert (counts == want).all() and (ids == want_ids).all()
+    assert (counts[~riding] == 0).all() and (ids[~riding] == 0).all()
+    _, every = _select_args(lens, np.ones(5, bool), seed=11)
+    (all_ids, all_counts), _ = _select_both(spec, every)
+    assert (ids[riding] == all_ids[riding]).all()
+    assert (counts[riding] == all_counts[riding]).all()
+    assert (all_counts > 0).all()
+
+
+def _keys_along_one_axis(levels, b=1, g=2, d=32, n_layers=2):
+    """Compressed keys whose score against a query along the first axis is
+    the given level (exact in bfloat16), the same in every layer, slot and
+    group: levels (NC,)."""
+    ck = jnp.zeros((n_layers, b, g, levels.shape[0], d))
+    return ck.at[..., 0].set(jnp.asarray(levels, jnp.float32))
+
+
+@pytest.mark.parametrize("tie", ["equal-pooled-scores-at-the-64th-place",
+                                 "a-softmax-of-exact-zeros"])
+def test_sparse_select_kernel_breaks_ties_toward_the_lower_block(tie):
+    """Ties, constructed: of two blocks whose pooled scores are the same
+    float at the 64th place the lower one is read, and where one key holds
+    the whole softmax (every other probability underflows to an exact 0)
+    the free places go to the lowest blocks — `lax.top_k`'s rule, which
+    the kernel's rank keeps. Position 20 000: blocks 0 and 280..312 are
+    forced (34 of the 64), 30 are free."""
+    n, nc, d = 20000, SELECT_BUDGET // 16, 32
+    low, high = 150, 200                            # the two that tie
+    levels = np.zeros(nc, np.float32)
+    if tie.startswith("equal"):
+        best = np.arange(10, 10 + 29 * 3, 3)        # 29 blocks well ahead
+        levels[4 * best + 1] = 3.0
+        levels[[4 * low + 1, 4 * high + 1]] = 2.0
+        expect = sorted([0, *best, low, *range(280, 313)])
+    else:
+        levels[4 * 100 + 1] = 64.0      # a score of 256: the rest read 0.0
+        expect = sorted([0, *range(1, 30), 100, *range(280, 313)])
+    q = jnp.zeros((1, 2, 2, d)).at[..., 0].set(32.0 ** 0.5 * 4)
+    spec, args = _select_args([n], [1], q=q, ck=_keys_along_one_axis(levels))
+    (ids, counts), (want_ids, want) = _select_both(spec, args)
+    assert (counts == want).all() and (ids == want_ids).all()
+    assert counts.tolist() == [[64, 64]]
+    assert ids[0, 0, :64].tolist() == expect
+    if tie.startswith("equal"):
+        assert high not in ids[0, 0]
+
+
+def test_select_decode_without_a_mask_is_every_slot_riding(monkeypatch):
+    """`select_decode`, the model's entry: `riders` absent is every slot
+    riding, and with the kernels interpreted (TONY_FLASH_INTERPRET) it
+    returns what the plain path does."""
+    from tony_tpu.ops import sparse_attention as sa
+
+    spec, args = _select_args([36000, 9000, 100], [1, 1, 1], seed=5)
+    layer, _, _, lens, j, flag, q, row, ck = args
+    plain = sa.select_decode(layer, q, ck, lens, spec, (j, flag, row))
+    monkeypatch.setattr(sa, "_INTERPRET", True)
+    kernel = sa.select_decode(layer, q, ck, lens, spec, (j, flag, row))
+    want = sa._select_decode_jnp(*args, spec=spec)
+    for a, b, c in zip(plain, kernel, want):
+        assert bool(jnp.all(a == c)) and bool(jnp.all(b == c))
+    assert np.asarray(want[1]).tolist() == [[64, 64], [64, 64], [2, 2]]

@@ -452,7 +452,11 @@ def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip, mask):
     temporaries), and the step holds each kind's block once: its program
     text is 0.50 MB, three times that of Mistral's one block (0.17 MB: two
     kinds of block, the selection, two kernels), where sixteen unrolled
-    layers would be some sixteen times it."""
+    layers would be some sixteen times it. The selection is the kernel
+    `tony_sparse_select`, which takes a rider's compressed keys out of the
+    whole `ck` leaf (no layer's keys are sliced out) and ranks its block
+    scores: the program sorts nothing (before PR 44 the period's body held
+    two sorts, `lax.top_k`'s full sort of 576 pairs a row and the ids')."""
     from tony_tpu.serve.engine import _decode_sample_step
 
     config, params, cache, cache_bytes, slots, budget = _sala_cell(one_chip)
@@ -469,9 +473,13 @@ def test_sala_decode_step_updates_its_cache_by_kind_in_place(one_chip, mask):
     text = compiled.as_text()
     slab = (slots, config.n_kv_heads, budget, config.head_dim)
     assert _slab_results_outside_fusions(text, slab) == []
+    keys = slab[:2] + (budget // config.sparse.kernel_stride, slab[3])
+    assert _slab_results_outside_fusions(text, keys) == []
     assert _weight_results_outside_fusions(text, params) == []
-    for kernel in ("tony_sparse_read", "tony_lightning_step"):
+    for kernel in ("tony_sparse_select", "tony_sparse_read",
+                   "tony_lightning_step"):
         assert kernel in text, kernel
+    assert " sort(" not in text and "top_k" not in text.lower()
     # two kinds of block, each once: not sixteen unrolled layers
     assert text.count("tony_lightning_step") < 8
     assert len(text) < 1.2e6, len(text)

@@ -480,22 +480,29 @@ def prefill(params: Params, tokens: jax.Array, config: SalaConfig,
 # decode: one token a slot
 # ---------------------------------------------------------------------------
 
-def _completed_window(tail, k_new, pos, sp: SparseSpec):
-    """The compressed key the new token completes, if it does: (index j
-    (B,), flag (B,), row (B, G, hd)). `tail` (B, G, kernel_size, hd) is the
-    slot's ring of its last kernel_size K rows (position t at t %
-    kernel_size): the window's mean is the ring's, with the new token's
-    row where it will be written."""
+def _completed_window(pos, sp: SparseSpec):
+    """Which compressed key the token at `pos` completes, if it does, and
+    where its K row stands in the ring of the last kernel_size rows:
+    (index j (B,), flag (B,), own (B, kernel_size) the ring entry
+    pos % kernel_size). The same for every sparse layer of a step."""
     ks, st = sp.kernel_size, sp.kernel_stride
-    own = jnp.arange(ks, dtype=jnp.int32)[None, :] == (pos % ks)[:, None]
-    rows = jnp.where(own[:, None, :, None], k_new[:, :, None, :], tail)
-    mean = jnp.sum(rows.astype(jnp.float32), axis=2) / ks
     flag = (pos + 1 >= ks) & ((pos + 1 - ks) % st == 0)
-    return (jnp.maximum(pos + 1 - ks, 0) // st, flag,
-            mean.astype(k_new.dtype))
+    own = jnp.arange(ks, dtype=jnp.int32)[None, :] == (pos % ks)[:, None]
+    return jnp.maximum(pos + 1 - ks, 0) // st, flag, own
 
 
-def _sparse_decode(x, layer: Params, p, cache, pos, riding,
+def _window_mean(tail, k_new, own):
+    """The compressed key (B, G, hd) of the window the new token ends.
+    `tail` (B, G, kernel_size, hd) is the slot's ring of its last
+    kernel_size K rows (position t at t % kernel_size): the window's mean
+    is the ring's, with the new token's row where it will be written
+    (`own`)."""
+    rows = jnp.where(own[:, None, :, None], k_new[:, :, None, :], tail)
+    mean = jnp.sum(rows.astype(jnp.float32), axis=2) / tail.shape[2]
+    return mean.astype(k_new.dtype)
+
+
+def _sparse_decode(x, layer: Params, p, cache, pos, window, riders,
                    config: SalaConfig):
     b = x.shape[0]
     nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
@@ -509,16 +516,17 @@ def _sparse_decode(x, layer: Params, p, cache, pos, riding,
                    config.norm_eps).astype(cache["k"].dtype)
     v = heads(layer["wv"], nkv).astype(cache["v"].dtype)
     qg = q.reshape(b, nkv, nh // nkv, hd)
+    layer_index = jnp.reshape(p, (1,))
     with jax.named_scope("tony_sparse_select"):
         tail = lax.dynamic_index_in_dim(cache["tail"], p, 0, keepdims=False)
-        new = _completed_window(tail, k, pos, sp)
-        ck = lax.dynamic_index_in_dim(cache["ck"], p, 0, keepdims=False)
-        ids, counts = select_decode(qg, ck, pos, sp, new)
-        # a slot that does not ride reads none of the blocks selected for
-        # it: its token attends to its own row alone
-        counts = jnp.where(riding[:, None], counts, 0)
-    attn = sparse_decode_attention(jnp.reshape(p, (1,)), ids, counts, pos,
-                                   qg, k, v, cache["k"], cache["v"], sp)
+        j, flag, own = window
+        new = (j, flag, _window_mean(tail, k, own))
+        # no blocks are selected for a slot that does not ride (count 0):
+        # its token attends to its own row alone
+        ids, counts = select_decode(layer_index, qg, cache["ck"], pos, sp,
+                                    new, riders)
+    attn = sparse_decode_attention(layer_index, ids, counts, pos, qg, k, v,
+                                   cache["k"], cache["v"], sp)
     attn = attn.reshape(b, nh * hd) * jax.nn.sigmoid(h @ layer["w_og"])
     x = _finish_layer(x, attn @ layer["wo"], layer, config)
     return x, {"k": k[:, :, None, :], "v": v[:, :, None, :],
@@ -580,7 +588,8 @@ def decode_step(params: Params, config: SalaConfig,
     x = jnp.take(params["embed"], token, axis=0).astype(STREAM) \
         * config.scale_emb
     riding = jnp.ones(token.shape, bool) if attend is None else attend > 0
-    riders = compact_riders(riding)     # once, not a lightning layer
+    riders = compact_riders(riding)     # once a step, not once a layer
+    window = _completed_window(pos, sp)
     decays = jnp.exp(-jnp.asarray(lightning_slopes(config)))
 
     # a period's lightning layers are indexed out of the whole stack by
@@ -593,7 +602,8 @@ def decode_step(params: Params, config: SalaConfig,
     def period(carry, xs):
         x, state = carry
         sparse, p = xs
-        x, rows = _sparse_decode(x, sparse, p, cache, pos, riding, config)
+        x, rows = _sparse_decode(x, sparse, p, cache, pos, window, riders,
+                                 config)
 
         def one(carry, i):
             x, state = carry
@@ -616,10 +626,8 @@ def decode_step(params: Params, config: SalaConfig,
     # the new rows of all sparse layers, written once and in place; a
     # token that completes no window writes its compressed key to the last
     # entry, which no position ever reads
-    done = (pos + 1 >= sp.kernel_size) \
-        & ((pos + 1 - sp.kernel_size) % sp.kernel_stride == 0)
-    at = jnp.where(done, (pos + 1 - sp.kernel_size) // sp.kernel_stride,
-                   cache["ck"].shape[3] - 1)
+    j, done, _ = window
+    at = jnp.where(done, j, cache["ck"].shape[3] - 1)
     written = write_cache_rows(
         {name: cache[name] for name in ("k", "v", "ck", "tail")},
         {**rows, "tail": rows["k"]},

@@ -188,9 +188,13 @@ def compact_riders(riding: jax.Array) -> tuple[jax.Array, jax.Array]:
     return slots, jnp.reshape(count, (1,))
 
 
+def riding_mask(slots: jax.Array, count: jax.Array) -> jax.Array:
+    """The mask (B,) that `compact_riders` compacted into (slots, count)."""
+    return jnp.zeros(slots.shape, bool).at[slots].set(True) & (count[0] > 0)
+
+
 def _step_jnp(layer, slots, count, decay, q, k, v, state, *, scale: float):
-    riding = jnp.zeros(q.shape[:1], bool).at[slots].set(True) \
-        & (count[0] > 0)
+    riding = riding_mask(slots, count)
     old = lax.dynamic_index_in_dim(state, layer[0], 0, keepdims=False)
     s = decay[None, :, None, None] * old.astype(jnp.float32) \
         + k[..., :, None] * v[..., None, :]

@@ -25,11 +25,23 @@ tile of 8 blocks. It walks every key tile up to the causal edge: the
 queries of a block select different blocks, and with weights that are
 not trained their union is nearly all of them (a first version that
 walked the union of 64 queries' blocks through scalar-prefetched indices
-ran 4 x slower for it: PERF.md, PR 30). Decode (`select_decode` + `sparse_decode_attention`)
-selects over the slot's cached compressed keys and reads the selected
-blocks of the K/V cache in place: the kernel `tony_sparse_read` takes the
-whole cache in HBM and copies only those blocks to VMEM, so no gathered
-copy of the cache exists.
+ran 4 x slower for it: PERF.md, PR 30).
+
+Decode (`select_decode` + `sparse_decode_attention`) selects over the
+slot's cached compressed keys and reads the selected blocks of the K/V
+cache in place. Stage 1 is one kernel a sparse layer, `tony_sparse_select`:
+a program a riding slot takes that slot's compressed keys out of the whole
+`ck` leaf, scores its blocks with `block_scores`' arithmetic (bfloat16
+operands, float32 products and softmax, the pool, the forced blocks) and
+takes the `topk` best by rank instead of sorting them — the k-th largest
+score by a search over its bits, ties to the lower block as `lax.top_k`
+breaks them, the ascending ids by a count over a running sum; a slot that
+does not ride costs a grid step, its count 0. (As XLA operations the same
+stage was two sorts and some 25 launches a layer: `lax.top_k` lowers to a
+full sort of the 576 block scores; PERF.md, PR 44.) Stage 2 is the kernel
+`tony_sparse_read`, which takes the whole cache in HBM and copies only the
+selected blocks to VMEM, so no gathered copy of the cache exists. The
+admission keeps `select_blocks` (`lax.top_k` over a chunk of queries).
 
 Dispatch is by platform at lowering time, as in ops/attention.py: the
 Pallas kernels on a TPU, the same arithmetic in plain jnp elsewhere.
@@ -46,6 +58,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tony_tpu.ops.attention import _INTERPRET, NEG_INF
+from tony_tpu.ops.lightning import compact_riders, riding_mask
 
 FORCED = -NEG_INF       # score of a block that is always taken
 
@@ -351,22 +364,19 @@ def sparse_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # decode: select over the cached compressed keys, read the blocks in place
 # ---------------------------------------------------------------------------
 
-def select_decode(q: jax.Array, ck: jax.Array, lens: jax.Array,
-                  spec: SparseSpec, new) -> tuple[jax.Array, jax.Array]:
-    """The blocks each slot's new token reads. q (B, G, R, d) at position
-    lens[b] (the rows the cache holds); ck (B, G, NC, d) the slot's
-    compressed keys; `new` = (j (B,), flag (B,), row (B, G, d)) as in
-    `block_scores`. Returns ids (B, G, max_read_blocks) ascending and
-    counts (B, G): a context of at most `dense_len` tokens (the new one
-    included) reads all its blocks, a longer one its selected `topk`."""
-    b = q.shape[0]
+def _select_decode_jnp(layer, slots, count, lens, j, flag, q, row, ck, *,
+                       spec: SparseSpec):
+    """The selection in plain jnp (what runs off the TPU, and the kernel's
+    reference): `select_blocks` a slot, which sorts twice (`lax.top_k`,
+    then the ids ascending)."""
     sm = spec.max_read_blocks
+    ck = lax.dynamic_index_in_dim(ck, layer[0], 0, keepdims=False)
 
     def one(qb, ckb, pos, j, flag, row):
         return select_blocks(qb[:, :, None, :], ckb, pos[None], spec,
-                             (j, flag, row))[:, 0]          # (G, topk)
+                             (j, flag > 0, row))[:, 0]      # (G, topk)
 
-    sel = jax.vmap(one)(q, ck, lens, *new)                  # (B, G, topk)
+    sel = jax.vmap(one)(q, ck, lens, j, flag, row)          # (B, G, topk)
     big = jnp.int32(2 ** 30)
     picked = jnp.sort(jnp.where(sel < 0, big, sel), axis=-1)
     picked = jnp.pad(picked, ((0, 0), (0, 0), (0, sm - spec.topk)),
@@ -377,8 +387,201 @@ def select_decode(q: jax.Array, ck: jax.Array, lens: jax.Array,
     n_every = (lens + spec.block_size - 1) // spec.block_size
     ids = jnp.where(dense[:, None, None], every[None, None, :], picked)
     counts = jnp.where(dense[:, None], n_every[:, None], n_picked)
+    counts = jnp.where(riding_mask(slots, count)[:, None], counts, 0)
     ids = jnp.where(every[None, None, :] < counts[..., None], ids, 0)
     return ids.astype(jnp.int32), counts.astype(jnp.int32)
+
+
+def _select_kernel(layer_ref, slots_ref, count_ref, lens_ref, j_ref,
+                   flag_ref, q_ref, row_ref, ck_ref, ids_ref, cnt_ref, ckf,
+                   score_ref, *, spec: SparseSpec, nb: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+    groups, _, d = q_ref.shape[1:]
+    nc = ck_ref.shape[3]
+    nbp = score_ref.shape[1]        # the blocks, padded to whole lane tiles
+    sm = ids_ref.shape[-1]
+    st, ks, block = spec.kernel_stride, spec.kernel_size, spec.block_size
+    k = min(spec.topk, nb)
+    f32 = jnp.float32
+
+    @pl.when(i == 0)
+    def _():        # a slot no program visits: count 0, ids 0
+        ids_ref[...] = jnp.zeros_like(ids_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        if nc < 4 * nbp:    # rows past the keys: never valid, not NaN
+            ckf[pl.ds(nc, 4 * nbp - nc), :] = jnp.zeros(
+                (4 * nbp - nc, d), f32)
+
+    @pl.when(i < count_ref[0])
+    def _():
+        slot = slots_ref[i]
+        pos = lens_ref[slot]
+        lane = lax.broadcasted_iota(jnp.int32, (1, nbp), 1)
+
+        def cumsum(x):      # along lanes, inclusive: log2(nbp) shifted adds
+            shift = 1
+            while shift < nbp:
+                x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0.0)
+                shift *= 2
+            return x
+
+        # `block_scores` at T = 1, a group at a time. The four compressed
+        # keys of a block are four reads of every fourth row (of a float32
+        # copy: a strided read wants 32-bit rows), so a block is a lane
+        # from the matmul on and the pool is a max of planes.
+        last = pos // block
+        first_window = jnp.maximum(pos - spec.window_size + 1, 0) // block
+        for g in range(groups):
+            ckf[pl.ds(0, nc), :] = ck_ref[0, 0, g].astype(f32)
+
+            @pl.when(flag_ref[slot] > 0)
+            def _():    # the key the token itself completes
+                ckf[pl.ds(j_ref[slot], 1), :] = \
+                    row_ref[0, g:g + 1, :].astype(f32)
+
+            q = q_ref[0, g]                                     # (R, d)
+            planes, valid = [], []
+            for r in range(4):
+                keys = ckf[pl.ds(r, nbp, stride=4), :].astype(q.dtype)
+                s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=f32) * d ** -0.5
+                ok = (4 * lane + r) * st + ks <= pos + 1
+                planes.append(jnp.where(ok, s, NEG_INF))
+                valid.append(ok)
+            m = functools.reduce(jnp.maximum, (
+                jnp.max(s, axis=1, keepdims=True) for s in planes))
+            planes = [jnp.where(ok, jnp.exp(s - m), 0.0)
+                      for s, ok in zip(planes, valid)]
+            den = jnp.maximum(sum(jnp.sum(p, axis=1, keepdims=True)
+                                  for p in planes), 1e-30)
+            g0, g1, g2, g3 = (jnp.sum(p / den, axis=0, keepdims=True)
+                              for p in planes)                  # (1, nbp)
+            before = jnp.where(lane >= 1, pltpu.roll(g3, 1, 1), -jnp.inf)
+            pooled = functools.reduce(jnp.maximum, (before, g0, g1, g2, g3))
+            forced = (lane < spec.init_blocks) | (lane >= first_window)
+            score = jnp.where(forced, FORCED, pooled)
+            score_ref[g:g + 1, :] = jnp.where(lane <= last, score, NEG_INF)
+
+        # the k best, ties to the lower index (`lax.top_k`'s rule), with
+        # no sort: the k-th largest score by a search over the bits of its
+        # order-preserving integer, every group at once
+        score = score_ref[...]                                  # (G, nbp)
+        bits = pltpu.bitcast(score, jnp.int32)
+        key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+        def at_least(t):
+            return jnp.sum((key >= t).astype(f32), axis=1, keepdims=True)
+
+        t = jnp.where(at_least(jnp.int32(0)) >= k, jnp.int32(0),
+                      jnp.int32(-2 ** 31))
+        for bit in range(30, -1, -1):
+            higher = t + jnp.int32(1 << bit)
+            t = jnp.where(at_least(higher) >= k, higher, t)
+        above, tie = key > t, key == t
+        ties = tie.astype(f32)
+        room = k - jnp.sum(above.astype(f32), axis=1, keepdims=True)
+        taken = (above | (tie & (cumsum(ties) - ties < room))) \
+            & (score > NEG_INF / 2)
+        taken = taken.astype(f32)
+        n_picked = jnp.sum(taken, axis=1, keepdims=True)        # (G, 1)
+        seen = cumsum(taken)                                    # (G, nbp)
+
+        # ascending ids: the n-th taken block is the number of blocks by
+        # which at most n were seen; stood up as a column, laid down as a
+        # row by a sum against the identity
+        nth = lax.broadcasted_iota(jnp.int32, (spec.topk, 1), 0).astype(f32)
+        eye = lax.broadcasted_iota(jnp.int32, (spec.topk, sm), 0) \
+            == lax.broadcasted_iota(jnp.int32, (spec.topk, sm), 1)
+        every = lax.broadcasted_iota(jnp.int32, (1, sm), 1)
+        dense = pos + 1 <= spec.dense_len
+        n_every = (pos + block - 1) // block
+        for g in range(groups):
+            column = jnp.sum((seen[g:g + 1, :] <= nth).astype(f32), axis=1,
+                             keepdims=True)                     # (topk, 1)
+            picked = jnp.sum(jnp.where(eye, column, 0.0), axis=0,
+                             keepdims=True).astype(jnp.int32)   # (1, sm)
+            n = jnp.where(dense, n_every,
+                          n_picked[g:g + 1, :].astype(jnp.int32))  # (1, 1)
+            ids = jnp.where(dense, every, picked)
+            ids_ref[slot, g:g + 1, :] = jnp.where(every < n, ids, 0)
+            cnt_ref[pl.ds(slot, 1), g:g + 1] = n
+
+
+def _select_decode_pallas(layer, slots, count, lens, j, flag, q, row, ck, *,
+                          spec: SparseSpec, interpret: bool = False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, g, r, d = q.shape
+    nc = ck.shape[3]
+    nb = nc // 4
+    nbp = -(-nb // 128) * 128
+    sm = spec.max_read_blocks
+
+    return pl.pallas_call(
+        functools.partial(_select_kernel, spec=spec, nb=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            # one program a slot that rides (`compact_riders`); the
+            # programs past the last rider name its blocks again and are
+            # empty grid steps
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, g, r, d),
+                             lambda i, _, slots, *__: (slots[i], 0, 0, 0)),
+                pl.BlockSpec((1, g, d),
+                             lambda i, _, slots, *__: (slots[i], 0, 0)),
+                # the rider's compressed keys of this layer, out of the
+                # whole leaf
+                pl.BlockSpec((1, 1, g, nc, d),
+                             lambda i, layer, slots, *_:
+                             (layer[0], slots[i], 0, 0, 0)),
+            ],
+            # every slot's ids and counts stay in VMEM for the whole call
+            out_specs=[pl.BlockSpec((b, g, sm), lambda i, *_: (0, 0, 0)),
+                       pl.BlockSpec((b, g), lambda i, *_: (0, 0))],
+            scratch_shapes=[pltpu.VMEM((4 * nbp, d), jnp.float32),
+                            pltpu.VMEM((g, nbp), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, g, sm), jnp.int32),
+                   jax.ShapeDtypeStruct((b, g), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="tony_sparse_select",
+    )(layer, slots, count, lens, j, flag, q, row, ck)
+
+
+def select_decode(layer: jax.Array, q: jax.Array, ck: jax.Array,
+                  lens: jax.Array, spec: SparseSpec, new,
+                  riders=None) -> tuple[jax.Array, jax.Array]:
+    """The blocks each slot's new token reads. q (B, G, R, d) at position
+    lens[b] (the rows the cache holds); ck (L, B, G, NC, d) the WHOLE leaf
+    of compressed keys, of which only `layer` (a (1,) int32) is read;
+    `new` = (j (B,), flag (B,), row (B, G, d)) as in `block_scores`;
+    `riders` = `compact_riders(mask)` of ops/lightning.py (absent: every
+    slot rides). Returns ids (B, G, max_read_blocks) ascending and counts
+    (B, G): a context of at most `dense_len` tokens (the new one included)
+    reads all its blocks, a longer one its selected `topk`; a slot that
+    does not ride reads none (count 0, ids 0).
+
+    On a TPU one call is the kernel `tony_sparse_select`: a program a
+    rider scores its blocks as `block_scores` does and takes the `topk`
+    best by rank, with `lax.top_k`'s ties, sorting nothing. Elsewhere the
+    same selection through `select_blocks` (two sorts)."""
+    if riders is None:
+        riders = compact_riders(jnp.ones(q.shape[:1], bool))
+    j, flag, row = new
+    args = (layer, *riders, lens.astype(jnp.int32), j.astype(jnp.int32),
+            flag.astype(jnp.int32), q, row, ck)
+    if _INTERPRET:
+        return _select_decode_pallas(*args, spec=spec, interpret=True)
+    return lax.platform_dependent(
+        *args, tpu=functools.partial(_select_decode_pallas, spec=spec),
+        default=functools.partial(_select_decode_jnp, spec=spec))
 
 
 def _valid_rows(ids, counts, lens, block: int):
