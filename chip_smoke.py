@@ -71,20 +71,23 @@ except ImportError as e:
           f"in, and there is none beside it ({e})", file=sys.stderr)
     sys.exit(2)
 
-# train phase, one chip. Batch 2: the v5e compiler's memory_analysis() of
-# this very step gives 6.36 GiB of arguments + 5.42 GiB of temporaries at
-# batch 2 (11.8 of ~15.75 GiB usable; tests/test_tpu_compile.py holds it)
-# and 6.36 + 9.74 = 16.1 GiB at batch 4. Batch 4 did run on the chip, so
-# that sum is an upper bound the program does not reach, and the chip's
-# peak_bytes_in_use (6.40 GiB at either batch) leaves out the temporaries:
-# batch 2 is the size neither source leaves in doubt (CHANGES.md, PR 22).
+# train phase, one chip. Batch 2: the v5e compiler's buffer assignment for
+# this very step (the arguments plus one heap of temporaries: the count
+# the chip obeys, PERF.md section 6, PR 47) is 13.86 of ~15.75 GiB usable
+# since `save_flash` keeps seven tensors a block (10.13 before;
+# tests/test_tpu_compile.py holds it). memory_analysis()'s arguments +
+# temporaries reads 16.61 for it (6.36 + 10.25; 11.8 before PR 47, and
+# 16.1 at batch 4, which "did run on the chip", CHANGES.md, PR 22: that
+# sum charges the layer scan's saved stacks twice), and the chip's
+# peak_bytes_in_use (6.40 GiB at either batch) leaves out the temporaries.
 MODEL = "llama3_1b_proxy"
 SEQ_LEN = 4096
 BATCH = 2
 STEPS = 6
 REHEARSE_STEPS = 20     # tiny learns slowly through its 10 warm-up steps
 # four-chip comparison: 8 of 16 layers so that batch 4 (one row a device
-# under fsdp=4) also fits the ONE-device reference (3.55 + 5.48 GiB)
+# under fsdp=4) also fits the ONE-device reference (10.87 GiB by the
+# buffer assignment, since PR 47's policy)
 FOUR_LAYERS = 8
 FOUR_BATCH = 4
 FOUR_STEPS = 6

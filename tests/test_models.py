@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from tony_tpu.models.llama import (
     get_config, llama_forward, llama_init, llama_loss, llama_param_axes,
@@ -457,3 +458,92 @@ def test_trainer_double_setup_mesh_loss():
     t.setup()          # retry: rebinds against the ORIGINAL loss_fn
     t.run()
     assert t.last_loss is not None
+
+
+# -- the replay's rule (PERF.md §3): what a rematted block saves ------------
+
+def _remat_model(model, **overrides):
+    """(config, init, loss, block) of the dense or the MoE model at the
+    tiny shapes."""
+    from tony_tpu.models import llama, moe
+    if model == "dense":
+        return (get_config("tiny", **overrides), llama_init, llama_loss,
+                llama._block)
+    return (moe.get_moe_config("moe_tiny", **overrides), moe.moe_init,
+            moe.moe_loss, moe._block)
+
+
+@pytest.mark.parametrize("policy", ["save_flash", "full"])
+@pytest.mark.parametrize("model", ["dense", "moe"])
+def test_remat_replay_gives_the_unrematted_loss_and_gradients(model, policy):
+    """A saved tensor is the very value the replay would recompute, so
+    what a policy saves changes memory and time, never numbers: the loss
+    and every gradient leaf under `remat=True` are those of `remat=False`,
+    under either policy, for the dense block and the MoE block (bit for
+    bit on the CPU)."""
+    plain, init, loss_fn, _ = _remat_model(model, remat=False)
+    remat, _, _, _ = _remat_model(model, remat=True, remat_policy=policy)
+    params = init(plain, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                                plain.vocab_size, jnp.int32)
+    batch = {"tokens": tokens}
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, batch, plain)))(params)
+    got_loss, got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, batch, remat)))(params)
+    assert float(got_loss) == float(want_loss)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("model", ["dense", "moe"])
+def test_save_flash_saves_the_named_tensors_and_the_blocks_inputs(
+        model, capsys):
+    """What one rematted block keeps for its backward under `save_flash`:
+    its inputs (the stream and the layer's weights), the RoPE tables, and
+    exactly the tensors the policy names — the flash kernel's five
+    residuals, the attention sublayer's projected output, and (dense
+    block) `w_gate`'s result. Nothing else: not `w_up`'s result, not a
+    norm's output, not the SwiGLU product. Under `full`, the inputs only."""
+    from functools import partial
+
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from tony_tpu.models.llama import SAVE_FLASH_NAMES, rope_tables
+
+    b, s = 2, 16
+
+    def saved(policy):
+        config, init, _, block = _remat_model(model, remat=True,
+                                              remat_policy=policy)
+        layer = jax.tree.map(lambda l: l[0],
+                             init(config, jax.random.PRNGKey(0))["layers"])
+        cos, sin = rope_tables(config, s)
+        block = jax.checkpoint(partial(block, config, cos, sin),
+                               policy=config.checkpoint_policy())
+        x = jnp.ones((b, s, config.dim), config.dtype)
+        print_saved_residuals(
+            lambda x, layer: jnp.sum(jax.tree.leaves(block(x, layer))[0]),
+            x, layer)
+        lines = capsys.readouterr().out.strip().splitlines()
+        return config, [l for l in lines if "from the argument" not in l
+                        and "from a constant" not in l]
+
+    config, made = saved("save_flash")
+    h, hk, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    want = {"flash_q": f"[{b},{h},{s},{hd}]",
+            "flash_k": f"[{b},{hk},{s},{hd}]",
+            "flash_v": f"[{b},{hk},{s},{hd}]",
+            "flash_out": f"[{b},{h},{s},{hd}]", "flash_lse": f"[{b},{h},{s}]",
+            "attn_proj": f"[{b},{s},{config.dim}]",
+            "mlp_gate": f"[{b},{s},{config.ffn_dim}]"}
+    assert set(want) == set(SAVE_FLASH_NAMES)
+    if model == "moe":
+        del want["mlp_gate"]     # the expert bank names nothing
+    assert sorted(l.split(" ")[0] for l in made) == sorted(
+        "f32" + v for v in want.values()), made
+    for name in ("flash_q", "flash_k", "flash_v", "flash_lse"):
+        assert sum(f"named '{name}'" in l for l in made) == 1, made
+    assert saved("full")[1] == []     # nothing the block made is kept

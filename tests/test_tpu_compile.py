@@ -374,19 +374,21 @@ def test_serving_programs_read_each_layers_weights_in_place(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
-def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
-    """The step chip_smoke.py's train phase runs — the trainer's own
-    construction (train/trainer.py: adamw under a warm-up cosine schedule,
-    bf16 state, donated) at the smoke's batch. A passing compile does NOT
-    prove the program fits (batch 8 compiles too, at 6.36 + 13.74 = 20.1
-    GiB for a 16 GB chip), so the size is read from memory_analysis().
-    Arguments + temporaries is an upper bound: batch 4 sums to 16.1 GiB
-    and still ran on the chip (CHANGES.md, PR 22)."""
+def _compile_train_step(config, one_chip, dump_to, batch):
+    """(lowered, compiled, bytes) of the trainer's own step for `config`
+    (train/trainer.py: adamw under a warm-up cosine schedule, bf16 state,
+    donated) on `batch` (names -> shapes). The bytes are the compile's own
+    buffer assignment — the arguments plus one preallocated heap of
+    temporaries, "Total bytes used" of the memory-usage report XLA dumps —
+    which is the count the chip obeys: a ballast of (15.75 GiB - this)
+    runs beside the step and one of 0.25 GiB more does not, for four steps
+    of four sizes (tools/train_ballast.py; PERF.md §6, PR 47).
+    `memory_analysis()`'s arguments + temporaries adds the layer scan's
+    stacked residuals a second time and reads 2.0 to 3.9 GiB higher."""
     import optax
 
     from tony_tpu.train.step import make_train_step
 
-    config = get_config("llama3_1b_proxy")
     params = _abstract_params(config, one_chip)
     optimizer = optax.adamw(
         optax.warmup_cosine_decay_schedule(0.0, 3e-4, 10, 100),
@@ -394,26 +396,78 @@ def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip):
     opt_state = jax.tree.map(
         lambda l: _sds(l.shape, l.dtype, one_chip),
         jax.eval_shape(optimizer.init, params))
-    batch = {k: _sds((SMOKE_BATCH, SMOKE_SEQ), jnp.int32, one_chip)
-             for k in ("inputs", "targets")}
+    batch = {k: _sds(shape, jnp.int32, one_chip)
+             for k, shape in batch.items()}
     step = make_train_step(partial(llama_loss, config=config), optimizer,
                            jit=False)
     lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt_state, batch)
-    compiled = lowered.compile()
-    # flash fwd once (the remat replay reuses the saved out/lse), dq and
-    # dk/dv once, RMSNorm twice a layer forward + twice in the replay +
-    # the final norm — in the lowered step and in the compiled one
-    want = {"tony_flash_fwd": 1, "tony_flash_bwd_dq": 1,
-            "tony_flash_bwd_dkv": 1, "tony_rmsnorm": 5,
-            "tpu_custom_call": 8}
-    assert kernel_counts(lowered.as_text()) == want
-    assert kernel_counts(compiled.as_text()) == want
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert total < HBM_USABLE, f"{total / GiB:.2f} GiB"
-    # and with room: the process also holds the prefetched batches
-    assert total < 13 * GiB, f"{total / GiB:.2f} GiB"
+    compiled = lowered.compile(
+        compiler_options={"xla_dump_to": str(dump_to)})
+    (report,) = dump_to.glob("*jit_train_step*memory-usage-report.txt")
+    used = int(re.search(r"Total bytes used: (\d+)",
+                         report.read_text()).group(1))
+    return lowered, compiled, used
+
+
+# flash fwd once (the remat replay reuses the saved residuals), dq and
+# dk/dv once, RMSNorm twice a layer forward + twice in the replay + the
+# final norm — in the lowered step and in the compiled one
+TRAIN_STEP_KERNELS = {"tony_flash_fwd": 1, "tony_flash_bwd_dq": 1,
+                      "tony_flash_bwd_dkv": 1, "tony_rmsnorm": 5,
+                      "tpu_custom_call": 8}
+
+
+def test_whole_1b_proxy_train_step_holds_the_kernels_and_fits(one_chip,
+                                                              tmp_path):
+    """The step chip_smoke.py's train phase runs, at the smoke's batch. A
+    passing compile does NOT prove the program fits (batch 8 compiles
+    too), so the size is read from the buffer assignment
+    (`_compile_train_step`). Before PR 47 this test summed
+    `memory_analysis()`'s arguments and temporaries, "an upper bound:
+    batch 4 sums to 16.1 GiB and still ran on the chip" (CHANGES.md,
+    PR 22); by that sum this step now reads 16.61 GiB and by the chip's
+    count 13.86 (10.13 before `save_flash` kept q, k, v, the projected
+    output and `w_gate`'s result: 224 MB a layer over 16 layers)."""
+    lowered, compiled, used = _compile_train_step(
+        get_config("llama3_1b_proxy"), one_chip, tmp_path,
+        {k: (SMOKE_BATCH, SMOKE_SEQ) for k in ("inputs", "targets")})
+    assert kernel_counts(lowered.as_text()) == TRAIN_STEP_KERNELS
+    assert kernel_counts(compiled.as_text()) == TRAIN_STEP_KERNELS
+    assert used < HBM_USABLE, f"{used / GiB:.2f} GiB"
+    # and with room: at least 1 GiB under usable, for a process that also
+    # holds two prefetched batches of 64 KB and whatever the runtime keeps
+    assert used < HBM_USABLE - 1 * GiB, f"{used / GiB:.2f} GiB"
+
+
+def test_train_4k_cells_step_fits_and_replays_no_saved_matmul(one_chip,
+                                                              tmp_path):
+    """The train-4k cell's own step (the literals of
+    benchmark/configs/mistral-7b-train.json: Mistral-7B widths, 5 layers,
+    adamw, donated, `xent_chunk` 1024, `remat_policy` "save_flash"; the
+    batch as benchmark/lib/traffic.py hands it over, 2 rows of 4096 + 1
+    tokens). (a) It takes 14.22 GiB by the count the chip obeys and
+    must stay at or under 15.0, 0.75 GiB under usable (11.89 before PR 47;
+    `w_up`'s result on top would read 15.33, which is why the policy stops
+    where it does). (b) The kernels are called as before. (c) The replay
+    holds no matmul whose result the policy saves: XLA counts 13.88e12
+    flops for the step (loop bodies once) where the parent's counted
+    15.53e12 — one layer's `wq`/`wk`/`wv` (0.4125e12), `wo` (0.275e12) and
+    `w_gate` (0.962e12) are gone from the backward loop's body; `w_up`
+    (0.962e12) stays."""
+    from tony_tpu.models.llama import LlamaConfig
+
+    config = LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=5, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq=4096, rope_theta=10000.0, norm_eps=1e-5,
+        xent_chunk=1024, remat=True, remat_policy="save_flash")
+    lowered, compiled, used = _compile_train_step(
+        config, one_chip, tmp_path, {"tokens": (2, 4097)})
+    assert used <= 15.0 * GiB, f"{used / GiB:.2f} GiB"
+    assert kernel_counts(lowered.as_text()) == TRAIN_STEP_KERNELS
+    assert kernel_counts(compiled.as_text()) == TRAIN_STEP_KERNELS
+    flops = compiled.cost_analysis()["flops"]
+    assert flops <= 13.90e12, f"{flops / 1e12:.3f}e12"
 
 
 # -- layers of several kinds: the sala-longdoc cell's two programs ---------

@@ -32,14 +32,18 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from tony_tpu.ops.attention import flash_attention
+from tony_tpu.ops.attention import FLASH_RESIDUAL_NAMES, flash_attention
 from tony_tpu.ops.rmsnorm import rms_norm
 from tony_tpu.ops.rope import apply_rope, rope_frequencies
 from tony_tpu.parallel.ring import ring_attention
 from tony_tpu.parallel.sharding import constrain
 
 Params = dict[str, Any]
+
+# what remat_policy="save_flash" keeps of a block (LlamaConfig's comment)
+SAVE_FLASH_NAMES = FLASH_RESIDUAL_NAMES + ("attn_proj", "mlp_gate")
 
 
 @dataclass(frozen=True)
@@ -63,10 +67,20 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # remat policy: "save_flash" keeps the flash-attention residuals
-    # (out+lse, named in ops/attention.py) so the backward replay never
-    # re-runs the fwd kernel, for ~64MB/layer of bf16 (the train cell
-    # runs it; against "full" it is not measured, PERF.md);
+    # remat policy. "save_flash" keeps, of every block, what the chip can
+    # keep and the backward would otherwise re-make (SAVE_FLASH_NAMES; the
+    # name says less than it saves, a rename waits for the benchmark's
+    # config file): all five residuals of the flash kernel (q, k, v, out,
+    # lse, named in ops/attention.py), the attention sublayer's projected
+    # output, and w_gate's result. The backward replay of a block then
+    # runs no flash fwd kernel, no wq/wk/wv/wo/w_gate matmul, no RoPE and
+    # no split into heads; it still recomputes the two norms, w_up, the
+    # SwiGLU product and the residual adds. Per row of the batch*seq in
+    # bf16: 2*(2*H*hd + 2*Hkv*hd + dim + ffn) bytes + 4*H for lse — 449
+    # MiB a layer at the train cell's 8192 rows of Mistral's widths, for
+    # 8.9 % more tokens a second there than out and lse alone give
+    # (PERF.md §6, PR 47, with the memory count the chip obeys; w_up's
+    # result as well would not leave 0.75 GiB free at 5 layers).
     # "full" rematerializes everything (minimum memory)
     remat_policy: str = "save_flash"
     # sequence-parallel flavor when the mesh shards seq: "ring" streams K/V
@@ -89,7 +103,7 @@ class LlamaConfig:
         """The jax.checkpoint policy for this config (None = save none)."""
         if self.remat_policy == "save_flash":
             return jax.checkpoint_policies.save_only_these_names(
-                "flash_out", "flash_lse")
+                *SAVE_FLASH_NAMES)
         return None
 
     @property
@@ -333,7 +347,8 @@ def attention_sublayer(h: jax.Array, layer: Params, config: LlamaConfig,
     v = constrain(v, ("batch", "kv_heads", "seq", None))
     attn = _attention_dispatch(q, k, v, config)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
-    return jnp.einsum("bsh,hd->bsd", attn, layer["wo"])
+    return checkpoint_name(jnp.einsum("bsh,hd->bsd", attn, layer["wo"]),
+                           "attn_proj")
 
 
 def _block(config: LlamaConfig, cos, sin, x, layer: Params):
@@ -342,7 +357,8 @@ def _block(config: LlamaConfig, cos, sin, x, layer: Params):
     x = constrain(x, ("batch", "seq", None))
 
     h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-    gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"])
+    gate = checkpoint_name(
+        jnp.einsum("bsd,df->bsf", h, layer["w_gate"]), "mlp_gate")
     up = jnp.einsum("bsd,df->bsf", h, layer["w_up"])
     # inlined swiglu_mlp so the mid-activation sharding constraint can sit
     # between the einsums (generate.py's decode uses the helper directly)

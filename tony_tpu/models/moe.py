@@ -47,13 +47,16 @@ expert. It has no training path and no aux loss.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tony_tpu.models.llama import LlamaConfig, llama_init, llama_param_axes
+from tony_tpu.models.llama import (
+    LlamaConfig, attention_sublayer, llama_init, llama_param_axes,
+)
 from tony_tpu.ops.expert_matmul import (
     expert_matmul, padded_rows, tile_rows_for,
 )
@@ -304,25 +307,25 @@ def _sparse_dispatch(xt, layer, gates, keep, position, capacity,
 # forward/loss (Llama block with MoE MLP)
 # ---------------------------------------------------------------------------
 
+def _block(config: MoEConfig, cos, sin, x, layer: Params):
+    h = rms_norm(x, layer["attn_norm"], config.norm_eps)
+    x = x + attention_sublayer(h, layer, config, cos, sin)
+    x = constrain(x, ("batch", "seq", None))
+    h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
+    moe_out, aux = moe_mlp(h, layer, config)
+    return constrain(x + moe_out, ("batch", "seq", None)), aux
+
+
 def moe_hidden(params: Params, tokens: jax.Array, config: MoEConfig
                ) -> tuple[jax.Array, jax.Array]:
     """-> (final-normed hidden (B,S,D), total aux loss)."""
-    from tony_tpu.models.llama import (
-        attention_sublayer, embed_lookup, rope_tables,
-    )
+    from tony_tpu.models.llama import embed_lookup, rope_tables
 
     s = tokens.shape[1]
     cos, sin = rope_tables(config, s)
     x = embed_lookup(params["embed"], tokens, config)
 
-    def block(x, layer):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        x = x + attention_sublayer(h, layer, config, cos, sin)
-        x = constrain(x, ("batch", "seq", None))
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        moe_out, aux = moe_mlp(h, layer, config)
-        return constrain(x + moe_out, ("batch", "seq", None)), aux
-
+    block = partial(_block, config, cos, sin)
     if config.remat:
         block = jax.checkpoint(block, policy=config.checkpoint_policy())
 

@@ -699,16 +699,24 @@ def _forward(q, k, v, causal, sm_scale, block_q, block_k, kv_len):
     return _shard_kernel_call(dispatch, (q, k, v), 3, 2)
 
 
+# the custom VJP's residuals, by the names `_fwd_rule` gives them
+FLASH_RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out",
+                        "flash_lse")
+
+
 def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, kv_len):
     out, lse = _forward(q, k, v, causal, sm_scale, block_q, block_k, kv_len)
-    # named so a `save_only_these_names("flash_out", "flash_lse")` remat
-    # policy keeps exactly the flash residuals: the backward replay then
-    # skips re-running the fwd kernel (the single most expensive recompute
-    # in a rematted transformer block) for ~1 GB of saved bf16 at
-    # llama3_1b_proxy scale
+    # all five residuals are named, so a `save_only_these_names(*
+    # FLASH_RESIDUAL_NAMES)` remat policy keeps exactly what the backward
+    # kernels read: the replay then re-runs neither the fwd kernel nor
+    # what made q, k and v (three projections, RoPE on two, three head
+    # transposes). Outside a jax.checkpoint a name is the identity, and a
+    # serving program never reaches this rule (it runs under
+    # differentiation alone). Bytes and what they buy: PERF.md §3, "the
+    # replay's rule"
     from jax.ad_checkpoint import checkpoint_name
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    q, k, v, out, lse = (checkpoint_name(x, name) for x, name in zip(
+        (q, k, v, out, lse), FLASH_RESIDUAL_NAMES))
     return out, (q, k, v, out, lse)
 
 

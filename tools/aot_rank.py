@@ -12,8 +12,12 @@ Mosaic custom-call kernels (its optimal_seconds comes back as a negative
 sentinel on these programs, and its flops/bytes skip kernel internals),
 so the bound is a floor on step time, not an estimate. The value is
 (a) variants that will OOM or blow compile are eliminated offline, and
-(b) the HBM plan per variant is exact — so chip time is spent
-measuring only configs that can actually run.
+(b) the HBM plan per variant is known — so chip time is spent
+measuring only configs that can actually run. (The plan summed here,
+memory_analysis()'s arguments + temporaries, charges a layer scan's saved
+stacks twice; the chip obeys the compile's buffer assignment, which reads
+2-4 GiB lower for a train step: PERF.md section 7, PR 47. The recorded
+verdicts in aot_rank_result*.json are by the sum.)
 
 Usage (CPU host, no TPU):
   JAX_PLATFORMS=cpu python tools/aot_rank.py [variant ...]
